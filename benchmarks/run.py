@@ -26,6 +26,7 @@ import subprocess
 import time
 import traceback
 
+from repro.utils.compile_cache import configure_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("bench.run")
@@ -140,6 +141,7 @@ def main(argv=None) -> int:
                     help="results-artifact label: BENCH_<label>.json "
                          "(default: $BENCH_LABEL, then the git short sha)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     scale = "smoke" if args.smoke else args.scale
     if args.only:
         only = set(args.only.split(","))

@@ -101,7 +101,6 @@ class RunTimeResult:
 class AutoSpMV:
     predictor: AutoSpmvPredictor
     overhead: OverheadPredictor | None = None
-    interpret: bool = True
     dataset: object | None = None  # the §5.4 TuningDataset the predictor was
     # fit on, when the builder kept it — telemetry refits merge its labels so
     # a handful of fleet measurements never erase offline coverage
@@ -189,9 +188,7 @@ class AutoSpMV:
     ) -> CompileTimeResult:
         feats = extract_features(dense)
         plan = self.plan_compile_time(feats, objective)
-        kernel = compile_spmv(
-            dense, default_format(), plan.schedule, interpret=self.interpret
-        )
+        kernel = compile_spmv(dense, default_format(), plan.schedule)
         log.info("compile-time: %s -> %s", objective, plan.schedule)
         return CompileTimeResult(feats, plan.schedule, kernel, plan.predicted)
 
@@ -212,7 +209,7 @@ class AutoSpMV:
         )
         convert = should_convert(plan, n_iterations, current_format)
         kernel = (
-            compile_spmv(dense, plan.best_format, schedule, interpret=self.interpret)
+            compile_spmv(dense, plan.best_format, schedule)
             if convert
             else None
         )

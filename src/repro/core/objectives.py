@@ -345,7 +345,13 @@ class CalibratedCostModel(TpuCostModel):
         raw = json.loads(Path(path).read_text())
         if raw.get("version") != 1:
             raise ValueError(f"unsupported calibration version: {raw.get('version')!r}")
-        resolved = hw or HARDWARE.get(raw.get("hardware", ""), TPU_V5E)
+        name = raw.get("hardware", "")
+        if hw is None and name not in HARDWARE:
+            raise ValueError(
+                f"calibration {path} names unknown hardware {name!r}; "
+                f"known: {sorted(HARDWARE)}"
+            )
+        resolved = hw or HARDWARE[name]
         corrections = {
             fmt: FormatCalibration(
                 launch_overhead_s=float(d["launch_overhead_s"]),

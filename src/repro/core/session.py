@@ -48,7 +48,7 @@ from repro.core.autotuner import (
 )
 from repro.core.cache import CacheEntry, TuningCache
 from repro.core.features import SparsityFeatures, extract_features
-from repro.kernels.common import DEFAULT_SCHEDULE, KernelSchedule
+from repro.kernels.common import DEFAULT_SCHEDULE, InfeasibleConfig, KernelSchedule
 from repro.kernels.ops import (
     compile_spmspv as _compile_spmspv_kernel,
     compile_spmv,
@@ -264,7 +264,7 @@ class AutoSpmvSession:
     ):
         before = kernel_memo_stats()["compiles"]
         kernel = compile_spmv(
-            dense, fmt, schedule, interpret=self.tuner.interpret, memo_key=fp
+            dense, fmt, schedule, memo_key=fp
         )
         self.stats.kernel_compiles += kernel_memo_stats()["compiles"] - before
         return kernel
@@ -282,7 +282,7 @@ class AutoSpmvSession:
         fp, _, _ = self._analyze(dense)
         before = kernel_memo_stats()["compiles"]
         prepared = _compile_spmspv_kernel(
-            dense, schedule, interpret=self.tuner.interpret, memo_key=fp
+            dense, schedule, memo_key=fp
         )
         self.stats.kernel_compiles += kernel_memo_stats()["compiles"] - before
         return prepared
@@ -388,9 +388,7 @@ class AutoSpmvSession:
                 # memoized (e.g. a plan for another objective converted this
                 # matrix earlier)
                 overhead_eff = plan.overhead_s
-                if kernel_memoized(
-                    fp, plan.best_format, schedule, interpret=self.tuner.interpret
-                ):
+                if kernel_memoized(fp, plan.best_format, schedule):
                     overhead_eff -= plan.convert_overhead_s
                 self.stats.overhead_paid_s += overhead_eff
             else:
@@ -406,9 +404,7 @@ class AutoSpmvSession:
                 # when the bucket was first tuned; conversion (c) only
                 # re-applies if the prepared kernel is not actually memoized
                 # in this process.
-                if kernel_memoized(
-                    fp, plan.best_format, schedule, interpret=self.tuner.interpret
-                ):
+                if kernel_memoized(fp, plan.best_format, schedule):
                     overhead_eff = 0.0
                 else:
                     overhead_eff = plan.convert_overhead_s
@@ -592,11 +588,11 @@ class AutoSpmvSession:
             before = kernel_memo_stats()["compiles"]
             if fused:
                 kernel = compile_fused_partitioned(
-                    dense, plan, interpret=self.tuner.interpret, memo_key=fp
+                    dense, plan, memo_key=fp
                 )
             else:
                 kernel = compile_partitioned(
-                    dense, plan, interpret=self.tuner.interpret, memo_key=fp
+                    dense, plan, memo_key=fp
                 )
             self.stats.kernel_compiles += kernel_memo_stats()["compiles"] - before
         return PartitionedResult(
@@ -654,14 +650,13 @@ class AutoSpmvSession:
                         bp.block.row_end,
                         fmt,
                         bp.schedule,
-                        interpret=self.tuner.interpret,
                         memo_key=base.fingerprint,
                     )
                     self.stats.kernel_compiles += (
                         kernel_memo_stats()["compiles"] - before
                     )
                     kernels[i] = dc_replace(bk, fmt=fmt, kernel=swapped)
-                except Exception as exc:
+                except InfeasibleConfig as exc:
                     log.warning(
                         "serve: %s infeasible for block %d of bucket %s (%s)",
                         fmt,
@@ -845,7 +840,7 @@ class AutoSpmvSession:
         else:
             try:
                 kernel = self._compile(dense, fp, fmt, base.schedule)
-            except Exception as exc:
+            except InfeasibleConfig as exc:
                 # an exploratory format can be infeasible for this matrix
                 # (storage blow-up, tile mismatch): serving must not fail on
                 # a bandit probe — fall back to the compile-time default-
@@ -996,7 +991,6 @@ def build_tuner(
     n_extra: int = 4,
     *,
     fit_overhead: bool = True,
-    interpret: bool = True,
 ) -> AutoSpMV:
     """Convenience: collect a small dataset, fit predictors + overhead model.
 
@@ -1017,4 +1011,4 @@ def build_tuner(
         overhead = OverheadPredictor().fit(
             [measure_overheads(generate_by_name(n, scale=scale), n) for n in names]
         )
-    return AutoSpMV(pred, overhead, interpret=interpret, dataset=ds)
+    return AutoSpMV(pred, overhead, dataset=ds)

@@ -11,7 +11,6 @@ model code runs unmodified on a single CPU device.
 from repro.dist.partition import hint, sharding_context
 from repro.dist.sharding import (
     RULE_SETS,
-    abstract_mesh,
     batch_sharding,
     build_sharding,
     spec_for,
@@ -19,7 +18,6 @@ from repro.dist.sharding import (
 
 __all__ = [
     "RULE_SETS",
-    "abstract_mesh",
     "batch_sharding",
     "build_sharding",
     "hint",

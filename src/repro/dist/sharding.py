@@ -19,10 +19,10 @@ weight replicates).
 from __future__ import annotations
 
 import math
-from typing import Any, Iterable, Mapping
+from typing import Any, Mapping
 
 import jax
-from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 MODEL_AXIS = "model"
 
@@ -75,19 +75,6 @@ def spmv_mesh(n_blocks: int | None = None):
     devices = jax.devices()
     n = len(devices) if n_blocks is None else max(1, min(n_blocks, len(devices)))
     return jax.sharding.Mesh(np.asarray(devices[:n]), ("data",))
-
-
-def abstract_mesh(axis_sizes: Iterable[int], axis_names: Iterable[str]) -> AbstractMesh:
-    """Version-compatible ``AbstractMesh`` constructor.
-
-    jax <= 0.4.x takes a single tuple of (name, size) pairs; newer releases
-    take (axis_sizes, axis_names).
-    """
-    sizes, names = tuple(axis_sizes), tuple(axis_names)
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(sizes, names)
 
 
 def _mesh_axis_sizes(mesh) -> dict[str, int]:

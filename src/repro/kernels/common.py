@@ -21,15 +21,13 @@ schedule is what the Auto-SpMV compile-time mode predicts per input matrix.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases; kernels
-# import the alias so either jax works.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 LANE = 128  # TPU vector lane quantum — the single source of truth
 SUBLANE = 8  # TPU sublane quantum (sparse/formats re-exports both)
@@ -54,7 +52,49 @@ X_RESIDENCY_CHOICES = ("vmem", "stream")
 DIMENSION_SEMANTICS_CHOICES = ("parallel", "arbitrary")
 
 # TPU v5e VMEM per core (bytes) — the hard budget the schedule must respect.
-VMEM_BYTES = 128 * 1024 * 1024 // 2  # 64 MiB usable planning budget
+# Every kernel passes it to Mosaic as ``vmem_limit_bytes`` (the compiler's
+# own scoped default is smaller), so the footprint model's feasibility check
+# and the compiler enforce the same limit.
+VMEM_BYTES = 128 * 1024 * 1024 // 2  # 64 MiB
+
+
+@functools.cache
+def default_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode: only on the CPU backend.
+
+    Resolved once, on first kernel call (never at import), so every kernel
+    on a TPU host is compiled by Mosaic and nothing above ``kernels/`` can
+    ask for the interpreter."""
+    return jax.default_backend() == "cpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel wrapper's explicit override, else ``default_interpret()``."""
+    return default_interpret() if interpret is None else bool(interpret)
+
+
+def row_sums(p: jax.Array, unroll: int) -> jax.Array:
+    """Kernel-body lane reduction of a ``(rows, width)`` tile into a
+    lane-dense ``(1, rows)`` row, as ``unroll`` independent partial sums."""
+    step = p.shape[1] // unroll
+    parts = [
+        jnp.sum(p[:, k * step : (k + 1) * step], axis=1, keepdims=True)
+        for k in range(unroll)
+    ]
+    return functools.reduce(jnp.add, parts).T
+
+
+def first_of_run(ids_ref, t):
+    """Kernel-body test: is grid step ``t`` the first of its run in the
+    sorted scalar-prefetched id map ``ids_ref`` (step -> output block)?"""
+    return (t == 0) | (ids_ref[t] != ids_ref[jnp.maximum(t - 1, 0)])
+
+
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """Mosaic compiler parameters shared by every kernel."""
+    return pltpu.CompilerParams(
+        dimension_semantics=dimension_semantics, vmem_limit_bytes=VMEM_BYTES
+    )
 
 
 @dataclass(frozen=True)

@@ -1,16 +1,28 @@
-"""Pallas TPU kernel: CSR SpMV (flat COO-tile segmented accumulation).
+"""Pallas TPU kernel: CSR SpMV (row-block segmented reduction).
 
 GPU scalar/vector-CSR does not map onto the TPU's 8x128 vector unit, so the
-CSR kernel is re-thought (DESIGN.md §2): nonzeros are walked in lane-aligned
-flat tiles along a *sequential* grid; each step forms the per-nonzero
-products and scatter-accumulates them into the VMEM-resident output vector
-by row id. Rows straddling a tile boundary are stitched for free because the
-output block persists in VMEM across the sequential grid. Padding nonzeros
-carry ``row_id == n_rows`` and fall into a spill slot that ops.py truncates.
+CSR kernel is re-thought (DESIGN.md §2). ``prepare`` pads the nonzero
+stream of every block of ``rows_per_block`` rows to a whole number of
+``nnz_tile`` tiles (explicit zeros in the block's last row, at least one
+tile per block), so each tile belongs to exactly one row block. The grid
+walks the tiles in row order:
 
-This keeps CSR's no-padding storage property; the price — an in-VMEM
-scatter-add per tile — is exactly the "CSR is hostile to wide SIMD" effect
-the paper observes on GPU (finding 5), now in TPU form.
+* X is gathered by XLA before the launch (Mosaic cannot gather single
+  elements of a vector inside a kernel) into a plane shaped like the
+  values;
+* a tile -> row-block map (each tile's first row id over ``rows_per_block``,
+  scalar-prefetched) drives the output index map, so consecutive tiles of
+  one block accumulate into the same lane-dense ``(1, rows_per_block)``
+  output block;
+* inside a tile, the products are reduced per row by a one-hot mask of the
+  row ids against the block's rows — a sorted segmented reduction in
+  place of a scatter-add.
+
+Tiles are stored as ``(n_tiles, 1, nnz_tile)`` views so every block's last
+two dims equal the array's, as Mosaic's (8, 128) tiling rule requires. The
+price of CSR's no-padding storage is the masked reduction — exactly the
+"CSR is hostile to wide SIMD" effect the paper observes on GPU (finding 5),
+now in TPU form.
 """
 
 from __future__ import annotations
@@ -19,30 +31,35 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import CompilerParams, KernelSchedule
+from repro.kernels.common import (
+    KernelSchedule,
+    compiler_params,
+    first_of_run,
+    resolve_interpret,
+    row_sums,
+)
 
 
-def _csr_kernel(d_ref, c_ref, r_ref, x_ref, y_ref, *, unroll: int, accum_dtype):
-    i = pl.program_id(0)
+def _csr_kernel(
+    bmap_ref, d_ref, xg_ref, r_ref, y_ref, *, rpb: int, unroll: int, accum_dtype
+):
+    t = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(first_of_run(bmap_ref, t))
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    xv = x_ref[...]
-    nt = d_ref.shape[0]
-    step = nt // unroll
-    y = y_ref[...].astype(accum_dtype)
-    for k in range(unroll):
-        sl = slice(k * step, (k + 1) * step)
-        prods = (d_ref[sl].astype(accum_dtype)) * jnp.take(xv, c_ref[sl]).astype(
-            accum_dtype
-        )
-        y = y.at[r_ref[sl]].add(prods)
-    y_ref[...] = y.astype(y_ref.dtype)
+    p = d_ref[0].astype(accum_dtype) * xg_ref[0].astype(accum_dtype)  # (1, nt)
+    local = r_ref[0] - bmap_ref[t] * rpb  # (1, nt) row within the block
+    nt = p.shape[1]
+    hit = local == lax.broadcasted_iota(jnp.int32, (rpb, nt), 0)
+    # select in float32: Mosaic cannot broadcast a mask over packed bf16
+    masked = jnp.where(hit, p.astype(jnp.float32), 0.0).astype(accum_dtype)
+    y_ref[...] += row_sums(masked, unroll).reshape(y_ref.shape).astype(y_ref.dtype)
 
 
 def csr_spmv_pallas(
@@ -51,39 +68,47 @@ def csr_spmv_pallas(
     row_ids: jax.Array,
     x: jax.Array,
     n_rows: int,
+    tiling: tuple[int, int],
     schedule: KernelSchedule,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """SpMV over tile-aligned flat CSR/COO arrays.
+    """SpMV over a row-block-aligned CSR stream.
 
-    ``data/indices/row_ids: (nnz_pad,)`` with ``nnz_pad % nnz_tile == 0``;
-    padding entries must have ``row_ids == n_rows``. Returns ``y: (n_rows+1,)``
-    (last slot = padding spill, truncated by the wrapper).
+    ``data/indices/row_ids: (nnz_pad,)`` laid out by ``prepare`` for
+    ``tiling = (rows_per_block, nnz_tile)``: every tile lies inside one row
+    block and every row block has at least one tile. The schedule supplies
+    the numerics (``accum_dtype``, ``unroll``). Returns ``y:
+    (n_row_blocks * rows_per_block,)``.
     """
+    rpb, nt = tiling
     (nnz_pad,) = data.shape
-    nt = schedule.nnz_tile
-    if nnz_pad % nt:
-        raise ValueError(f"nnz {nnz_pad} not aligned to nnz_tile {nt}")
-    grid = (nnz_pad // nt,)
+    if nnz_pad % nt or nt % schedule.unroll:
+        raise ValueError(f"CSR stream {nnz_pad} not aligned to nnz_tile {nt}")
+    n_tiles = nnz_pad // nt
+    n_blocks = -(-n_rows // rpb)
+    tiles = lambda a: a.reshape(n_tiles, 1, nt)  # noqa: E731
+    block_of_tile = row_ids[::nt] // rpb
+    xg = jnp.take(x, tiles(indices), axis=0)  # XLA gather, tile-shaped
     kernel = functools.partial(
-        _csr_kernel, unroll=schedule.unroll, accum_dtype=schedule.jnp_accum_dtype
+        _csr_kernel,
+        rpb=rpb,
+        unroll=schedule.unroll,
+        accum_dtype=schedule.jnp_accum_dtype,
     )
-    return pl.pallas_call(
+    tile_spec = pl.BlockSpec((1, 1, nt), lambda t, bmap: (t, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_tiles,),
+        in_specs=[tile_spec, tile_spec, tile_spec],
+        out_specs=pl.BlockSpec((1, 1, rpb), lambda t, bmap: (bmap[t], 0, 0)),
+    )
+    y = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((nt,), lambda i: (i,)),
-            pl.BlockSpec((nt,), lambda i: (i,)),
-            pl.BlockSpec((nt,), lambda i: (i,)),
-            pl.BlockSpec(x.shape, lambda i: (0,)),
-        ],
-        # whole output vector resident in VMEM across the sequential grid
-        out_specs=pl.BlockSpec((n_rows + 1,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((n_rows + 1,), x.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",),  # carried accumulation => sequential
-        ),
-        interpret=interpret,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_blocks, 1, rpb), x.dtype),
+        compiler_params=compiler_params("arbitrary"),  # carried accumulation
+        interpret=resolve_interpret(interpret),
         name="csr_spmv",
-    )(data, indices, row_ids, x)
+    )(block_of_tile, tiles(data), xg, tiles(row_ids))
+    return y.reshape(n_blocks * rpb)

@@ -1,11 +1,14 @@
 """Pallas TPU kernel: ELL SpMV.
 
-Grid ``(row_blocks, width_tiles)``; each step loads a ``(rows_per_block,
-nnz_tile)`` VMEM tile of the ELL value/column planes, gathers the matching X
-entries from the VMEM-resident dense vector, and accumulates partial row sums
-into the output block (revisited across the width grid axis, so the width
-axis must be 'arbitrary'). ``unroll`` splits the tile into independent
-accumulator chains — the VREG-pressure knob standing in for maxrregcount.
+Mosaic cannot gather single elements of a vector inside a kernel, so the
+X gather runs in XLA just before the launch: ``xg = x[cols]`` is a plane of
+the same shape as the value plane. Grid ``(row_blocks, width_tiles)``; each
+step loads ``(rows_per_block, nnz_tile)`` tiles of the value plane and of
+``xg``, multiplies them, reduces along the lanes, and accumulates the row
+sums into a lane-dense ``(1, rows_per_block)`` output block (revisited
+across the width axis, so that axis is 'arbitrary'). ``unroll`` splits the
+lane reduction into independent partial sums — the VREG-pressure knob
+standing in for maxrregcount.
 """
 
 from __future__ import annotations
@@ -15,31 +18,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import CompilerParams, KernelSchedule
+from repro.kernels.common import (
+    KernelSchedule,
+    compiler_params,
+    resolve_interpret,
+    row_sums,
+)
 
 
-def _ell_kernel(d_ref, c_ref, x_ref, y_ref, *, unroll: int, accum_dtype):
+def _ell_kernel(d_ref, xg_ref, y_ref, *, unroll: int, accum_dtype):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    d = d_ref[...]  # (rpb, nt)
-    c = c_ref[...]  # (rpb, nt)
-    xv = x_ref[...]  # (n_cols,)
-    step = d.shape[1] // unroll
-    # independent accumulator chains (ILP / register-pressure analogue)
-    accs = []
-    for k in range(unroll):
-        sl = slice(k * step, (k + 1) * step)
-        dk = d[:, sl].astype(accum_dtype)
-        xk = jnp.take(xv, c[:, sl], axis=0).astype(accum_dtype)
-        accs.append(jnp.sum(dk * xk, axis=1))
-    acc = functools.reduce(jnp.add, accs)
-    y_ref[...] += acc.reshape(y_ref.shape).astype(y_ref.dtype)
+    p = d_ref[...].astype(accum_dtype) * xg_ref[...].astype(accum_dtype)
+    y_ref[...] += row_sums(p, unroll).reshape(y_ref.shape).astype(y_ref.dtype)
 
 
 def ell_spmv_pallas(
@@ -48,39 +44,38 @@ def ell_spmv_pallas(
     x: jax.Array,
     schedule: KernelSchedule,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """SpMV over padded ELL planes. Shapes must already be tile-aligned:
     ``data/cols: (R, W)`` with ``R % rows_per_block == 0`` and
-    ``W % nnz_tile == 0`` (ops.py performs the padding). Returns ``y: (R,)``.
+    ``W % nnz_tile == 0`` (``prepare`` performs the padding). Returns
+    ``y: (R,)``.
     """
     R, W = data.shape
     rpb, nt = schedule.rows_per_block, schedule.nnz_tile
     if R % rpb or W % nt:
         raise ValueError(f"ELL planes ({R},{W}) not aligned to ({rpb},{nt})")
-    grid = (R // rpb, W // nt)
+    xg = jnp.take(x, cols, axis=0)  # XLA gather: one extra (R, W) plane
     kernel = functools.partial(
         _ell_kernel, unroll=schedule.unroll, accum_dtype=schedule.jnp_accum_dtype
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(R // rpb, W // nt),
         in_specs=[
             pl.BlockSpec((rpb, nt), lambda i, j: (i, j)),
             pl.BlockSpec((rpb, nt), lambda i, j: (i, j)),
-            pl.BlockSpec(x.shape, lambda i, j: (0,)),  # X resident in VMEM
         ],
-        out_specs=pl.BlockSpec((rpb,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((R,), x.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=(schedule.dimension_semantics, "arbitrary"),
-        ),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((1, 1, rpb), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((R // rpb, 1, rpb), x.dtype),
+        compiler_params=compiler_params(schedule.dimension_semantics, "arbitrary"),
+        interpret=resolve_interpret(interpret),
         name="ell_spmv",
-    )(data, cols, x)
+    )(data, xg)
+    return y.reshape(R)
 
 
-def _ell_spmm_kernel(d_ref, c_ref, x_ref, y_ref, *, accum_dtype):
+def _ell_spmm_kernel(d_ref, xg_ref, y_ref, *, accum_dtype):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -88,11 +83,8 @@ def _ell_spmm_kernel(d_ref, c_ref, x_ref, y_ref, *, accum_dtype):
         y_ref[...] = jnp.zeros_like(y_ref)
 
     d = d_ref[...].astype(accum_dtype)  # (rpb, nt)
-    c = c_ref[...]
-    xg = jnp.take(x_ref[...], c, axis=0).astype(accum_dtype)  # (rpb, nt, k)
-    y_ref[...] += jnp.einsum(
-        "rw,rwk->rk", d, xg, preferred_element_type=accum_dtype
-    ).astype(y_ref.dtype)
+    xg = xg_ref[...].astype(accum_dtype)  # (rpb, nt, k)
+    y_ref[...] += jnp.sum(d[:, :, None] * xg, axis=1).astype(y_ref.dtype)
 
 
 def ell_spmm_pallas(
@@ -101,29 +93,27 @@ def ell_spmm_pallas(
     X: jax.Array,
     schedule: KernelSchedule,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """ELL SpMM (dense RHS ``X: (n_cols, k)``) — the MoE-dispatch shape."""
+    """ELL SpMM (dense RHS ``X: (n_cols, k)``) — the MoE-dispatch shape.
+    The rows of X are gathered by XLA into an ``(R, W, k)`` operand."""
     R, W = data.shape
     rpb, nt = schedule.rows_per_block, schedule.nnz_tile
     if R % rpb or W % nt:
         raise ValueError(f"ELL planes ({R},{W}) not aligned to ({rpb},{nt})")
     k = X.shape[1]
-    grid = (R // rpb, W // nt)
+    xg = jnp.take(X, cols, axis=0)  # (R, W, k)
     kernel = functools.partial(_ell_spmm_kernel, accum_dtype=schedule.jnp_accum_dtype)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(R // rpb, W // nt),
         in_specs=[
             pl.BlockSpec((rpb, nt), lambda i, j: (i, j)),
-            pl.BlockSpec((rpb, nt), lambda i, j: (i, j)),
-            pl.BlockSpec(X.shape, lambda i, j: (0, 0)),
+            pl.BlockSpec((rpb, nt, k), lambda i, j: (i, j, 0)),
         ],
         out_specs=pl.BlockSpec((rpb, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, k), X.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=(schedule.dimension_semantics, "arbitrary"),
-        ),
-        interpret=interpret,
+        compiler_params=compiler_params(schedule.dimension_semantics, "arbitrary"),
+        interpret=resolve_interpret(interpret),
         name="ell_spmm",
-    )(data, cols, X)
+    )(data, xg)

@@ -19,9 +19,13 @@ composite kernels do on GPU:
   work item; the descriptor rides in scalar-prefetch SMEM and drives the
   BlockSpec index maps;
 * each program scatter-accumulates its tile straight into the one
-  VMEM-resident ``(n_rows + 1,)`` output vector (the CSR flat-tile kernel's
-  spill-slot convention) — every program writes its y shard in place, no
-  ``jnp.concatenate``, no per-block dispatch.
+  VMEM-resident ``(n_rows + 1,)`` output vector (spill slot last) — every
+  program writes its y shard in place, no ``jnp.concatenate``, no
+  per-block dispatch.
+
+The kernel gathers single elements of X and scatter-adds by unsorted row
+id inside the kernel; Mosaic lowers neither, so it runs only in interpret
+mode and raises ``NotImplementedError`` on a TPU backend.
 """
 
 from __future__ import annotations
@@ -36,10 +40,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
-    CompilerParams,
     KernelSchedule,
     ceil_to,
+    compiler_params,
     fused_nnz_tile,
+    resolve_interpret,
 )
 from repro.sparse.formats import BELL, CSR, ELL, SELL
 
@@ -84,9 +89,9 @@ def _flatten_sell(mat: SELL) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     # column-major slice planes; padding row_ids (== n_rows) carry value 0
     # and are dropped by the caller's nonzero filter like any padding slot
     return (
-        np.asarray(mat.data),
-        np.asarray(mat.cols).astype(np.int32),
-        np.asarray(mat.row_ids).astype(np.int32),
+        np.asarray(mat.data).ravel(),
+        np.asarray(mat.cols).astype(np.int32).ravel(),
+        np.asarray(mat.row_ids).astype(np.int32).ravel(),
     )
 
 
@@ -126,7 +131,7 @@ def flatten_block(
 
 
 # ---------------------------------------------------------------------------
-# The single-launch kernel (CSR flat-tile scatter-add + work descriptor)
+# The single-launch kernel (flat-tile scatter-add + work descriptor)
 # ---------------------------------------------------------------------------
 
 
@@ -162,7 +167,7 @@ def fused_spmv_pallas(
     *,
     unroll: int = 1,
     accum_dtype="float32",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """One launch over the fused composite stream.
 
@@ -171,6 +176,11 @@ def fused_spmv_pallas(
     prefix-sum work descriptor: program ``p`` processes flat tile
     ``tile_map[p]``. Returns ``y: (n_rows + 1,)`` (spill slot last).
     """
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(
+            "fused_partitioned_spmv does not lower on TPU (in-kernel gather and "
+            "unsorted scatter-add); serve partitioned plans with fused=False"
+        )
     n_tiles = int(tile_map.shape[0])
     if data.shape[0] != n_tiles * tile:
         raise ValueError(
@@ -196,10 +206,8 @@ def fused_spmv_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows + 1,), x.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",),  # carried accumulation
-        ),
-        interpret=interpret,
+        compiler_params=compiler_params("arbitrary"),  # carried accumulation
+        interpret=True,
         name="fused_partitioned_spmv",
     )(tile_map, data, cols, rows, x)
 
@@ -223,7 +231,6 @@ class FusedSpmv:
     tile: int
     unroll: int
     accum_dtype: str
-    interpret: bool = True
 
     @property
     def n_tiles(self) -> int:
@@ -243,7 +250,6 @@ class FusedSpmv:
                     tile=self.tile,
                     unroll=self.unroll,
                     accum_dtype=self.accum_dtype,
-                    interpret=self.interpret,
                 )
             )
             object.__setattr__(self, "_jit_call", fn)
@@ -271,7 +277,7 @@ def fused_schedule_params(schedules: list[KernelSchedule], tile: int) -> tuple[i
     return max(unroll, 1), accum
 
 
-def lower_fused(dense: np.ndarray, plan, *, interpret: bool = True) -> FusedSpmv:
+def lower_fused(dense: np.ndarray, plan) -> FusedSpmv:
     """Lower every block of a ``CompositePlan`` into one fused stream.
 
     Each block's dense rows are prepared in the block's chosen format (the
@@ -330,5 +336,4 @@ def lower_fused(dense: np.ndarray, plan, *, interpret: bool = True) -> FusedSpmv
         tile=tile,
         unroll=unroll,
         accum_dtype=accum,
-        interpret=interpret,
     )

@@ -3,10 +3,13 @@
 ``prepare`` converts a dense matrix into the requested format with storage
 geometry matched to a ``KernelSchedule`` (the compile-time parameters the
 Auto-SpMV predictor emits), and ``spmv_pallas`` runs the matching Pallas
-kernel. Both are thin lookups into the pluggable format registry
+kernel as one jitted program per (format, storage shape, schedule). Both
+are thin lookups into the pluggable format registry
 (``repro.sparse.registry``): the per-format conversion, alignment padding,
 feasibility checks, and kernel binding live on each ``FormatSpec``, so a
-format registered at runtime is served here with no code change.
+format registered at runtime is served here with no code change. Whether
+the kernels are compiled by Mosaic or interpreted is decided once, in
+``kernels.common.default_interpret``: interpret only on the CPU backend.
 
 The registry import is deliberately lazy (inside the functions): this module
 is imported by ``repro.kernels.__init__``, which the sparse substrate itself
@@ -16,6 +19,7 @@ close that cycle during package initialization.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -58,25 +62,24 @@ def prepare(
     return get_format(fmt).prepare(np.asarray(dense), schedule)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def _jitted_spmv(spmv, mat, x, schedule):
+    # the registered ``spmv`` is a static argument, so re-registering a
+    # format for the same container never replays a stale trace
+    return spmv(mat, x, schedule)
+
+
 def spmv_pallas(
-    mat: Any,
-    x: jax.Array,
-    schedule: KernelSchedule = DEFAULT_SCHEDULE,
-    *,
-    interpret: bool = True,
+    mat: Any, x: jax.Array, schedule: KernelSchedule = DEFAULT_SCHEDULE
 ) -> jax.Array:
     """Run the Pallas SpMV kernel matching ``type(mat)``; returns y: (n_rows,)."""
     from repro.sparse.registry import spec_for
 
-    return spec_for(mat).spmv(mat, x, schedule, interpret=interpret)
+    return _jitted_spmv(spec_for(mat).spmv, mat, x, schedule)
 
 
 def spmm_pallas(
-    mat: Any,
-    X: jax.Array,
-    schedule: KernelSchedule = DEFAULT_SCHEDULE,
-    *,
-    interpret: bool = True,
+    mat: Any, X: jax.Array, schedule: KernelSchedule = DEFAULT_SCHEDULE
 ) -> jax.Array:
     """Multi-vector SpMV (ELL only — the MoE-dispatch shape)."""
     import jax.numpy as jnp
@@ -87,9 +90,7 @@ def spmm_pallas(
     if not isinstance(mat, ELL):
         raise TypeError("spmm_pallas currently supports ELL")
     n_rows = mat.shape[0]
-    return ell_spmm_pallas(mat.data, mat.cols, jnp.asarray(X), schedule, interpret=interpret)[
-        :n_rows
-    ]
+    return ell_spmm_pallas(mat.data, mat.cols, jnp.asarray(X), schedule)[:n_rows]
 
 
 def spmspv(
@@ -97,8 +98,6 @@ def spmspv(
     active: np.ndarray,
     xvals: np.ndarray,
     schedule: KernelSchedule = DEFAULT_SCHEDULE,
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     """Sparse-input-vector SpMV over a ``CscEll`` container.
 
@@ -109,7 +108,7 @@ def spmspv(
 
     if not isinstance(mat, CscEll):
         raise TypeError("spmspv expects a CscEll container (see prepare_spmspv)")
-    return csc_spmspv(mat, active, xvals, schedule, interpret=interpret)
+    return csc_spmspv(mat, active, xvals, schedule)
 
 
 @dataclass(frozen=True)
@@ -118,10 +117,9 @@ class PreparedSpmv:
 
     mat: Any  # a registered format container (CSR / ELL / BELL / SELL / plugin)
     schedule: KernelSchedule
-    interpret: bool = True
 
     def __call__(self, x: jax.Array) -> jax.Array:
-        return spmv_pallas(self.mat, x, self.schedule, interpret=self.interpret)
+        return spmv_pallas(self.mat, x, self.schedule)
 
 
 def matrix_fingerprint(dense: np.ndarray) -> str:
@@ -138,7 +136,7 @@ def matrix_fingerprint(dense: np.ndarray) -> str:
 
 
 # Process-wide LRU memo of prepared kernels, keyed by (caller key, fmt,
-# schedule, interpret). Opt-in via ``compile_spmv(..., memo_key=...)`` so
+# schedule). Opt-in via ``compile_spmv(..., memo_key=...)`` so
 # one-off callers don't pin large format storage. Bounded: each entry holds
 # the full converted matrix storage, so an unbounded memo on a serving path
 # streaming distinct matrices would grow RSS until OOM. Fused composite
@@ -175,17 +173,13 @@ def set_kernel_memo_limit(limit: int) -> None:
 
 
 def kernel_memoized(
-    memo_key: Hashable,
-    fmt: str,
-    schedule: KernelSchedule = DEFAULT_SCHEDULE,
-    *,
-    interpret: bool = True,
+    memo_key: Hashable, fmt: str, schedule: KernelSchedule = DEFAULT_SCHEDULE
 ) -> bool:
     """Whether ``compile_spmv`` with these arguments would be a memo hit.
 
     Lets the session's amortized-overhead accounting charge the conversion
     term only when conversion will actually run."""
-    return (memo_key, fmt, schedule, interpret) in _KERNEL_MEMO
+    return (memo_key, fmt, schedule) in _KERNEL_MEMO
 
 
 def clear_kernel_memo() -> None:
@@ -225,7 +219,6 @@ def compile_spmv(
     fmt: str,
     schedule: KernelSchedule = DEFAULT_SCHEDULE,
     *,
-    interpret: bool = True,
     memo_key: Hashable | None = None,
 ) -> PreparedSpmv:
     """prepare + bind: the full compile-time-mode product.
@@ -236,7 +229,7 @@ def compile_spmv(
     without re-running conversion — the ``c`` term of the §5.3 overhead
     model is paid once per unique matrix (until LRU eviction)."""
     if memo_key is not None:
-        key = (memo_key, fmt, schedule, interpret)
+        key = (memo_key, fmt, schedule)
         hit = _KERNEL_MEMO.get(key)
         if hit is not None:
             _MEMO_STATS["hits"] += 1
@@ -244,7 +237,7 @@ def compile_spmv(
             _KERNEL_MEMO.move_to_end(key)
             return hit
     with _span("kernel.compile", fmt=fmt):
-        prepared = PreparedSpmv(prepare(dense, fmt, schedule), schedule, interpret)
+        prepared = PreparedSpmv(prepare(dense, fmt, schedule), schedule)
     if memo_key is not None:
         # counters cover memoized traffic only, so hits/(hits+compiles) is a
         # true memo hit rate (plain one-off compiles don't skew it)
@@ -265,7 +258,6 @@ def compile_spmv_block(
     fmt: str,
     schedule: KernelSchedule = DEFAULT_SCHEDULE,
     *,
-    interpret: bool = True,
     memo_key: Hashable | None = None,
 ) -> PreparedSpmv:
     """``compile_spmv`` for one row block of a larger matrix.
@@ -278,7 +270,7 @@ def compile_spmv_block(
     """
     block = np.asarray(dense)[row_start:row_end]
     key = (memo_key, row_start, row_end) if memo_key is not None else None
-    return compile_spmv(block, fmt, schedule, interpret=interpret, memo_key=key)
+    return compile_spmv(block, fmt, schedule, memo_key=key)
 
 
 _FUSED_TAG_PREFIX = "fused:"
@@ -297,11 +289,7 @@ def fused_plan_signature(plan) -> tuple:
 
 
 def compile_spmv_fused(
-    dense: np.ndarray,
-    plan,
-    *,
-    interpret: bool = True,
-    memo_key: Hashable | None = None,
+    dense: np.ndarray, plan, *, memo_key: Hashable | None = None
 ):
     """Lower a ``CompositePlan`` to its single-launch fused kernel.
 
@@ -314,7 +302,7 @@ def compile_spmv_fused(
     key = None
     if memo_key is not None:
         tag = _FUSED_TAG_PREFIX + "+".join(bp.fmt for bp in plan.blocks)
-        key = (memo_key, tag, fused_plan_signature(plan), interpret)
+        key = (memo_key, tag, fused_plan_signature(plan))
         hit = _KERNEL_MEMO.get(key)
         if hit is not None:
             _MEMO_STATS["hits"] += 1
@@ -322,7 +310,7 @@ def compile_spmv_fused(
             _KERNEL_MEMO.move_to_end(key)
             return hit
     with _span("kernel.compile", fused=True, formats="+".join(bp.fmt for bp in plan.blocks)):
-        kernel = lower_fused(dense, plan, interpret=interpret)
+        kernel = lower_fused(dense, plan)
     if key is not None:
         _MEMO_STATS["compiles"] += 1
         _M_COMPILES.inc()
@@ -349,13 +337,10 @@ class PreparedSpmspv:
 
     mat: Any  # repro.kernels.spmspv.CscEll
     schedule: KernelSchedule
-    interpret: bool = True
     col_nnz: Any = None  # np.ndarray (n_cols,) int64
 
     def call_frontier(self, active: np.ndarray, xvals: np.ndarray) -> jax.Array:
-        return spmspv(
-            self.mat, active, xvals, self.schedule, interpret=self.interpret
-        )
+        return spmspv(self.mat, active, xvals, self.schedule)
 
     def __call__(self, x: jax.Array) -> jax.Array:
         """Dense-in/dense-out convenience: extracts the frontier host-side."""
@@ -374,7 +359,6 @@ def compile_spmspv(
     dense: np.ndarray,
     schedule: KernelSchedule = DEFAULT_SCHEDULE,
     *,
-    interpret: bool = True,
     memo_key: Hashable | None = None,
 ) -> PreparedSpmspv:
     """prepare + bind the sparse-input-vector path.
@@ -387,7 +371,7 @@ def compile_spmspv(
     from repro.kernels.spmspv import csc_from_dense
 
     if memo_key is not None:
-        key = (memo_key, _SPMSPV_TAG, schedule, interpret)
+        key = (memo_key, _SPMSPV_TAG, schedule)
         hit = _KERNEL_MEMO.get(key)
         if hit is not None:
             _MEMO_STATS["hits"] += 1
@@ -396,10 +380,7 @@ def compile_spmspv(
             return hit
     with _span("kernel.compile", fmt=_SPMSPV_TAG):
         prepared = PreparedSpmspv(
-            csc_from_dense(dense, schedule),
-            schedule,
-            interpret,
-            _col_nnz(dense),
+            csc_from_dense(dense, schedule), schedule, _col_nnz(dense)
         )
     if memo_key is not None:
         _MEMO_STATS["compiles"] += 1

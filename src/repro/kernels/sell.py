@@ -1,13 +1,17 @@
 """Pallas TPU kernel: SELL (sliced-ELL) SpMV over true ragged storage.
 
-Slices are stored column-major (formats.py), so width-tile ``j`` of slice
-``s`` is the contiguous chunk ``[slice_ptr[s] + j*nnz_tile*C, +nnz_tile*C)``
-— addressable by a flat BlockSpec whose index is computed from the
-scalar-prefetched slice pointers. Raggedness is handled two ways at once:
+Slices are stored column-major (formats.py) as one ``(total / C, C)``
+plane: row ``k`` of slice ``s``'s part holds the k-th stored nonzero of each
+of its C rows. Width-tile ``j`` of slice ``s`` is therefore the
+``(nnz_tile, C)`` block ``slice_ptr[s] / (nnz_tile * C) + j`` of that plane.
 
-* the *data movement* of out-of-range tiles is aliased to the slice's last
-  valid tile (already VMEM-resident, so the re-DMA is free), and
-* the *compute* of out-of-range tiles is masked off with ``pl.when``.
+The grid walks the flat list of stored tiles, so raggedness costs no
+masked grid steps: a tile -> slice map, built from ``slice_width`` just
+before the launch and scalar-prefetched, drives the output index map, and
+consecutive tiles of one slice accumulate into the same lane-dense
+``(1, C)`` output block. X is gathered by XLA before the launch (Mosaic
+cannot gather single elements of a vector inside a kernel). Every slice
+stores at least one tile, so every output block is written.
 
 This is the SELL-C-sigma -> TPU adaptation: storage stays ragged (the whole
 point of SELL), while every DMA stays tile-aligned.
@@ -22,85 +26,78 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import CompilerParams, KernelSchedule
+from repro.kernels.common import (
+    KernelSchedule,
+    compiler_params,
+    first_of_run,
+    resolve_interpret,
+)
 
 
-def _sell_kernel(
-    tptr_ref, wt_ref, d_ref, c_ref, x_ref, y_ref, *, C: int, unroll: int, accum_dtype
-):
-    del tptr_ref  # consumed by the index maps
-    s, j = pl.program_id(0), pl.program_id(1)
+def _sell_kernel(smap_ref, d_ref, xg_ref, y_ref, *, unroll: int, accum_dtype):
+    t = pl.program_id(0)
 
-    @pl.when(j == 0)
+    @pl.when(first_of_run(smap_ref, t))
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    @pl.when(j < wt_ref[s])
-    def _compute():
-        nt = d_ref.shape[0] // C
-        d = d_ref[...].reshape(nt, C)
-        c = c_ref[...].reshape(nt, C)
-        xv = x_ref[...]
-        step = nt // unroll
-        acc = jnp.zeros((C,), accum_dtype)
-        for k in range(unroll):
-            sl = slice(k * step, (k + 1) * step)
-            dk = d[sl].astype(accum_dtype)
-            xk = jnp.take(xv, c[sl], axis=0).astype(accum_dtype)
-            acc = acc + jnp.sum(dk * xk, axis=0)
-        y_ref[...] += acc.reshape(y_ref.shape).astype(y_ref.dtype)
+    p = d_ref[...].astype(accum_dtype) * xg_ref[...].astype(accum_dtype)  # (nt, C)
+    step = p.shape[0] // unroll
+    acc = functools.reduce(
+        jnp.add,
+        [
+            jnp.sum(p[k * step : (k + 1) * step], axis=0, keepdims=True)
+            for k in range(unroll)
+        ],
+    )
+    y_ref[...] += acc.reshape(y_ref.shape).astype(y_ref.dtype)
 
 
 def sell_spmv_pallas(
     data: jax.Array,
     cols: jax.Array,
-    tile_ptr: jax.Array,
-    width_tiles: jax.Array,
+    slice_width: jax.Array,
     x: jax.Array,
-    n_slices: int,
-    C: int,
-    max_width_tiles: int,
     schedule: KernelSchedule,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """SpMV over flat SELL storage.
+    """SpMV over SELL storage.
 
-    ``data/cols: (total,)`` column-major ragged slices; ``tile_ptr[s]`` =
-    ``slice_ptr[s] / (nnz_tile*C)`` (must divide exactly — ops.py re-pads
-    widths when the schedule's nnz_tile exceeds the storage quantum);
-    ``width_tiles[s]`` = stored width of slice s in nnz_tile units. Returns
-    ``y: (n_slices, C)``.
+    ``data/cols: (total / C, C)`` column-major ragged slices whose widths
+    ``slice_width: (n_slices,)`` are multiples of ``nnz_tile`` (``prepare``
+    pads them so). Returns ``y: (n_slices * C,)``.
     """
+    rows, C = data.shape
     nt = schedule.nnz_tile
-    blk = nt * C
-    if data.shape[0] % blk:
-        raise ValueError(f"SELL storage {data.shape[0]} not aligned to {blk}")
-    grid = (n_slices, max_width_tiles)
+    if rows % nt:
+        raise ValueError(f"SELL storage rows {rows} not aligned to nnz_tile {nt}")
+    n_slices = slice_width.shape[0]
+    n_tiles = rows // nt
+    slice_of_tile = jnp.repeat(
+        jnp.arange(n_slices, dtype=jnp.int32),
+        slice_width // nt,
+        total_repeat_length=n_tiles,
+    )
+    xg = jnp.take(x, cols, axis=0)  # XLA gather: one extra storage plane
     kernel = functools.partial(
-        _sell_kernel, C=C, unroll=schedule.unroll, accum_dtype=schedule.jnp_accum_dtype
+        _sell_kernel, unroll=schedule.unroll, accum_dtype=schedule.jnp_accum_dtype
     )
-
-    def _tile_idx(s, j, tptr, wt):
-        return (tptr[s] + jnp.minimum(j, wt[s] - 1),)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
+        num_scalar_prefetch=1,
+        grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((blk,), _tile_idx),
-            pl.BlockSpec((blk,), _tile_idx),
-            pl.BlockSpec(x.shape, lambda s, j, tptr, wt: (0,)),
+            pl.BlockSpec((nt, C), lambda t, smap: (t, 0)),
+            pl.BlockSpec((nt, C), lambda t, smap: (t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, C), lambda s, j, tptr, wt: (s, 0)),
+        out_specs=pl.BlockSpec((1, 1, C), lambda t, smap: (smap[t], 0, 0)),
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slices, C), x.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=(schedule.dimension_semantics, "arbitrary"),
-        ),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((n_slices, 1, C), x.dtype),
+        compiler_params=compiler_params("arbitrary"),  # carried accumulation
+        interpret=resolve_interpret(interpret),
         name="sell_spmv",
-    )(tile_ptr, width_tiles, data, cols, x)
+    )(slice_of_tile, data, xg)
+    return y.reshape(n_slices * C)

@@ -18,14 +18,18 @@ the TPU form of that kernel:
   column ``active[i]`` via a BlockSpec index map driven by the prefetched
   indices, multiplies by the SMEM-resident ``x[active[i]]``, and
   scatter-adds by row id into the one VMEM-resident ``(n_rows + 1)``
-  output vector (CSR-kernel spill-slot convention: padding row ids equal
-  ``n_rows`` and land in the last slot, truncated by the wrapper).
+  output vector (spill-slot convention: padding row ids equal ``n_rows``
+  and land in the last slot, truncated by the wrapper).
 
 Work is therefore proportional to ``sum(col_nnz[frontier])`` (padded to
 tiles), not ``nnz(A)`` — the asymmetry the density-threshold policy in
 ``repro.solvers.adaptive`` trades on. The frontier length is padded to the
 next power of two (min ``SUBLANE``), so a solve whose frontier grows from
 1 to n retraces at most ``log2(n)`` distinct kernel shapes.
+
+The in-kernel scatter-add by unsorted row id does not lower on Mosaic, so
+the kernel runs only in interpret mode and raises ``NotImplementedError``
+on a TPU backend.
 """
 
 from __future__ import annotations
@@ -42,11 +46,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
     SUBLANE,
-    CompilerParams,
     DEFAULT_SCHEDULE,
     InfeasibleConfig,
     KernelSchedule,
     ceil_to,
+    compiler_params,
+    resolve_interpret,
 )
 
 
@@ -150,7 +155,7 @@ def csc_spmspv_pallas(
     n_rows: int,
     schedule: KernelSchedule,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """SpMSpV over padded column slices and a pre-padded frontier.
 
@@ -160,6 +165,10 @@ def csc_spmspv_pallas(
     scalar-prefetch SMEM. Returns ``y: (n_rows + 1,)`` (last slot =
     padding spill, truncated by the wrapper).
     """
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(
+            "csc_spmspv does not lower on TPU (in-kernel scatter-add by row id)"
+        )
     W = data.shape[1]
     nt = schedule.nnz_tile
     if W % nt:
@@ -185,10 +194,8 @@ def csc_spmspv_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows + 1,), xvals.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),  # carried y
-        ),
-        interpret=interpret,
+        compiler_params=compiler_params("arbitrary", "arbitrary"),  # carried y
+        interpret=True,
         name="csc_spmspv",
     )(active, xvals, data, rows)
 
@@ -199,7 +206,7 @@ def csc_spmspv(
     xvals: np.ndarray,
     schedule: KernelSchedule = DEFAULT_SCHEDULE,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Frontier-level wrapper: pads, dispatches, truncates the spill slot.
 

@@ -54,6 +54,7 @@ from repro.train.serve import (
     SpmvRequest,
     SpmvServer,
 )
+from repro.utils.compile_cache import configure_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.serve")
@@ -412,6 +413,7 @@ def main(argv=None):
                     help="capture the serving run with jax.profiler into "
                          "this directory (Perfetto/TensorBoard viewable)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.spmv:
         return serve_spmv(args)

@@ -37,6 +37,7 @@ from repro.sparse.generate import (
     generate_by_name,
     random_matrix,
 )
+from repro.utils.compile_cache import configure_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.solve")
@@ -214,6 +215,7 @@ def main(argv=None):
     ap.add_argument("--obs-instance", default="solve",
                     help="instance label stamped into exported shards")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     return run_solve(args)
 
 

@@ -17,8 +17,8 @@ never change between decode steps. ``SparseInferenceEngine`` is the bridge:
 Jit interplay: ``serve_optimize`` is host-side (numpy fingerprints, cache
 lookups) and format conversion materializes device arrays, so plans must be
 computed *eagerly* before a decode graph is traced (``plan_all``; a first
-eager ``matmul`` also works) — the prepared interpret-mode Pallas kernels
-are then traceable and live inside the jitted decode graph as constants.
+eager ``matmul`` also works) — the prepared Pallas kernels are then
+traceable and live inside the jitted decode graph as constants.
 This is also why the engine requires ``unroll_layers`` in
 ``models.model._run_blocks``: a ``lax.scan`` over stacked layer params
 cannot hold per-layer host-planned kernels.
@@ -223,7 +223,6 @@ class SparseInferenceEngine:
                     layer.weight_t,
                     served.fmt,
                     served.schedule.replace(accum_dtype="float32"),
-                    interpret=self.session.tuner.interpret,
                     memo_key=layer.fingerprint,
                 )
                 self.stats.fp32_recompiles += 1

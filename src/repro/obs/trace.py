@@ -225,34 +225,25 @@ def span(name: str, **attrs):
 
 
 class profile_capture:
-    """Optionally wrap a region in ``jax.profiler`` (Perfetto/TensorBoard).
+    """Wrap a region in ``jax.profiler`` (Perfetto/TensorBoard).
 
     ``with profile_capture("artifacts/profile"):`` captures every XLA/Pallas
-    launch inside into a trace a real viewer can open. Failures (no
-    profiler support in this jax build, a capture already running) degrade
-    to a logged warning — profiling is diagnostic, never load-bearing."""
+    launch inside into a trace a real viewer can open. A profile that was
+    asked for and cannot be taken (no profiler support, a capture already
+    running) raises: a run that claims to be profiled must be."""
 
     def __init__(self, log_dir: str | Path):
         self.log_dir = str(log_dir)
-        self._active = False
 
     def __enter__(self):
-        try:
-            import jax
+        import jax
 
-            jax.profiler.start_trace(self.log_dir)
-            self._active = True
-            log.info("jax profiler capture -> %s", self.log_dir)
-        except Exception as exc:
-            log.warning("profiler capture unavailable (%s); continuing", exc)
+        jax.profiler.start_trace(self.log_dir)
+        log.info("jax profiler capture -> %s", self.log_dir)
         return self
 
     def __exit__(self, *exc) -> bool:
-        if self._active:
-            try:
-                import jax
+        import jax
 
-                jax.profiler.stop_trace()
-            except Exception as stop_exc:
-                log.warning("profiler stop failed (%s)", stop_exc)
+        jax.profiler.stop_trace()
         return False

@@ -119,7 +119,6 @@ def compile_partitioned(
     dense: np.ndarray,
     plan: CompositePlan,
     *,
-    interpret: bool = True,
     memo_key: Hashable | None = None,
 ) -> PartitionedSpmv:
     """Compile every block of ``plan`` through the registry + kernel memo."""
@@ -136,7 +135,6 @@ def compile_partitioned(
                 bp.block.row_end,
                 bp.fmt,
                 bp.schedule,
-                interpret=interpret,
                 memo_key=memo_key,
             ),
         )
@@ -209,15 +207,12 @@ def compile_fused_partitioned(
     dense: np.ndarray,
     plan: CompositePlan,
     *,
-    interpret: bool = True,
     memo_key: Hashable | None = None,
 ) -> FusedPartitionedSpmv:
     """Lower ``plan`` to its single-launch executor (one memo entry)."""
     from repro.kernels.ops import compile_spmv_fused
 
-    kernel = compile_spmv_fused(
-        np.asarray(dense), plan, interpret=interpret, memo_key=memo_key
-    )
+    kernel = compile_spmv_fused(np.asarray(dense), plan, memo_key=memo_key)
     fused = FusedPartitionedSpmv(kernel, plan)
     log.info(
         "compiled fused partitioned kernel: %d block(s) -> %d work item(s) "
@@ -246,9 +241,7 @@ class ShardedPartitionedSpmv:
         *,
         schedule: KernelSchedule = DEFAULT_SCHEDULE,
         mesh=None,
-        interpret: bool = True,
     ):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding
 
         dense = np.asarray(dense)
@@ -290,18 +283,18 @@ class ShardedPartitionedSpmv:
 
         def _block_body(d, c, x):
             # local shard: (1, R, W) planes + the replicated (gathered) x
-            y = ell_spmv_pallas(d[0], c[0], x, schedule, interpret=interpret)
+            y = ell_spmv_pallas(d[0], c[0], x, schedule)
             return y[None, :]
 
         self._fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 _block_body,
                 mesh=self.mesh,
                 in_specs=(plane_spec, plane_spec, x_spec),
                 out_specs=y_spec,
                 # pallas_call has no shard_map replication rule; the body is
                 # purely local (no collectives), so the check adds nothing
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -327,7 +320,6 @@ def shard_partitioned(
     *,
     schedule: KernelSchedule | None = None,
     mesh=None,
-    interpret: bool = True,
 ) -> ShardedPartitionedSpmv:
     """Build the multi-device executor from a plan or a bare partition.
 
@@ -358,5 +350,4 @@ def shard_partitioned(
         partition,
         schedule=schedule or DEFAULT_SCHEDULE,
         mesh=mesh,
-        interpret=interpret,
     )

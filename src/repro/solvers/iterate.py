@@ -144,7 +144,6 @@ class IterativeSolver:
                 self.dense,
                 plan.fmt,
                 sched,
-                interpret=self.session.tuner.interpret,
                 memo_key=plan.fingerprint,
             )
             log.info(

@@ -9,12 +9,14 @@ across block-rows (power-law graphs), BCSR stores only the occupied blocks
 
 TPU adaptation mirrors the BELL kernel: ``block_cols`` is a scalar-prefetch
 operand whose BlockSpec index map DMAs exactly the 128-wide X panel each
-stored block needs, and each grid step is a dense (br, 128) x (128,) matvec
-on MXU shapes. Row compression is handled like the CSR kernel handles
-nonzeros: ``block_rows`` (also scalar-prefetched) scatter-accumulates each
-block's partial product into the VMEM-resident output, which persists
-across the sequential grid. Padding blocks carry ``block_row == n_block_rows``
-and land in a spill row that the wrapper truncates.
+stored block needs, and each grid step is a dense (1, 128) x (br, 128)^T
+product on the MXU. Row compression is handled like the CSR kernel handles
+tiles: ``block_rows`` (also scalar-prefetched, sorted) drives the output
+index map, so consecutive blocks of one block-row accumulate into the same
+lane-dense ``(1, br)`` output block. Block-rows without a stored block are
+never visited; the output aliases a zero-filled input, so they read zero.
+Padding blocks carry ``block_row == n_block_rows`` and land in a spill row
+that the wrapper truncates.
 
 This module is deliberately *plugin-shaped*: it touches none of the
 dispatch layers (ops / tuning_space / objectives / session / adaptive).
@@ -35,14 +37,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.bell import block_matvec, x_panels
 from repro.kernels.common import (
     LANE,
     SUBLANE,
     VMEM_BYTES,
-    CompilerParams,
     InfeasibleConfig,
     KernelSchedule,
     ceil_to,
+    compiler_params,
+    first_of_run,
+    resolve_interpret,
 )
 from repro.sparse.registry import (
     FormatSpec,
@@ -189,65 +194,65 @@ def spmv_bcsr(mat: BCSR, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _bcsr_kernel(bcols_ref, brows_ref, d_ref, x_ref, y_ref, *, accum_dtype):
-    del bcols_ref  # consumed by the X index map
+def _bcsr_kernel(bcols_ref, brows_ref, d_ref, x_ref, y0_ref, y_ref, *, accum_dtype):
+    del bcols_ref, y0_ref  # the X index map / the aliased zero output
     i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(first_of_run(brows_ref, i))
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    blk = d_ref[0].astype(accum_dtype)  # (br, bc)
-    xs = x_ref[0].astype(accum_dtype)  # (bc,)
-    v = jnp.dot(blk, xs, preferred_element_type=accum_dtype)  # MXU matvec
-    r = brows_ref[i]  # scatter target: this block's block-row
-    y = y_ref[...].astype(accum_dtype)
-    y_ref[...] = y.at[r].add(v).astype(y_ref.dtype)
+    v = block_matvec(x_ref[0], d_ref[0], accum_dtype)  # (1, br)
+    y_ref[...] += v.reshape(y_ref.shape).astype(y_ref.dtype)
 
 
 def bcsr_spmv_pallas(
     data: jax.Array,
     block_cols: jax.Array,
     block_rows: jax.Array,
-    x_panels: jax.Array,
+    x: jax.Array,
     n_block_rows: int,
     schedule: KernelSchedule,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """SpMV over flat BCSR storage.
 
     ``data: (nb_pad, br, bc)``, ``block_cols/block_rows: (nb_pad,)`` int32
-    (padding blocks: col 0 / row ``n_block_rows``), ``x_panels:
-    (n_col_blocks, bc)``. Returns ``y: (n_block_rows + 1, br)`` — the last
-    row is the padding spill, truncated by the wrapper.
+    in block-row order (padding blocks: col 0 / row ``n_block_rows``),
+    ``x: (n_cols,)``. Returns ``y: ((n_block_rows + 1) * br,)`` — the last
+    ``br`` entries are the padding spill, truncated by the wrapper.
     """
     nb_pad, br, bc = data.shape
     kernel = functools.partial(_bcsr_kernel, accum_dtype=schedule.jnp_accum_dtype)
+    out_shape = jax.ShapeDtypeStruct((n_block_rows + 1, 1, br), x.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb_pad,),
         in_specs=[
             pl.BlockSpec((1, br, bc), lambda i, bcols, brows: (i, 0, 0)),
             # scalar-prefetch-driven gather: DMA the X panel this block needs
-            pl.BlockSpec((1, bc), lambda i, bcols, brows: (bcols[i], 0)),
+            pl.BlockSpec((1, 1, bc), lambda i, bcols, brows: (bcols[i], 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # zero output, never read
         ],
-        # whole output resident in VMEM across the sequential grid (CSR-style
-        # stitching: a block-row split across grid steps accumulates for free)
-        out_specs=pl.BlockSpec(
-            (n_block_rows + 1, br), lambda i, bcols, brows: (0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, 1, br), lambda i, bcols, brows: (brows[i], 0, 0)),
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows + 1, br), x_panels.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",),  # carried accumulation
-        ),
-        interpret=interpret,
+        out_shape=out_shape,
+        compiler_params=compiler_params("arbitrary"),  # carried accumulation
+        input_output_aliases={4: 0},  # block-rows without blocks stay zero
+        interpret=resolve_interpret(interpret),
         name="bcsr_spmv",
-    )(block_cols, block_rows, data, x_panels)
+    )(
+        block_cols,
+        block_rows,
+        data,
+        x_panels(x, bc),
+        jnp.zeros(out_shape.shape, out_shape.dtype),
+    )
+    return y.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -263,18 +268,17 @@ def _blocks_per_tile(schedule: KernelSchedule) -> int:
 
 def _bcsr_prepare(dense: np.ndarray, schedule: KernelSchedule) -> BCSR:
     dense = np.asarray(dense)
-    n_rows, n_cols = dense.shape
     br = min(schedule.rows_per_block, 256)
-    nbr = ceil_to(n_rows, br) // br
-    occ_bound = min((dense != 0).sum(), nbr * (ceil_to(n_cols, LANE) // LANE))
-    check_storage_bytes(int(occ_bound) * br * LANE * 8, "BCSR")
+    # the true stored size: the occupied blocks, padded to the tile quantum
+    n_blocks, _ = MatrixStats(dense).block_occupancy(br, LANE)
+    stored = ceil_to(max(n_blocks, 1), _blocks_per_tile(schedule))
+    check_storage_bytes(stored * (br * LANE + 2) * 4, "BCSR")
     return bcsr_from_dense(
         dense, br=br, bc=LANE, pad_blocks_to=_blocks_per_tile(schedule)
     )
 
 
-def _bcsr_spmv(mat: BCSR, x, schedule: KernelSchedule, *, interpret: bool = True):
-    n_rows, n_cols = mat.shape
+def _bcsr_spmv(mat: BCSR, x, schedule: KernelSchedule):
     bpt = _blocks_per_tile(schedule)
     if mat.data.shape[0] % bpt:
         raise InfeasibleConfig(
@@ -282,18 +286,11 @@ def _bcsr_spmv(mat: BCSR, x, schedule: KernelSchedule, *, interpret: bool = True
             f"nnz_tile={schedule.nnz_tile} storage quantum ({bpt} blocks); "
             "convert with prepare(..., schedule)"
         )
-    x = jnp.asarray(x)
-    xp = jnp.zeros(ceil_to(n_cols, mat.bc), x.dtype).at[:n_cols].set(x)
+    n_block_rows = mat.block_ptr.shape[0] - 1
     y = bcsr_spmv_pallas(
-        mat.data,
-        mat.block_cols,
-        mat.block_rows,
-        xp.reshape(-1, mat.bc),
-        mat.n_block_rows,
-        schedule,
-        interpret=interpret,
+        mat.data, mat.block_cols, mat.block_rows, x, n_block_rows, schedule
     )
-    return y[: mat.n_block_rows].reshape(-1)[:n_rows]
+    return y[: mat.shape[0]]
 
 
 def _bcsr_footprint(stats: MatrixStats, schedule: KernelSchedule) -> KernelFootprint:

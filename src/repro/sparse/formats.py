@@ -9,14 +9,16 @@ run-time mode explicitly performs conversion on the CPU and *measures* it
 TPU adaptation notes (DESIGN.md §2):
 
 * ``CSR`` carries a ``row_ids`` companion (COO expansion of ``indptr``) —
-  the flat segmented-sum kernel that replaces GPU scalar/vector-CSR needs
+  the segmented-reduction kernel that replaces GPU scalar/vector-CSR needs
   per-nonzero row ids. ``nbytes_core`` excludes companions so that format
-  size comparisons match the textbook definition.
+  size comparisons match the textbook definition. Kernel-ready storage
+  (``tiling != (0, 0)``) pads each row block's stream with explicit zeros.
 * ``BELL`` blocks default to 8×128 (sublane × lane) instead of the paper's
   GPU 2×2, so a stored block times an X segment is an MXU-shaped matmul.
-* ``SELL`` keeps true ragged storage (flat data + slice pointers); slice
-  widths are padded to the TPU lane quantum (128) rather than 1 — the
-  SELL-C-sigma adaptation for 8×128 vector registers.
+* ``SELL`` keeps true ragged storage (column-major slice planes stacked
+  into one ``(total / C, C)`` plane + slice pointers); slice widths are
+  padded to the TPU lane quantum (128) rather than 1 — the SELL-C-sigma
+  adaptation for 8×128 vector registers.
 """
 
 from __future__ import annotations
@@ -48,13 +50,23 @@ def _nbytes(*arrays) -> int:
 @jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class CSR:
-    """Compressed Sparse Row. ``row_ids`` is the kernel-facing companion."""
+    """Compressed Sparse Row. ``row_ids`` is the kernel-facing companion.
 
-    data: jax.Array  # (nnz,) nonzero values
-    indices: jax.Array  # (nnz,) column index per nonzero
+    ``tiling`` is the ``(rows_per_block, nnz_tile)`` geometry the stored
+    stream is aligned to (``csr_tiled``): each block of ``rows_per_block``
+    rows owns a whole number of ``nnz_tile`` tiles, at least one, its tail
+    padded with explicit zeros (column 0) in the block's last row. ``(0,
+    0)`` marks canonical, unpadded storage (``csr_from_dense``).
+    """
+
+    data: jax.Array  # (nnz,) stored values
+    indices: jax.Array  # (nnz,) column index per stored value
     indptr: jax.Array  # (n_rows + 1,) row boundaries
-    row_ids: jax.Array  # (nnz,) row index per nonzero (COO companion)
+    row_ids: jax.Array  # (nnz,) row index per stored value (COO companion)
     shape: tuple[int, int] = dataclasses.field(metadata=dict(static=True))
+    tiling: tuple[int, int] = dataclasses.field(
+        default=(0, 0), metadata=dict(static=True)
+    )
 
     @property
     def nnz(self) -> int:
@@ -128,25 +140,27 @@ class BELL:
 class SELL:
     """Sliced ELL (SELL-C-q): slices of C rows, per-slice padded width.
 
-    True ragged storage: ``data``/``cols`` are flat concatenations of
-    *column-major* (width_s, C) slice planes — element (row r, k-th stored
-    nonzero) of slice s lives at ``slice_ptr[s] + k * C + r``. Column-major
-    slices make every width-tile of a slice a contiguous ``nnz_tile * C``
-    chunk, which is what lets the Pallas kernel address tiles with a plain
-    BlockSpec index driven by scalar-prefetched slice pointers (DESIGN.md
-    §2). ``slice_ptr[s]`` is the flat element offset of slice s;
-    ``slice_width[s] = (slice_ptr[s+1] - slice_ptr[s]) / C``. Widths are
-    padded to the lane quantum ``q``. ``row_ids`` is the oracle-facing
-    companion (row per element, == n_rows on padding slots).
+    True ragged storage: ``data``/``cols`` stack the *column-major*
+    (width_s, C) slice planes into one ``(total / C, C)`` plane — element
+    (row r, k-th stored nonzero) of slice s lives at flat offset
+    ``slice_ptr[s] + k * C + r``, i.e. at ``[slice_ptr[s] / C + k, r]``.
+    Column-major slices make every width-tile of a slice a contiguous
+    ``(nnz_tile, C)`` block, which is what lets the Pallas kernel address
+    tiles with a plain BlockSpec (DESIGN.md §2). ``slice_ptr[s]`` is the
+    flat element offset of slice s; ``slice_width[s] = (slice_ptr[s+1] -
+    slice_ptr[s]) / C``. Widths are padded to the lane quantum ``q``.
+    ``row_ids`` is the oracle-facing companion (row per element, == n_rows
+    on padding slots).
     """
 
-    data: jax.Array  # (total,)
-    cols: jax.Array  # (total,) int32
+    data: jax.Array  # (total / C, C)
+    cols: jax.Array  # (total / C, C) int32
     slice_ptr: jax.Array  # (n_slices + 1,) int32, element offsets
     slice_width: jax.Array  # (n_slices,) int32
-    row_ids: jax.Array  # (total,) int32, == n_rows on padding slots
+    row_ids: jax.Array  # (total / C, C) int32, == n_rows on padding slots
     shape: tuple[int, int] = dataclasses.field(metadata=dict(static=True))
     C: int = dataclasses.field(metadata=dict(static=True))
+    q: int = dataclasses.field(default=LANE, metadata=dict(static=True))
 
     @property
     def n_slices(self) -> int:
@@ -187,6 +201,41 @@ def csr_from_dense(dense: np.ndarray, dtype=np.float32) -> CSR:
         indptr=jax.numpy.asarray(indptr),
         row_ids=jax.numpy.asarray(rows.astype(np.int32)),
         shape=(n_rows, n_cols),
+    )
+
+
+def csr_tiled(
+    dense: np.ndarray, rows_per_block: int, nnz_tile: int, dtype=np.float32
+) -> CSR:
+    """CSR whose stream is aligned to ``(rows_per_block, nnz_tile)`` tiles
+    (see ``CSR.tiling``) — the layout the Pallas CSR kernel walks."""
+    dense = np.asarray(dense)
+    n_rows, n_cols = dense.shape
+    rpb, nt = rows_per_block, nnz_tile
+    rows, cols = np.nonzero(dense)
+    n_blocks = max(-(-n_rows // rpb), 1)
+    blk = rows // rpb
+    counts = np.bincount(blk, minlength=n_blocks)
+    stored = np.maximum(-(-counts // nt), 1) * nt  # >= 1 tile per block
+    offsets = np.concatenate([[0], np.cumsum(stored)])
+    starts = np.concatenate([[0], np.cumsum(counts)])
+    pos = offsets[blk] + np.arange(rows.size) - starts[blk]
+    last_row = np.minimum((np.arange(n_blocks) + 1) * rpb, max(n_rows, 1)) - 1
+    data = np.zeros(int(offsets[-1]), dtype=dtype)
+    indices = np.zeros(int(offsets[-1]), dtype=np.int32)
+    row_ids = np.repeat(last_row, stored).astype(np.int32)
+    data[pos] = dense[rows, cols]
+    indices[pos] = cols
+    row_ids[pos] = rows
+    indptr = np.zeros(n_rows + 1, dtype=np.int32)
+    np.cumsum(np.bincount(row_ids, minlength=n_rows)[:n_rows], out=indptr[1:])
+    return CSR(
+        data=jax.numpy.asarray(data),
+        indices=jax.numpy.asarray(indices),
+        indptr=jax.numpy.asarray(indptr),
+        row_ids=jax.numpy.asarray(row_ids),
+        shape=(n_rows, n_cols),
+        tiling=(rpb, nt),
     )
 
 
@@ -270,13 +319,14 @@ def sell_from_dense(
         cols[base : base + C * w] = plane_c.T.ravel()
         row_ids[base : base + C * w] = plane_r.T.ravel()
     return SELL(
-        data=jax.numpy.asarray(data),
-        cols=jax.numpy.asarray(cols),
+        data=jax.numpy.asarray(data.reshape(-1, C)),
+        cols=jax.numpy.asarray(cols.reshape(-1, C)),
         slice_ptr=jax.numpy.asarray(slice_ptr),
         slice_width=jax.numpy.asarray(widths),
-        row_ids=jax.numpy.asarray(row_ids),
+        row_ids=jax.numpy.asarray(row_ids.reshape(-1, C)),
         shape=(n_rows, n_cols),
         C=C,
+        q=q,
     )
 
 
@@ -286,7 +336,10 @@ def _empty_dense(mat) -> np.ndarray:
 
 def csr_to_dense(mat: CSR) -> np.ndarray:
     out = _empty_dense(mat)
-    out[np.asarray(mat.row_ids), np.asarray(mat.indices)] = np.asarray(mat.data)
+    # add, not assign: tiled storage repeats (row, 0) for its explicit zeros
+    np.add.at(
+        out, (np.asarray(mat.row_ids), np.asarray(mat.indices)), np.asarray(mat.data)
+    )
     return out
 
 
@@ -318,12 +371,12 @@ def bell_to_dense(mat: BELL) -> np.ndarray:
 def sell_to_dense(mat: SELL) -> np.ndarray:
     out = _empty_dense(mat)
     n_rows = mat.shape[0]
-    rid = np.asarray(mat.row_ids)
+    rid = np.asarray(mat.row_ids).ravel()
     valid = rid < n_rows
     np.add.at(
         out,
-        (rid[valid], np.asarray(mat.cols)[valid]),
-        np.asarray(mat.data)[valid],
+        (rid[valid], np.asarray(mat.cols).ravel()[valid]),
+        np.asarray(mat.data).ravel()[valid],
     )
     return out
 

@@ -21,7 +21,7 @@ Adding a format is one call::
         from_dense=myfmt_from_dense,
         to_dense=myfmt_to_dense,
         prepare=my_prepare,         # (dense, schedule) -> MyFmt, aligned
-        spmv=my_spmv,               # (mat, x, schedule, *, interpret) -> y
+        spmv=my_spmv,               # (mat, x, schedule) -> y, jit-traceable
         reference=my_reference,     # pure-jnp oracle, (mat, x) -> y
         footprint=my_footprint,     # (MatrixStats, schedule) -> KernelFootprint
     ))
@@ -37,6 +37,10 @@ Contract notes for plugin authors (enforced by the shared suite in
 * ``from_dense``/``to_dense`` must round-trip exactly;
 * ``prepare`` aligns storage geometry to the ``KernelSchedule`` and raises
   ``InfeasibleConfig`` when storage would blow up (``check_storage_bytes``);
+  all padding and pointer arithmetic happens there, on the host, once;
+* ``spmv`` is traced under ``jax.jit`` (``kernels.ops.spmv_pallas``): it
+  may only read static metadata and shapes of the container, never its
+  values, so a call moves no matrix storage through the host;
 * ``spmv`` on storage prepared with a *different* schedule must either
   compute the exact result or raise ``InfeasibleConfig`` — never silently
   corrupt;
@@ -167,7 +171,7 @@ class FormatSpec:
     from_dense: Callable  # (dense, **kw) -> container
     to_dense: Callable  # (mat) -> np.ndarray (exact inverse)
     prepare: Callable  # (dense, KernelSchedule) -> container, tile-aligned
-    spmv: Callable  # (mat, x, KernelSchedule, *, interpret) -> y
+    spmv: Callable  # (mat, x, KernelSchedule) -> y, traced under jax.jit
     reference: Callable  # (mat, x) -> y — pure-jnp oracle
     footprint: Callable  # (MatrixStats, KernelSchedule) -> KernelFootprint
     priority: int = 100
@@ -284,6 +288,7 @@ from repro.sparse.formats import (  # noqa: E402
     bell_from_dense,
     bell_to_dense,
     csr_from_dense,
+    csr_tiled,
     csr_to_dense,
     ell_from_dense,
     ell_to_dense,
@@ -304,25 +309,24 @@ _VAL_B, _IDX_B = 4.0, 4.0  # fp32 values, int32 indices
 
 
 def _csr_prepare(dense: np.ndarray, schedule: KernelSchedule) -> CSR:
-    return csr_from_dense(np.asarray(dense))
+    dense = np.asarray(dense)
+    rpb, nt = schedule.rows_per_block, schedule.nnz_tile
+    counts = (dense != 0).sum(axis=1)
+    per_block = np.add.reduceat(counts, np.arange(0, max(len(counts), 1), rpb))
+    stored = int((np.maximum(-(-per_block // nt), 1) * nt).sum())
+    check_storage_bytes(stored * 3 * 4, "CSR")
+    return csr_tiled(dense, rpb, nt)
 
 
-def _csr_spmv(mat: CSR, x, schedule: KernelSchedule, *, interpret: bool = True):
+def _csr_spmv(mat: CSR, x, schedule: KernelSchedule):
+    if mat.tiling == (0, 0):
+        raise InfeasibleConfig(
+            "CSR storage is not tile-aligned; convert with prepare(..., schedule)"
+        )
     n_rows, _ = mat.shape
-    nt = schedule.nnz_tile
-    nnz = mat.data.shape[0]
-    nnz_pad = ceil_to(max(nnz, 1), nt)
-    data = pad_axis(np.asarray(mat.data), 0, nnz_pad)
-    indices = pad_axis(np.asarray(mat.indices), 0, nnz_pad)
-    row_ids = pad_axis(np.asarray(mat.row_ids), 0, nnz_pad, fill=n_rows)
+    # the stream's own tiling fixes the geometry; the schedule the numerics
     y = csr_spmv_pallas(
-        jnp.asarray(data),
-        jnp.asarray(indices),
-        jnp.asarray(row_ids),
-        jnp.asarray(x),
-        n_rows,
-        schedule,
-        interpret=interpret,
+        mat.data, mat.indices, mat.row_ids, x, n_rows, mat.tiling, schedule
     )
     return y[:n_rows]
 
@@ -362,7 +366,7 @@ def _ell_prepare(dense: np.ndarray, schedule: KernelSchedule) -> ELL:
     return ELL(jnp.asarray(data), jnp.asarray(cols), shape=mat.shape)
 
 
-def _ell_spmv(mat: ELL, x, schedule: KernelSchedule, *, interpret: bool = True):
+def _ell_spmv(mat: ELL, x, schedule: KernelSchedule):
     n_rows, _ = mat.shape
     rpb, nt = schedule.rows_per_block, schedule.nnz_tile
     R, W = mat.data.shape
@@ -371,8 +375,7 @@ def _ell_spmv(mat: ELL, x, schedule: KernelSchedule, *, interpret: bool = True):
             f"ELL planes ({R},{W}) not aligned to schedule ({rpb},{nt}); "
             "use prepare() with the same schedule"
         )
-    y = ell_spmv_pallas(mat.data, mat.cols, jnp.asarray(x), schedule, interpret=interpret)
-    return y[:n_rows]
+    return ell_spmv_pallas(mat.data, mat.cols, x, schedule)[:n_rows]
 
 
 def _ell_footprint(stats: MatrixStats, schedule: KernelSchedule) -> KernelFootprint:
@@ -399,22 +402,18 @@ def _ell_footprint(stats: MatrixStats, schedule: KernelSchedule) -> KernelFootpr
 
 def _bell_prepare(dense: np.ndarray, schedule: KernelSchedule) -> BELL:
     dense = np.asarray(dense)
-    n_rows, n_cols = dense.shape
     br = min(schedule.rows_per_block, 256)
-    nbr = ceil_to(n_rows, br) // br
-    # upper-bound occupancy estimate before materializing
-    occ_bound = min((dense != 0).sum(), nbr * (ceil_to(n_cols, LANE) // LANE))
-    check_storage_bytes(int(occ_bound) * br * LANE * 8 // max(nbr, 1) * nbr, "BELL")
+    # the true stored size, before materializing: every block-row holds as
+    # many (br x 128) blocks as the fullest one
+    _, max_blocks = MatrixStats(dense).block_occupancy(br, LANE)
+    nbr = ceil_to(dense.shape[0], br) // br
+    stored_blocks = nbr * max(max_blocks, 1)
+    check_storage_bytes(stored_blocks * (br * LANE + 1) * 4, "BELL")
     return bell_from_dense(dense, br=br, bc=LANE)
 
 
-def _bell_spmv(mat: BELL, x, schedule: KernelSchedule, *, interpret: bool = True):
-    n_rows, n_cols = mat.shape
-    x = jnp.asarray(x)
-    xp = jnp.zeros(ceil_to(n_cols, mat.bc), x.dtype).at[:n_cols].set(x)
-    x_panels = xp.reshape(-1, mat.bc)
-    y = bell_spmv_pallas(mat.data, mat.block_cols, x_panels, schedule, interpret=interpret)
-    return y.reshape(-1)[:n_rows]
+def _bell_spmv(mat: BELL, x, schedule: KernelSchedule):
+    return bell_spmv_pallas(mat.data, mat.block_cols, x, schedule)[: mat.shape[0]]
 
 
 def _bell_footprint(stats: MatrixStats, schedule: KernelSchedule) -> KernelFootprint:
@@ -449,33 +448,15 @@ def _sell_prepare(dense: np.ndarray, schedule: KernelSchedule) -> SELL:
     )
 
 
-def _sell_spmv(mat: SELL, x, schedule: KernelSchedule, *, interpret: bool = True):
-    n_rows, _ = mat.shape
+def _sell_spmv(mat: SELL, x, schedule: KernelSchedule):
     nt = schedule.nnz_tile
-    C = mat.C
-    blk = nt * C
-    sp = np.asarray(mat.slice_ptr)
-    sw = np.asarray(mat.slice_width)
-    if mat.data.shape[0] % blk or (sp % blk).any() or (sw % nt).any():
+    if mat.q % nt:
         raise InfeasibleConfig(
-            f"SELL storage quantum mismatch with nnz_tile={nt}; "
+            f"SELL widths padded to {mat.q}, not a multiple of nnz_tile={nt}; "
             "convert with prepare(..., schedule) so widths are nt-aligned"
         )
-    width_tiles = (sw // nt).astype(np.int32)
-    tile_ptr = (sp[:-1] // blk).astype(np.int32)
-    y = sell_spmv_pallas(
-        mat.data,
-        mat.cols,
-        jnp.asarray(tile_ptr),
-        jnp.asarray(width_tiles),
-        jnp.asarray(x),
-        n_slices=mat.n_slices,
-        C=C,
-        max_width_tiles=int(width_tiles.max(initial=1)),
-        schedule=schedule,
-        interpret=interpret,
-    )
-    return y.reshape(-1)[:n_rows]
+    y = sell_spmv_pallas(mat.data, mat.cols, mat.slice_width, x, schedule)
+    return y[: mat.shape[0]]
 
 
 def _sell_footprint(stats: MatrixStats, schedule: KernelSchedule) -> KernelFootprint:

@@ -57,7 +57,13 @@ def _sell_impl(data, cols, row_ids, x, *, n_rows):
 
 
 def spmv_sell(mat: SELL, x: jax.Array) -> jax.Array:
-    return _sell_impl(mat.data, mat.cols, mat.row_ids, x, n_rows=mat.shape[0])
+    return _sell_impl(
+        mat.data.reshape(-1),
+        mat.cols.reshape(-1),
+        mat.row_ids.reshape(-1),
+        x,
+        n_rows=mat.shape[0],
+    )
 
 
 _DISPATCH = {CSR: spmv_csr, ELL: spmv_ell, BELL: spmv_bell, SELL: spmv_sell}

@@ -123,6 +123,18 @@ def test_save_load_round_trip(tmp_path):
         CalibratedCostModel.load(path)
 
 
+def test_load_refuses_unknown_hardware(tmp_path):
+    """A calibration saved for hardware this build does not know must not
+    silently be applied to another chip's model."""
+    path = tmp_path / "cal.json"
+    CalibratedCostModel.fit({"csr": [(1e-5, 2e-4)]}, hw=TPU_V4).save(path)
+    raw = path.read_text().replace('"tpu_v4"', '"tpu_v99"')
+    path.write_text(raw)
+    with pytest.raises(ValueError, match="unknown hardware 'tpu_v99'"):
+        CalibratedCostModel.load(path)
+    assert CalibratedCostModel.load(path, hw=TPU_V4).hw is TPU_V4
+
+
 # --------------------------------------------------------------- plan flip
 
 

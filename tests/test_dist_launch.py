@@ -4,10 +4,10 @@ roofline math (the dry-run pieces that don't need 512 devices)."""
 import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, applicable, cells_for, get_config
-from repro.dist.sharding import abstract_mesh, spec_for
+from repro.dist.sharding import spec_for
 from repro.launch.hlo_analysis import (
     CollectiveOp,
     parse_collectives,
@@ -15,10 +15,8 @@ from repro.launch.hlo_analysis import (
 )
 from repro.launch.specs import model_flops, train_batch_specs
 
-# abstract_mesh() papers over the AbstractMesh constructor change across jax
-# releases (pairs tuple in <=0.4.x, (sizes, names) afterwards)
-MESH1 = abstract_mesh((16, 16), ("data", "model"))
-MESH2 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 # ------------------------------------------------------------- sharding rules

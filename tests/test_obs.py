@@ -25,6 +25,7 @@ from repro.obs.trace import (
     Tracer,
     get_tracer,
     load_spans,
+    profile_capture,
     span_children,
 )
 from repro.sparse.generate import random_matrix
@@ -125,6 +126,14 @@ def test_disabled_tracer_and_registry_are_noops():
     g = reg.gauge("g")
     g.set(3.0)
     assert math.isnan(g.value)
+
+
+def test_profile_capture_raises_when_a_profile_cannot_be_taken(tmp_path):
+    """A run that asked for a profile must not carry on without one."""
+    with profile_capture(tmp_path / "outer"):
+        with pytest.raises(RuntimeError):
+            with profile_capture(tmp_path / "inner"):
+                pass
 
 
 def test_trace_jsonl_roundtrip_with_torn_line(tmp_path):

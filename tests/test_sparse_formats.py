@@ -77,7 +77,7 @@ def test_sell_storage_invariants(dense):
     sw = np.asarray(mat.slice_width)
     assert (np.diff(sp) == sw * mat.C).all()
     assert (sw % 128 == 0).all()  # lane-quantum padding
-    assert mat.data.shape[0] == sp[-1]
+    assert mat.data.shape == (sp[-1] // mat.C, mat.C)  # stacked slice planes
 
 
 @given(dense=dense_strategy)

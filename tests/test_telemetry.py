@@ -20,7 +20,7 @@ from repro.core import (
 )
 from repro.core.cache import CacheEntry
 from repro.core.predictor import OBJECTIVES
-from repro.kernels.common import DEFAULT_SCHEDULE
+from repro.kernels.common import DEFAULT_SCHEDULE, InfeasibleConfig
 from repro.sparse.generate import random_matrix
 from repro.telemetry import (
     AdaptiveConfig,
@@ -312,7 +312,7 @@ def test_serve_optimize_falls_back_when_exploration_infeasible(monkeypatch):
 
     def explode_non_csr(d, fp, fmt, schedule):
         if fmt != "csr":
-            raise ValueError(f"{fmt} storage would be huge")
+            raise InfeasibleConfig(f"{fmt} storage would be huge")
         return orig(d, fp, fmt, schedule)
 
     monkeypatch.setattr(session, "_compile", explode_non_csr)
