@@ -56,6 +56,7 @@ from repro.kernels.ops import (
     kernel_memoized,
     matrix_fingerprint,
 )
+from repro.obs.trace import counted_span as _counted_span
 from repro.obs.trace import span as _span
 from repro.sparse.registry import default_format, format_names
 from repro.utils.logging import get_logger
@@ -243,10 +244,18 @@ class AutoSpmvSession:
         self._pred_memo: dict[tuple[str, str, str], float] = {}
 
     # ------------------------------------------------------------- internals
+    @staticmethod
+    def _fingerprint(dense: np.ndarray) -> str:
+        """``matrix_fingerprint`` under a ``session.fingerprint`` span: the
+        hash reads every byte of the dense input."""
+        dense = np.asarray(dense)
+        with _counted_span("session.fingerprint", bytes=int(dense.nbytes)):
+            return matrix_fingerprint(dense)
+
     def _analyze(
         self, dense: np.ndarray, fingerprint: str | None = None
     ) -> tuple[str, SparsityFeatures, str]:
-        fp = fingerprint if fingerprint is not None else matrix_fingerprint(dense)
+        fp = fingerprint if fingerprint is not None else self._fingerprint(dense)
         cached = self._feat_memo.get(fp)
         if cached is not None:
             self._feat_memo.move_to_end(fp)
@@ -448,7 +457,7 @@ class AutoSpmvSession:
         """
         if mode not in ("compile", "run"):
             raise ValueError(f"mode must be 'compile' or 'run', got {mode!r}")
-        fps = [matrix_fingerprint(np.asarray(m)) for m in mats]
+        fps = [self._fingerprint(m) for m in mats]
         unique: dict[str, object] = {}
         for fp, m in zip(fps, mats):
             if fp in unique:
@@ -816,62 +825,63 @@ class AutoSpmvSession:
         this degrades to ``compile_time_optimize`` plus plan identity, so
         telemetry-only deployments record without changing any decision.
         """
-        fp, feats, bucket = self._analyze(dense, fingerprint)
-        key = self.plan_key(feats, objective)
-        pre_existing = self.cache.peek(*key) is not None
-        base = self.compile_time_optimize(dense, objective, fingerprint=fp)
-        default_fmt = default_format()
-        fmt, exploratory = default_fmt, False
-        if self.adaptive is not None:
-            incumbent = self._incumbent_format(feats, bucket, objective)
-            fmt, exploratory = self.adaptive.choose(
-                bucket,
-                objective,
-                incumbent,
-                format_names(),
-                prior_value=self._predicted_latency(
-                    feats, bucket, objective, incumbent, base.schedule
+        with _span("session.serve", objective=objective):
+            fp, feats, bucket = self._analyze(dense, fingerprint)
+            key = self.plan_key(feats, objective)
+            pre_existing = self.cache.peek(*key) is not None
+            base = self.compile_time_optimize(dense, objective, fingerprint=fp)
+            default_fmt = default_format()
+            fmt, exploratory = default_fmt, False
+            if self.adaptive is not None:
+                incumbent = self._incumbent_format(feats, bucket, objective)
+                fmt, exploratory = self.adaptive.choose(
+                    bucket,
+                    objective,
+                    incumbent,
+                    format_names(),
+                    prior_value=self._predicted_latency(
+                        feats, bucket, objective, incumbent, base.schedule
+                    ),
+                )
+                if exploratory:
+                    self.stats.explorations += 1
+            if fmt == default_fmt:
+                kernel = base.kernel
+            else:
+                try:
+                    kernel = self._compile(dense, fp, fmt, base.schedule)
+                except InfeasibleConfig as exc:
+                    # an exploratory format can be infeasible for this matrix
+                    # (storage blow-up, tile mismatch): serving must not fail on
+                    # a bandit probe — fall back to the compile-time default-
+                    # format kernel and retire the arm so the failure is paid
+                    # once, not per request
+                    log.warning(
+                        "serve: %s infeasible for bucket %s (%s); serving %s",
+                        fmt,
+                        bucket,
+                        exc,
+                        default_fmt,
+                    )
+                    if self.adaptive is not None:
+                        self.adaptive.disable(bucket, objective, fmt)
+                    fmt, exploratory, kernel = default_fmt, False, base.kernel
+            return ServedPlan(
+                fingerprint=fp,
+                features=feats,
+                bucket=bucket,
+                objective=objective,
+                fmt=fmt,
+                schedule=base.schedule,
+                kernel=kernel,
+                predicted=dict(base.predicted),
+                plan_id="/".join(key),
+                exploratory=exploratory,
+                cache_hit=pre_existing,
+                predicted_s=self._predicted_latency(
+                    feats, bucket, objective, fmt, base.schedule
                 ),
             )
-            if exploratory:
-                self.stats.explorations += 1
-        if fmt == default_fmt:
-            kernel = base.kernel
-        else:
-            try:
-                kernel = self._compile(dense, fp, fmt, base.schedule)
-            except InfeasibleConfig as exc:
-                # an exploratory format can be infeasible for this matrix
-                # (storage blow-up, tile mismatch): serving must not fail on
-                # a bandit probe — fall back to the compile-time default-
-                # format kernel and retire the arm so the failure is paid
-                # once, not per request
-                log.warning(
-                    "serve: %s infeasible for bucket %s (%s); serving %s",
-                    fmt,
-                    bucket,
-                    exc,
-                    default_fmt,
-                )
-                if self.adaptive is not None:
-                    self.adaptive.disable(bucket, objective, fmt)
-                fmt, exploratory, kernel = default_fmt, False, base.kernel
-        return ServedPlan(
-            fingerprint=fp,
-            features=feats,
-            bucket=bucket,
-            objective=objective,
-            fmt=fmt,
-            schedule=base.schedule,
-            kernel=kernel,
-            predicted=dict(base.predicted),
-            plan_id="/".join(key),
-            exploratory=exploratory,
-            cache_hit=pre_existing,
-            predicted_s=self._predicted_latency(
-                feats, bucket, objective, fmt, base.schedule
-            ),
-        )
 
     def observe(self, plan: ServedPlan, measured_s: float) -> None:
         """Feed one measured execution back: record, update the bandit, and

@@ -31,6 +31,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128  # TPU vector lane quantum — the single source of truth
 SUBLANE = 8  # TPU sublane quantum (sparse/formats re-exports both)
+GATHER_SCOPE = "spmv.gather"  # named scope of the XLA gather of x in every SpMV
 
 
 class InfeasibleConfig(ValueError):

@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
+    GATHER_SCOPE,
     KernelSchedule,
     compiler_params,
     first_of_run,
@@ -89,7 +90,8 @@ def csr_spmv_pallas(
     n_blocks = -(-n_rows // rpb)
     tiles = lambda a: a.reshape(n_tiles, 1, nt)  # noqa: E731
     block_of_tile = row_ids[::nt] // rpb
-    xg = jnp.take(x, tiles(indices), axis=0)  # XLA gather, tile-shaped
+    with jax.named_scope(GATHER_SCOPE):
+        xg = jnp.take(x, tiles(indices), axis=0)  # XLA gather, tile-shaped
     kernel = functools.partial(
         _csr_kernel,
         rpb=rpb,

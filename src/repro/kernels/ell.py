@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.common import (
+    GATHER_SCOPE,
     KernelSchedule,
     compiler_params,
     resolve_interpret,
@@ -55,7 +56,8 @@ def ell_spmv_pallas(
     rpb, nt = schedule.rows_per_block, schedule.nnz_tile
     if R % rpb or W % nt:
         raise ValueError(f"ELL planes ({R},{W}) not aligned to ({rpb},{nt})")
-    xg = jnp.take(x, cols, axis=0)  # XLA gather: one extra (R, W) plane
+    with jax.named_scope(GATHER_SCOPE):
+        xg = jnp.take(x, cols, axis=0)  # XLA gather: one extra (R, W) plane
     kernel = functools.partial(
         _ell_kernel, unroll=schedule.unroll, accum_dtype=schedule.jnp_accum_dtype
     )

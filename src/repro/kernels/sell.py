@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
+    GATHER_SCOPE,
     KernelSchedule,
     compiler_params,
     first_of_run,
@@ -79,7 +80,8 @@ def sell_spmv_pallas(
         slice_width // nt,
         total_repeat_length=n_tiles,
     )
-    xg = jnp.take(x, cols, axis=0)  # XLA gather: one extra storage plane
+    with jax.named_scope(GATHER_SCOPE):
+        xg = jnp.take(x, cols, axis=0)  # XLA gather: one extra storage plane
     kernel = functools.partial(
         _sell_kernel, unroll=schedule.unroll, accum_dtype=schedule.jnp_accum_dtype
     )

@@ -17,7 +17,10 @@ diagonal dominance margin) since CG's contract requires one.
 
 Convergence metadata is always written as JSON (default
 ``artifacts/solve/SOLVE_<solver>_<matrix>.json``) so CI and fleets can
-assert on the emitted artifact rather than parse logs.
+assert on the emitted artifact rather than parse logs. With
+``--profile-dir`` the solve runs under ``jax.profiler``: the capture holds
+the solve's spans (``solver.solve`` and below, ``obs/trace.py``) on its
+host plane beside the device's ops, on one clock.
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from contextlib import nullcontext
 from pathlib import Path
 
 import numpy as np
@@ -93,37 +97,43 @@ def run_solve(args):
 
     from repro.solvers import cg, pagerank, power_iteration
 
-    if args.solver == "pagerank":
-        result = pagerank(
-            session,
-            dense,
-            damping=args.damping,
-            tol=args.tol,
-            max_iters=args.max_iters,
-            policy=policy,
-            objective=args.objective,
-        )
-    elif args.solver == "cg":
-        rng = np.random.default_rng(args.seed)
-        b = rng.standard_normal(n).astype(np.float32)
-        result = cg(
-            session,
-            spd_operator(dense),
-            b,
-            tol=args.tol,
-            max_iters=args.max_iters,
-            policy=policy,
-            objective=args.objective,
-        )
-    else:
-        result = power_iteration(
-            session,
-            dense,
-            tol=args.tol,
-            max_iters=args.max_iters,
-            policy=policy,
-            objective=args.objective,
-        )
+    capture = nullcontext()
+    if args.profile_dir:
+        from repro.obs import profile_capture
+
+        capture = profile_capture(args.profile_dir)
+    with capture:
+        if args.solver == "pagerank":
+            result = pagerank(
+                session,
+                dense,
+                damping=args.damping,
+                tol=args.tol,
+                max_iters=args.max_iters,
+                policy=policy,
+                objective=args.objective,
+            )
+        elif args.solver == "cg":
+            rng = np.random.default_rng(args.seed)
+            b = rng.standard_normal(n).astype(np.float32)
+            result = cg(
+                session,
+                spd_operator(dense),
+                b,
+                tol=args.tol,
+                max_iters=args.max_iters,
+                policy=policy,
+                objective=args.objective,
+            )
+        else:
+            result = power_iteration(
+                session,
+                dense,
+                tol=args.tol,
+                max_iters=args.max_iters,
+                policy=policy,
+                objective=args.objective,
+            )
 
     stats = session.stats
     log.info(
@@ -214,6 +224,10 @@ def main(argv=None):
                          "after solving (obs/aggregate.py input)")
     ap.add_argument("--obs-instance", default="solve",
                     help="instance label stamped into exported shards")
+    ap.add_argument("--profile-dir", default=None,
+                    help="capture the solve with jax.profiler into this "
+                         "directory: program spans and device ops on one "
+                         "clock (Perfetto/TensorBoard viewable)")
     args = ap.parse_args(argv)
     configure_compile_cache()
     return run_solve(args)

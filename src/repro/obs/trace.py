@@ -9,19 +9,33 @@ The paper's headline numbers are *measured* latencies (§6.3); a trace stream
 is how a serving reproduction keeps that measurement methodology inspectable
 per request instead of trusting aggregate counters.
 
-Cost discipline: an enabled span is one ``perf_counter`` pair plus a dict
-append into a bounded deque; a disabled tracer hands out a shared no-op
-context manager, so instrumented code pays one attribute read. Export is a
-JSONL append-log following ``telemetry/recorder.py``'s torn-line convention
-(a crash mid-append leaves at most one unparseable trailing line, which
-``load_spans`` skips), and ``profile_capture`` optionally wraps a region in
-``jax.profiler`` so a fused-kernel launch can be opened in Perfetto.
+Every record names its ``root``: the outermost span open on its thread when
+it opened (itself, for a root), so all the spans of one request share one
+identifier. ``counted_span`` opens a span that also records the calling
+thread's resource deltas (``getrusage(RUSAGE_THREAD)``: ``user_s``,
+``sys_s``, ``minflt``, ``majflt``, ``nvcsw``, ``nivcsw``) as attributes,
+which tells a thread faulting or zeroing pages in the kernel from one that
+was descheduled or one that computed.
+
+While a ``jax.profiler`` capture runs, an enabled span also enters a
+profiler ``TraceMe`` under its own name, so program spans sit on the
+capture's host plane, on the profiler's clock, beside the device's ops.
+
+Cost discipline: an enabled span is one ``perf_counter`` pair, one check
+for a running capture and a dict append into a bounded deque (a counted
+span adds two ``getrusage`` calls); a disabled tracer hands out a shared
+no-op context manager. Export is a JSONL append-log following
+``telemetry/recorder.py``'s torn-line convention (a crash mid-append leaves
+at most one unparseable trailing line, which ``load_spans`` skips), and
+``profile_capture`` wraps a region in ``jax.profiler`` so program spans and
+device ops can be opened together in Perfetto.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import resource
 import threading
 import time
 from collections import deque
@@ -52,10 +66,25 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that the
+    tracer itself stays importable without JAX."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+_ANNOTATION = None
+
+
 class _Span:
     """One live span; becomes a plain dict in the collector on exit."""
 
-    __slots__ = ("tracer", "name", "attrs", "span_id", "parent_id", "t0", "ts")
+    __slots__ = ("tracer", "name", "attrs", "span_id", "parent_id", "root_id",
+                 "t0", "ts", "profiled")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
@@ -72,13 +101,21 @@ class _Span:
         self.span_id = tr._next_id()
         stack = tr._stack()
         self.parent_id = stack[-1] if stack else None
+        self.root_id = stack[0] if stack else self.span_id
         stack.append(self.span_id)
+        annotation = _annotation()
+        self.profiled = None
+        if annotation.is_enabled():  # a profiler capture is running
+            self.profiled = annotation(self.name)
+            self.profiled.__enter__()
         self.ts = time.time()
         self.t0 = time.perf_counter()
         return self
 
-    def __exit__(self, exc_type, *exc) -> bool:
+    def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self.t0
+        if self.profiled is not None:
+            self.profiled.__exit__(exc_type, exc, tb)
         stack = self.tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -86,6 +123,7 @@ class _Span:
             "name": self.name,
             "id": self.span_id,
             "parent": self.parent_id,
+            "root": self.root_id,
             "ts": self.ts,
             "dur_s": dur,
             "thread": threading.get_ident(),
@@ -96,6 +134,32 @@ class _Span:
             rec["attrs"] = self.attrs
         self.tracer._collect(rec)
         return False
+
+
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)  # Linux only
+
+
+class _CountedSpan(_Span):
+    """A span that also records its thread's resource deltas as attributes."""
+
+    __slots__ = ("usage",)
+
+    def __enter__(self) -> "_CountedSpan":
+        super().__enter__()
+        self.usage = resource.getrusage(_RUSAGE_THREAD)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        u0, u1 = self.usage, resource.getrusage(_RUSAGE_THREAD)
+        self.attrs.update(
+            user_s=u1.ru_utime - u0.ru_utime,
+            sys_s=u1.ru_stime - u0.ru_stime,
+            minflt=u1.ru_minflt - u0.ru_minflt,
+            majflt=u1.ru_majflt - u0.ru_majflt,
+            nvcsw=u1.ru_nvcsw - u0.ru_nvcsw,
+            nivcsw=u1.ru_nivcsw - u0.ru_nivcsw,
+        )
+        return super().__exit__(exc_type, exc, tb)
 
 
 class Tracer:
@@ -142,6 +206,16 @@ class Tracer:
         if not self.enabled:
             return NOOP_SPAN
         return _Span(self, name, attrs)
+
+    def counted_span(self, name: str, **attrs):
+        """``span`` that also records the calling thread's resource deltas
+        (CPU time in user and kernel mode, page faults, context switches).
+        Where the platform has no per-thread usage it is a plain span."""
+        if not self.enabled:
+            return NOOP_SPAN
+        if _RUSAGE_THREAD is None:
+            return _Span(self, name, attrs)
+        return _CountedSpan(self, name, attrs)
 
     def spans(self) -> list[dict]:
         with self._lock:
@@ -224,13 +298,19 @@ def span(name: str, **attrs):
     return _TRACER.span(name, **attrs)
 
 
+def counted_span(name: str, **attrs):
+    """Module-level ``Tracer.counted_span`` on the process-wide tracer."""
+    return _TRACER.counted_span(name, **attrs)
+
+
 class profile_capture:
     """Wrap a region in ``jax.profiler`` (Perfetto/TensorBoard).
 
     ``with profile_capture("artifacts/profile"):`` captures every XLA/Pallas
-    launch inside into a trace a real viewer can open. A profile that was
-    asked for and cannot be taken (no profiler support, a capture already
-    running) raises: a run that claims to be profiled must be."""
+    launch inside, and every enabled span opened inside, into a trace a
+    real viewer can open. A profile that was asked for and cannot be taken
+    (no profiler support, a capture already running) raises: a run that
+    claims to be profiled must be."""
 
     def __init__(self, log_dir: str | Path):
         self.log_dir = str(log_dir)

@@ -6,9 +6,20 @@ iteration loop replays the cached ``PreparedSpmv`` (plus, when the adaptive
 policy routes a sparse frontier, the lazily-compiled SpMSpV twin) — the
 session's ``plans_computed`` / ``kernel_compiles`` counters stay flat while
 ``observe()`` feeds every iteration's wall time back into the telemetry
-bandit. Each iteration runs inside a nested ``solver.iterate`` span and
-bumps ``solver_iterations_total``, so a trace of a 50-iteration solve shows
-one ``session.serve`` and fifty iterate spans under it.
+bandit. A solve is one span tree under a ``solver.solve`` root::
+
+    solver.solve                 (solver, max_iters)
+     ├─ solver.setup             (the per-solve set-up, once)
+     │   ├─ solver.count_nnz     (the ``dense != 0`` count)
+     │   └─ session.serve        (serve_optimize)
+     │       ├─ session.fingerprint
+     │       └─ session.optimize
+     └─ solver.iterate × N       (iteration; bumps solver_iterations_total)
+         └─ kernel.execute       (one per matvec: the time matvec_seconds holds)
+
+``solver.solve``, ``solver.setup``, ``solver.count_nnz``, ``solver.iterate``
+and the session's ``session.fingerprint`` carry the thread's resource
+deltas (``counted_span``).
 
 Solvers (``pagerank`` / ``cg`` / ``power``) express one iteration as a
 ``step(matvec, state) -> (state, residual)`` callable and hand the loop to
@@ -29,6 +40,7 @@ import numpy as np
 from repro.kernels.common import KernelSchedule
 from repro.kernels.ops import compile_spmv
 from repro.obs.metrics import get_metrics
+from repro.obs.trace import counted_span as _counted_span
 from repro.obs.trace import span as _span
 from repro.solvers.adaptive import SPMSPV, AdaptiveSpmvPolicy
 from repro.utils.logging import get_logger
@@ -122,7 +134,7 @@ class IterativeSolver:
         self.max_iters = int(max_iters)
         self.policy = policy
         self.force_fp32 = force_fp32
-        self.nnz = int((self.dense != 0).sum())
+        self.nnz = 0  # counted in setup(), per solve
         self.n_cols = int(self.dense.shape[1])
         self.plan = None
         self._spmv_kernel = None
@@ -136,21 +148,24 @@ class IterativeSolver:
         """Serve the ONE plan this whole solve amortizes; idempotent."""
         if self.plan is not None:
             return self.plan
-        plan = self.session.serve_optimize(self.dense, self.objective)
-        kernel = plan.kernel
-        if self.force_fp32 and plan.schedule.accum_dtype != "float32":
-            sched = plan.schedule.replace(accum_dtype="float32")
-            kernel = compile_spmv(
-                self.dense,
-                plan.fmt,
-                sched,
-                memo_key=plan.fingerprint,
-            )
-            log.info(
-                "solver %s: plan schedule accumulates in %s; recompiled fp32",
-                self.name,
-                plan.schedule.accum_dtype,
-            )
+        with _counted_span("solver.setup", solver=self.name):
+            with _counted_span("solver.count_nnz"):
+                self.nnz = int((self.dense != 0).sum())
+            plan = self.session.serve_optimize(self.dense, self.objective)
+            kernel = plan.kernel
+            if self.force_fp32 and plan.schedule.accum_dtype != "float32":
+                sched = plan.schedule.replace(accum_dtype="float32")
+                kernel = compile_spmv(
+                    self.dense,
+                    plan.fmt,
+                    sched,
+                    memo_key=plan.fingerprint,
+                )
+                log.info(
+                    "solver %s: plan schedule accumulates in %s; recompiled fp32",
+                    self.name,
+                    plan.schedule.accum_dtype,
+                )
         self.plan = plan
         self._spmv_kernel = kernel
         if self.policy is not None:
@@ -181,14 +196,16 @@ class IterativeSolver:
         decision = self.policy.choose(density) if self.policy is not None else None
         if decision is not None and decision.kind == SPMSPV:
             kernel = self._ensure_spmspv()
-            t0 = perf_counter()
-            y = jax.block_until_ready(kernel.call_frontier(active, x[active]))
-            dt = perf_counter() - t0
+            with _span("kernel.execute", fmt=SPMSPV):
+                t0 = perf_counter()
+                y = jax.block_until_ready(kernel.call_frontier(active, x[active]))
+                dt = perf_counter() - t0
             self.modeled_work += kernel.modeled_work(active)
         else:
-            t0 = perf_counter()
-            y = jax.block_until_ready(self._spmv_kernel(jnp.asarray(x)))
-            dt = perf_counter() - t0
+            with _span("kernel.execute", fmt=self.plan.fmt):
+                t0 = perf_counter()
+                y = jax.block_until_ready(self._spmv_kernel(jnp.asarray(x)))
+                dt = perf_counter() - t0
             self.modeled_work += self.nnz
             self.session.observe(self.plan, dt)
         kind = decision.kind if decision is not None else "spmv"
@@ -214,7 +231,6 @@ class IterativeSolver:
         extras: Callable[[Any], dict] | None = None,
     ) -> SolveResult:
         """Iterate ``step`` to convergence under spans/metrics/accounting."""
-        self.setup()
         metrics = get_metrics()
         iters_total = metrics.counter("solver_iterations_total", solver=self.name)
         iter_hist = metrics.histogram("solver_iteration_seconds", solver=self.name)
@@ -222,10 +238,11 @@ class IterativeSolver:
         iter_seconds: list[float] = []
         converged = False
         it = 0
-        with _span("solver.solve", solver=self.name, max_iters=self.max_iters):
+        with _counted_span("solver.solve", solver=self.name, max_iters=self.max_iters):
+            self.setup()
             for it in range(1, self.max_iters + 1):
                 t0 = perf_counter()
-                with _span("solver.iterate", solver=self.name, iteration=it):
+                with _counted_span("solver.iterate", solver=self.name, iteration=it):
                     state, res = step(self.matvec, state)
                 dt = perf_counter() - t0
                 iters_total.inc()

@@ -133,3 +133,20 @@ def test_schedule_validation():
         KernelSchedule(unroll=3)  # must divide nnz_tile
     with pytest.raises(ValueError):
         KernelSchedule(accum_dtype="float16")
+
+
+@pytest.mark.parametrize("fmt", ["csr", "ell", "sell"])
+def test_the_gather_of_x_is_named_in_the_spmv_program(fmt):
+    """The XLA gather sits in a ``spmv.gather`` scope, so a trace names it."""
+    from repro.kernels.common import GATHER_SCOPE
+    from repro.kernels.ops import _jitted_spmv
+    from repro.sparse.registry import spec_for
+
+    dense = random_matrix(120, 6.0, "fem", seed=3).astype(np.float32)
+    mat = prepare(dense, fmt, DEFAULT_SCHEDULE)
+    x = np.ones(dense.shape[1], np.float32)
+    text = _jitted_spmv.lower(spec_for(mat).spmv, mat, x, DEFAULT_SCHEDULE).as_text(
+        debug_info=True
+    )
+    assert GATHER_SCOPE == "spmv.gather"
+    assert f"{GATHER_SCOPE}/" in text

@@ -88,3 +88,36 @@ def test_serve_cli_spmv_partitioned(tmp_path):
         assert err < 0.05  # bfloat16 schedules allowed; must still be SpMV
         assert r.fmt and r.latency_s > 0  # "fmtA+fmtB..." composite report
     assert (tmp_path / "tuning.json").exists()
+
+
+def test_solve_cli_profile_dir_captures_the_solves_spans(tmp_path):
+    """``launch.solve --profile-dir``: one capture holds the solve's spans."""
+    import glob
+
+    import jax
+
+    from repro.launch.solve import main as solve_main
+
+    res = solve_main([
+        "--solver", "power",
+        "--matrix", "fem",
+        "--scale", "0.0008",
+        "--max-iters", "3",
+        "--tol", "0",
+        "--train-matrices", "2",
+        "--json-out", str(tmp_path / "solve.json"),
+        "--profile-dir", str(tmp_path / "profile"),
+    ])
+    assert res.iterations == 3
+    (path,) = glob.glob(str(tmp_path / "profile" / "**" / "*.xplane.pb"), recursive=True)
+    profile = jax.profiler.ProfileData.from_file(path)
+    names = [
+        e.name
+        for plane in profile.planes if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    ]
+    assert names.count("solver.solve") == 1
+    assert names.count("solver.iterate") == names.count("kernel.execute") == 3
+    assert {"solver.setup", "solver.count_nnz", "session.serve",
+            "session.fingerprint"} <= set(names)

@@ -23,9 +23,11 @@ from repro.obs.metrics import MetricsRegistry, get_metrics, reset_metrics
 from repro.obs.trace import (
     NOOP_SPAN,
     Tracer,
+    counted_span,
     get_tracer,
     load_spans,
     profile_capture,
+    span,
     span_children,
 )
 from repro.sparse.generate import random_matrix
@@ -126,6 +128,132 @@ def test_disabled_tracer_and_registry_are_noops():
     g = reg.gauge("g")
     g.set(3.0)
     assert math.isnan(g.value)
+
+
+def test_disabled_tracer_hands_out_the_shared_noop_for_counted_spans_too():
+    import tracemalloc
+
+    tracer = Tracer(enabled=False)
+    assert tracer.counted_span("solver.iterate", iteration=1) is NOOP_SPAN
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        for _ in range(10_000):
+            with tracer.span("kernel.execute"):
+                pass
+            with tracer.counted_span("solver.iterate"):
+                pass
+        held = tracemalloc.get_traced_memory()[0] - before
+    finally:
+        tracemalloc.stop()
+    assert held < 1024  # nothing kept per span
+    assert tracer.spans() == []
+
+
+def test_every_span_names_its_root():
+    tracer = Tracer()
+    with tracer.span("solver.solve"):
+        with tracer.span("solver.setup"):
+            with tracer.span("session.serve"):
+                pass
+        with tracer.span("solver.iterate"):
+            pass
+    with tracer.span("other.request"):
+        pass
+    spans = {s["name"]: s for s in tracer.spans()}
+    root = spans["solver.solve"]["id"]
+    assert {spans[n]["root"] for n in ("solver.solve", "solver.setup", "session.serve",
+                                       "solver.iterate")} == {root}
+    assert spans["other.request"]["root"] == spans["other.request"]["id"] != root
+
+
+def test_counted_span_records_the_threads_usage(monkeypatch):
+    import resource
+    from types import SimpleNamespace
+
+    import repro.obs.trace as trace_mod
+
+    def usage(t, faults, switches):
+        return SimpleNamespace(ru_utime=t, ru_stime=t / 4, ru_minflt=faults, ru_majflt=1,
+                               ru_nvcsw=switches, ru_nivcsw=2 * switches)
+
+    readings = iter([usage(1.0, 100, 3), usage(1.5, 160, 5)])
+    who = []
+
+    def fake_getrusage(which):
+        who.append(which)
+        return next(readings)
+
+    monkeypatch.setattr(trace_mod.resource, "getrusage", fake_getrusage)
+    tracer = Tracer()
+    with tracer.counted_span("solver.setup", solver="power") as sp:
+        sp.set(done=True)
+        with tracer.span("session.serve"):
+            pass
+    inner, outer = tracer.spans()
+    assert who == [resource.RUSAGE_THREAD] * 2  # the calling thread's own usage
+    assert outer["attrs"] == pytest.approx({
+        "solver": "power", "done": True, "user_s": 0.5, "sys_s": 0.125, "minflt": 60,
+        "majflt": 0, "nvcsw": 2, "nivcsw": 4,
+    })
+    assert "attrs" not in inner  # a plain span pays no syscalls
+
+
+def test_counted_span_reads_real_usage():
+    tracer = Tracer()
+    with tracer.counted_span("session.fingerprint"):
+        np.ones(1 << 20, np.float64).sum()
+    (rec,) = tracer.spans()
+    for key in ("user_s", "sys_s", "minflt", "majflt", "nvcsw", "nivcsw"):
+        assert rec["attrs"][key] >= 0, key
+
+
+def test_optimize_many_fingerprints_each_matrix_under_a_span():
+    clear_kernel_memo()
+    session = AutoSpmvSession(_fake_tuner())
+    a = random_matrix(96, 5.0, "fem", seed=1).astype(np.float32)
+    b = random_matrix(96, 5.0, "fem", seed=2).astype(np.float32)
+    get_tracer().clear()
+    session.optimize_many([a, b, a], "latency")
+    spans = get_tracer().spans()
+    fps = [s for s in spans if s["name"] == "session.fingerprint"]
+    assert len(fps) == 3  # one per matrix hashed; the optimize calls reuse them
+    assert all(s["parent"] is None and s["attrs"]["bytes"] == a.nbytes for s in fps)
+    assert all("sys_s" in s["attrs"] for s in fps)
+    # serve_optimize hashes inside its own span
+    get_tracer().clear()
+    session.serve_optimize(b, "latency")
+    spans = {s["name"]: s for s in get_tracer().spans()}
+    assert spans["session.fingerprint"]["parent"] == spans["session.serve"]["id"]
+    assert spans["session.optimize"]["parent"] == spans["session.serve"]["id"]
+
+
+def test_spans_reach_the_profilers_host_plane(tmp_path):
+    """While a capture runs, every span is also a profiler event under its
+    own name, on the host plane the benchmark's trace reduction reads, and
+    placed where its ``ts`` puts it."""
+    from chipbench import tracing
+
+    tracer = get_tracer()
+    with span("before.capture"):
+        pass
+    with profile_capture(tmp_path):
+        for i in range(3):
+            with span("probe.request", i=i):
+                with counted_span("probe.step"):
+                    np.ones(1000).sum()
+    trace = tracing.load(tmp_path)
+    records = [r for r in tracer.spans() if r["name"].startswith("probe.")]
+    events = [e for e in trace.host if e.name.startswith("probe.")]
+    assert len(records) == len(events) == 6
+    assert not [e for e in trace.host if e.name == "before.capture"]
+    for name in ("probe.request", "probe.step"):
+        mine = sorted((r for r in records if r["name"] == name), key=lambda r: r["ts"])
+        theirs = sorted((e for e in events if e.name == name), key=lambda e: e.start)
+        for r, e in zip(mine, theirs):
+            placed = r["ts"] * 1e9 - trace.start_epoch_ns
+            assert abs(e.start - placed) < 5e6  # ns: the same clock
+            assert e.end - e.start <= r["dur_s"] * 1e9 + 5e6
 
 
 def test_profile_capture_raises_when_a_profile_cannot_be_taken(tmp_path):

@@ -145,10 +145,17 @@ def test_policy_bandit_learns_crossover():
 
 
 # ----------------------------------------------------- amortization contract
-def test_fifty_iteration_solve_plans_exactly_once(session, web):
+SOLVES = {
+    "power": lambda session, m, iters: power_iteration(session, m, tol=0.0, max_iters=iters),
+    "pagerank": lambda session, m, iters: pagerank(session, m, tol=0.0, max_iters=iters),
+}
+
+
+@pytest.mark.parametrize("solver", sorted(SOLVES))
+def test_fifty_iteration_solve_plans_exactly_once(solver, session, web):
     tracer = get_tracer()
     tracer.clear()
-    res = power_iteration(session, web, tol=0.0, max_iters=50)
+    res = SOLVES[solver](session, web, 50)
     assert res.iterations == 50
     stats = session.stats
     assert stats.plans_computed == 1, (
@@ -159,11 +166,69 @@ def test_fifty_iteration_solve_plans_exactly_once(session, web):
     iterate = [s for s in spans if s["name"] == "solver.iterate"]
     assert len(iterate) == 50
     assert {s["attrs"]["iteration"] for s in iterate} == set(range(1, 51))
-    assert all(s["attrs"]["solver"] == "power" for s in iterate)
+    assert all(s["attrs"]["solver"] == solver for s in iterate)
+    # one kernel.execute inside every iteration, and none outside one
+    ids = {s["id"] for s in iterate}
+    execute = [s for s in spans if s["name"] == "kernel.execute"]
+    assert sorted(s["parent"] for s in execute) == sorted(ids)
+    assert len({s["root"] for s in spans}) == 1  # one solve, one request id
     # a second solve over the same matrix reuses the cached plan entirely
-    res2 = power_iteration(session, web, tol=0.0, max_iters=5)
+    res2 = SOLVES[solver](session, web, 5)
     assert session.stats.plans_computed == 1
     assert res2.cache_hit
+
+
+COUNTED = {"solver.solve", "solver.setup", "solver.count_nnz", "session.fingerprint",
+           "solver.iterate"}
+USAGE = {"user_s", "sys_s", "minflt", "majflt", "nvcsw", "nivcsw"}
+
+
+def test_one_power_solve_is_one_span_tree(session, web):
+    tracer = get_tracer()
+    tracer.clear()
+    power_iteration(session, web, tol=0.0, max_iters=4)
+    spans = tracer.spans()
+    by_id = {s["id"]: s for s in spans}
+
+    def kids(span):
+        return sorted(s["name"] for s in spans if s["parent"] == span["id"])
+
+    (root,) = [s for s in spans if s["parent"] is None]
+    assert root["name"] == "solver.solve"
+    assert root["attrs"]["solver"] == "power" and root["attrs"]["max_iters"] == 4
+    assert all(s["root"] == root["id"] for s in spans)
+    assert kids(root) == ["solver.iterate"] * 4 + ["solver.setup"]
+    (setup,) = [s for s in spans if s["name"] == "solver.setup"]
+    assert kids(setup) == ["session.serve", "solver.count_nnz"]
+    (serve,) = [s for s in spans if s["name"] == "session.serve"]
+    assert kids(serve) == ["session.fingerprint", "session.optimize"]
+    (fingerprint,) = [s for s in spans if s["name"] == "session.fingerprint"]
+    assert fingerprint["attrs"]["bytes"] == web.astype(np.float32).nbytes
+    for it in (s for s in spans if s["name"] == "solver.iterate"):
+        assert kids(it) == ["kernel.execute"]
+    # the set-up comes first, then the iterations in order
+    assert by_id[setup["parent"]] is root
+    starts = [s["ts"] for s in sorted(spans, key=lambda s: s["id"]) if s["parent"] == root["id"]]
+    assert starts == sorted(starts)
+
+
+def test_thread_usage_rides_on_the_five_counted_spans_only(session, web):
+    tracer = get_tracer()
+    tracer.clear()
+    power_iteration(session, web, tol=0.0, max_iters=3)
+    spans = tracer.spans()
+    assert COUNTED <= {s["name"] for s in spans}
+    for s in spans:
+        attrs = s.get("attrs") or {}
+        if s["name"] in COUNTED:
+            assert USAGE <= set(attrs), s["name"]
+            assert all(attrs[k] >= 0 for k in USAGE), (s["name"], attrs)
+        else:
+            assert not USAGE & set(attrs), s["name"]
+    (root,) = [s for s in spans if s["name"] == "solver.solve"]
+    iterate = [s for s in spans if s["name"] == "solver.iterate"]
+    # the root's usage holds its iterations'
+    assert root["attrs"]["minflt"] >= sum(s["attrs"]["minflt"] for s in iterate)
 
 
 def test_force_fp32_guard_recompiles_bf16_schedules(web):
