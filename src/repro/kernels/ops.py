@@ -121,6 +121,15 @@ class PreparedSpmv:
     def __call__(self, x: jax.Array) -> jax.Array:
         return spmv_pallas(self.mat, x, self.schedule)
 
+    @property
+    def gather(self) -> str | None:
+        """Where this kernel gathers a float32 x: "vmem" inside the kernel,
+        "xla" before the launch, None where the format does not say."""
+        from repro.sparse.registry import spec_for
+
+        site = spec_for(self.mat).x_gather
+        return None if site is None else site(self.mat, np.float32)
+
 
 def matrix_fingerprint(dense: np.ndarray) -> str:
     """Content hash of a dense-held matrix — the kernel-memo identity.

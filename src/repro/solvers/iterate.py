@@ -15,7 +15,8 @@ bandit. A solve is one span tree under a ``solver.solve`` root::
      │       ├─ session.fingerprint
      │       └─ session.optimize
      └─ solver.iterate × N       (iteration; bumps solver_iterations_total)
-         └─ kernel.execute       (one per matvec: the time matvec_seconds holds)
+         └─ kernel.execute       (one per matvec: the time matvec_seconds holds;
+                                  fmt, and gather: where the kernel gathers x)
 
 ``solver.solve``, ``solver.setup``, ``solver.count_nnz``, ``solver.iterate``
 and the session's ``session.fingerprint`` carry the thread's resource
@@ -202,7 +203,8 @@ class IterativeSolver:
                 dt = perf_counter() - t0
             self.modeled_work += kernel.modeled_work(active)
         else:
-            with _span("kernel.execute", fmt=self.plan.fmt):
+            gather = getattr(self._spmv_kernel, "gather", None)
+            with _span("kernel.execute", fmt=self.plan.fmt, gather=gather):
                 t0 = perf_counter()
                 y = jax.block_until_ready(self._spmv_kernel(jnp.asarray(x)))
                 dt = perf_counter() - t0

@@ -175,6 +175,9 @@ class FormatSpec:
     reference: Callable  # (mat, x) -> y — pure-jnp oracle
     footprint: Callable  # (MatrixStats, KernelSchedule) -> KernelFootprint
     priority: int = 100
+    # (mat, x dtype) -> where spmv gathers x: "vmem" inside the kernel,
+    # "xla" before the launch; None where the format does not say
+    x_gather: Callable | None = None
     description: str = ""
 
 
@@ -277,7 +280,7 @@ def spec_for(mat) -> FormatSpec:
 # ---------------------------------------------------------------------------
 
 from repro.kernels.bell import bell_spmv_pallas  # noqa: E402
-from repro.kernels.csr import csr_spmv_pallas  # noqa: E402
+from repro.kernels.csr import csr_spmv_pallas, x_gather  # noqa: E402
 from repro.kernels.ell import ell_spmv_pallas  # noqa: E402
 from repro.kernels.sell import sell_spmv_pallas  # noqa: E402
 from repro.sparse.formats import (  # noqa: E402
@@ -329,6 +332,10 @@ def _csr_spmv(mat: CSR, x, schedule: KernelSchedule):
         mat.data, mat.indices, mat.row_ids, x, n_rows, mat.tiling, schedule
     )
     return y[:n_rows]
+
+
+def _csr_x_gather(mat: CSR, x_dtype) -> str:
+    return x_gather(mat.shape[1], x_dtype, mat.tiling[1])
 
 
 def _csr_footprint(stats: MatrixStats, schedule: KernelSchedule) -> KernelFootprint:
@@ -488,6 +495,7 @@ register_format(FormatSpec(
     spmv=_csr_spmv,
     reference=_ref_csr,
     footprint=_csr_footprint,
+    x_gather=_csr_x_gather,
     priority=0,
     description="Compressed Sparse Row (flat segmented-sum kernel)",
 ))

@@ -410,7 +410,8 @@ class SpmvServer:
         for req in group:
             with _span("server.request", rid=req.rid, objective=objective, mode="observed"):
                 plan = self.session.serve_optimize(req.dense, objective)
-                with _span("kernel.execute", fmt=plan.fmt):
+                gather = getattr(plan.kernel, "gather", None)
+                with _span("kernel.execute", fmt=plan.fmt, gather=gather):
                     t0 = time.perf_counter()
                     y = np.asarray(plan.kernel(jnp.asarray(req.x)))
                     dt = time.perf_counter() - t0
@@ -503,7 +504,8 @@ class SpmvServer:
                     "server.request", rid=req.rid, objective=objective, mode="batch"
                 ):
                     req.schedule = res.schedule
-                    with _span("kernel.execute", fmt=default_format()):
+                    gather = getattr(res.kernel, "gather", None)
+                    with _span("kernel.execute", fmt=default_format(), gather=gather):
                         t_exec = time.perf_counter()
                         req.y = np.asarray(res.kernel(jnp.asarray(req.x)))
                         exec_s = time.perf_counter() - t_exec

@@ -137,16 +137,121 @@ def test_schedule_validation():
 
 @pytest.mark.parametrize("fmt", ["csr", "ell", "sell"])
 def test_the_gather_of_x_is_named_in_the_spmv_program(fmt):
-    """The XLA gather sits in a ``spmv.gather`` scope, so a trace names it."""
+    """The XLA gather sits in a ``spmv.gather`` scope, so a trace names it.
+    CSR gathers a float32 x inside its kernel; a bf16 x takes its XLA gather."""
+    import jax.numpy as jnp
+
     from repro.kernels.common import GATHER_SCOPE
     from repro.kernels.ops import _jitted_spmv
     from repro.sparse.registry import spec_for
 
     dense = random_matrix(120, 6.0, "fem", seed=3).astype(np.float32)
     mat = prepare(dense, fmt, DEFAULT_SCHEDULE)
-    x = np.ones(dense.shape[1], np.float32)
+    x = jnp.ones(dense.shape[1], jnp.bfloat16 if fmt == "csr" else jnp.float32)
     text = _jitted_spmv.lower(spec_for(mat).spmv, mat, x, DEFAULT_SCHEDULE).as_text(
         debug_info=True
     )
     assert GATHER_SCOPE == "spmv.gather"
     assert f"{GATHER_SCOPE}/" in text
+
+
+def _csr_both_gathers(dense, sched, x):
+    """y of the CSR kernel with x gathered inside it and by XLA."""
+    import jax
+
+    from repro.kernels.csr import csr_spmv_pallas
+
+    mat = prepare(dense, "csr", sched)
+    n_rows = dense.shape[0]
+
+    def run(gather):
+        fn = jax.jit(
+            lambda x: csr_spmv_pallas(
+                mat.data, mat.indices, mat.row_ids, x, n_rows, mat.tiling, sched,
+                gather=gather,
+            )
+        )
+        return np.asarray(fn(x))
+
+    return mat, run("vmem"), run("xla")
+
+
+def _csr_gather_case(n_rows, n_cols, empty_block=False):
+    dense = random_matrix(max(n_rows, n_cols), 9.0, "denserows", seed=n_cols)
+    dense = dense[:n_rows, :n_cols].astype(np.float32)
+    dense[:, -1] = 1.5  # the last column of x, past the last whole row of 128
+    if empty_block:
+        dense[8:16] = 0.0  # an 8-row block stored as one tile of padding
+    return dense
+
+
+@pytest.mark.parametrize(
+    "rpb,nt,n_rows,n_cols,empty_block",
+    [
+        pytest.param(rpb, nt, 300, 300, False, id=f"rpb{rpb}-nt{nt}")
+        for rpb in (8, 64, 512)
+        for nt in (128, 1024)
+    ]
+    + [
+        pytest.param(8, 128, 100, 100, False, id="x-in-one-row"),
+        pytest.param(64, 1024, 260, 128, False, id="x-exactly-one-row"),
+        pytest.param(8, 1024, 200, 300, True, id="tile-of-padding-only"),
+    ],
+)
+def test_csr_gathers_x_in_the_kernel_bit_for_bit_as_xla_does(
+    rpb, nt, n_rows, n_cols, empty_block
+):
+    from repro.kernels.common import GATHER_SCOPE
+    from repro.kernels.ops import PreparedSpmv, _jitted_spmv
+    from repro.sparse.registry import spec_for
+
+    dense = _csr_gather_case(n_rows, n_cols, empty_block)
+    sched = KernelSchedule(rows_per_block=rpb, nnz_tile=nt)
+    x = np.random.default_rng(rpb + nt).normal(size=n_cols).astype(np.float32)
+    mat, y_vmem, y_xla = _csr_both_gathers(dense, sched, x)
+    if empty_block:
+        assert (np.asarray(mat.data).reshape(-1, nt) == 0).all(axis=1).any()
+    assert y_vmem.dtype == y_xla.dtype and np.array_equal(y_vmem, y_xla)
+    np.testing.assert_allclose(y_vmem[:n_rows], dense @ x, rtol=1e-4, atol=1e-4)
+    # the served path takes the in-kernel gather and keeps no XLA gather of x
+    assert PreparedSpmv(mat, sched).gather == "vmem"
+    text = _jitted_spmv.lower(spec_for(mat).spmv, mat, x, sched).as_text(debug_info=True)
+    assert f"{GATHER_SCOPE}/" not in text
+    np.testing.assert_array_equal(np.asarray(spmv_pallas(mat, x, sched)), y_vmem[:n_rows])
+
+
+@pytest.mark.parametrize("case", ["bf16-x", "x-past-the-row-bound"])
+def test_csr_keeps_the_xla_gather_where_the_kernel_cannot_gather(case):
+    """A bf16 x, or an x of more rows of 128 than ``x_gather`` allows, is
+    gathered by XLA before the launch, inside the ``spmv.gather`` scope."""
+    import jax.numpy as jnp
+
+    from repro.kernels.common import GATHER_SCOPE
+    from repro.kernels.csr import VMEM_GATHER_MAX_ROWS, WALK_ROWS, x_gather
+    from repro.kernels.ops import PreparedSpmv, _jitted_spmv
+    from repro.sparse.registry import spec_for
+
+    sched = KernelSchedule(rows_per_block=8, nnz_tile=128)
+    rng = np.random.default_rng(7)
+    if case == "bf16-x":
+        n_cols, dtype = 300, jnp.bfloat16
+    else:
+        # a 128-wide tile fills one sublane in 8, so its bound is an eighth,
+        # in whole steps of the walk; one column more takes another step
+        fit = VMEM_GATHER_MAX_ROWS // 8 // WALK_ROWS * WALK_ROWS * 128
+        n_cols, dtype = fit + 1, jnp.float32
+        assert x_gather(fit, dtype, 128) == "vmem"
+    dense = np.zeros((40, n_cols), np.float32)
+    cols = rng.integers(0, n_cols, size=(40, 6))
+    dense[np.arange(40)[:, None], cols] = rng.normal(size=cols.shape)
+    dense[3, n_cols - 1] = 2.0
+    mat = prepare(dense, "csr", sched)
+    assert x_gather(n_cols, dtype, 128) == "xla"
+    if case != "bf16-x":
+        assert PreparedSpmv(mat, sched).gather == "xla"
+    x = jnp.asarray(rng.normal(size=n_cols), dtype)
+    text = _jitted_spmv.lower(spec_for(mat).spmv, mat, x, sched).as_text(debug_info=True)
+    assert f"{GATHER_SCOPE}/" in text
+    y = np.asarray(spmv_pallas(mat, x, sched), np.float32)
+    ref = dense @ np.asarray(x, np.float32)
+    np.testing.assert_allclose(y, ref, rtol=2e-2, atol=2e-2)

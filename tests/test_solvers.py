@@ -212,6 +212,25 @@ def test_one_power_solve_is_one_span_tree(session, web):
     assert starts == sorted(starts)
 
 
+class _CsrPredictor(_FakePredictor):
+    def predict_format(self, feats, objective):
+        return "csr"
+
+    def estimate_objective(self, feats, config, objective):
+        return 0.5 if config.fmt == "csr" else 1.0
+
+
+def test_power_solve_on_a_csr_plan_gathers_x_in_the_kernel(web):
+    """Every ``kernel.execute`` of a CSR solve says where x was gathered."""
+    session = AutoSpmvSession(AutoSpMV(_CsrPredictor(), _FakeOverhead()))
+    tracer = get_tracer()
+    tracer.clear()
+    res = power_iteration(session, web, tol=0.0, max_iters=3)
+    assert res.iterations == 3
+    execute = [s for s in tracer.spans() if s["name"] == "kernel.execute"]
+    assert [s["attrs"] for s in execute] == [{"fmt": "csr", "gather": "vmem"}] * 3
+
+
 def test_thread_usage_rides_on_the_five_counted_spans_only(session, web):
     tracer = get_tracer()
     tracer.clear()

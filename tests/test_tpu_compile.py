@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.kernels.bell import bell_spmv_pallas
 from repro.kernels.common import (
+    GATHER_SCOPE,
     NNZ_TILE_CHOICES,
     ROWS_PER_BLOCK_CHOICES,
     KernelSchedule,
@@ -92,15 +93,17 @@ def _compile(fn, *args):
     return compiled
 
 
-def _csr(sharding, sched):
+def _csr(sharding, sched, n=N, gather=None):
     rpb, nt = sched.rows_per_block, sched.nnz_tile
-    nnz_pad = ceil_to(NNZ + -(-N // rpb) * nt, nt)  # worst-case block padding
-    _compile(
-        lambda d, c, r, x: csr_spmv_pallas(d, c, r, x, N, (rpb, nt), sched, interpret=False),
+    nnz_pad = ceil_to(NNZ + -(-n // rpb) * nt, nt)  # worst-case block padding
+    return _compile(
+        lambda d, c, r, x: csr_spmv_pallas(
+            d, c, r, x, n, (rpb, nt), sched, gather=gather, interpret=False
+        ),
         _sds(sharding, (nnz_pad,)),
         _sds(sharding, (nnz_pad,), jnp.int32),
         _sds(sharding, (nnz_pad,), jnp.int32),
-        _sds(sharding, (N,)),
+        _sds(sharding, (n,)),
     )
 
 
@@ -156,6 +159,17 @@ def test_bell_compiles_over_block_rows(one_chip, rpb):
 @pytest.mark.parametrize("fmt", sorted(SEED_KERNELS))
 def test_seed_kernel_compiles_over_numerics(one_chip, fmt, numerics):
     SEED_KERNELS[fmt](one_chip, KernelSchedule(**numerics))
+
+
+@pytest.mark.parametrize("gather", ["vmem", "xla"])
+@pytest.mark.parametrize("nt", [NNZ_TILE_CHOICES[0], NNZ_TILE_CHOICES[-1]])
+@pytest.mark.parametrize("n", [N, SUITE["rim"].n], ids=["human_gene2", "rim"])
+def test_csr_gather_of_x_compiles_at_the_cells_widths(one_chip, n, nt, gather):
+    """x of R = 113 (human_gene2) and 177 (rim) rows of 128, gathered in
+    the kernel from VMEM or by XLA before the launch."""
+    sched = KernelSchedule(rows_per_block=8, nnz_tile=nt)
+    text = _csr(one_chip, sched, n=n, gather=gather).as_text()
+    assert (GATHER_SCOPE in text) == (gather == "xla")
 
 
 @pytest.fixture()
