@@ -153,6 +153,11 @@ def generate(matrix: dict, value_seed: int, scale: float = 1.0) -> np.ndarray:
     return dense
 
 
+def inputs(config: dict, seed: int, scale: float = 1.0) -> np.ndarray:
+    """A driver's ``inputs``: the dense matrix of a configuration's ``matrix``."""
+    return generate(config["matrix"], seed, scale)
+
+
 def start_vector(n: int, seed: int, solve_index: int, stream: int = 1) -> np.ndarray:
     """Dense float64 start vector of one solve, drawn from (seed, index);
     ``stream`` keeps the warm-up's vectors apart from the window's."""

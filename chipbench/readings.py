@@ -20,7 +20,7 @@ import gc
 import json
 import sys
 
-from chipbench import matrices, run
+from chipbench import run
 
 CONTROL_REQUESTS = 3  # per control seed, about half a run's requests
 
@@ -36,10 +36,12 @@ def seed_list(text: str) -> list[int]:
 
 def control_readings(cell: run.Cell, seed: int, scale: float = 1.0) -> dict:
     """Worst numbers of the control over its first requests, and whether
-    the run's decision reads it correct."""
-    dense = matrices.generate(cell.config["matrix"], seed, scale)
-    answers = cell.driver.control(dense, cell.config, cell.traffic, seed, CONTROL_REQUESTS)
-    worst, failed = run.decide(cell.driver.check(dense, answers, cell.traffic), cell.limits)
+    the run's decision reads it correct. The cell's driver makes the inputs
+    the program would be handed, from the configuration and ``seed``."""
+    driver = cell.driver
+    inputs = driver.inputs(cell.config, seed, scale)
+    answers = driver.control(inputs, cell.config, cell.traffic, seed, CONTROL_REQUESTS)
+    worst, failed = run.decide(driver.check(inputs, answers, cell.traffic), cell.limits)
     return {"correct": failed == 0, **worst}
 
 

@@ -3,15 +3,16 @@
     python -m chipbench.run --workload human_gene2.solve --seed 7 --seconds 30 --trace 0
 
 One process, one cell, one run, from the root of a checkout. The cell names
-a configuration (``chipbench/configs/<config>.json``: the matrix and how the
-program is asked to serve it) and a traffic mix
-(``chipbench/traffic/<traffic>.json``), whose ``driver`` names the module
-under ``chipbench/drivers/`` that sets the program up, answers one request
-and checks the answers. Set-up builds the program objects once; the window
-then sends requests back to back, one caller, until ``--seconds`` have
-passed, finishing the request in flight. After the window every answer is
-compared with the driver's plain reference against the cell's limits
-(``chipbench/limits/<cell>.json``).
+a configuration (``chipbench/configs/<config>.json``: what the program is
+asked to serve) and a traffic mix (``chipbench/traffic/<traffic>.json``),
+whose ``driver`` names the module under ``chipbench/drivers/`` that makes
+the inputs, sets the program up, answers one request and checks the
+answers; ``chipbench.drivers`` states that contract. Set-up builds the
+program objects once; the window then sends requests back to back, one
+caller, until ``--seconds`` have passed, finishing the request in flight.
+After the window every answer is compared with the driver's plain
+reference against the cell's limits (``chipbench/limits/<cell>.json``),
+one for each number the driver's ``check`` returns.
 
 With ``--trace 0`` the result's metrics are the cell's end-to-end metrics;
 with ``--trace 1`` the window runs under the profiler and the metrics are
@@ -21,6 +22,23 @@ SpMVs, compilations inside the window); the last stdout line is the JSON
 result. The numbers compared and their limits are the last lines on
 stderr. With no TPU, or fewer chips than the cell asks for, the run exits
 non-zero and prints no result.
+
+A new cell is new files only, found by the names in ``BENCHMARK.json``:
+
+* ``chipbench/configs/<config>.json``: the configuration as it is run;
+* ``chipbench/traffic/<traffic>.json``: the mix's parameters and its
+  ``driver``;
+* ``chipbench/drivers/<driver>.py``, where no driver serves the mix yet;
+* ``chipbench/limits/<cell>.json``: ``limits``, one per name of the
+  driver's ``CHECKS``, the ``readings`` they were set from
+  (``python -m chipbench.readings``), and ``cpu_scale``, the size at which
+  CPU tests run the cell and its control;
+* ``chipbench/metrics/<metric>.py`` for each new per-layer metric: a
+  ``read(ctx)`` that returns nothing where it finds nothing to read;
+* its own tests under ``tests/chipbench/``, among them those of a new
+  driver's input generator;
+* its entries under ``configs``, ``workloads`` and the metrics of
+  ``BENCHMARK.json``.
 """
 
 from __future__ import annotations
@@ -66,10 +84,19 @@ class Cell:
     limits: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    cpu_scale: float | None = None  # the size CPU tests run the cell at
 
     @property
     def driver(self):
-        return importlib.import_module(f"chipbench.drivers.{self.traffic['driver']}")
+        """The traffic's driver module, held to ``chipbench.drivers``' contract."""
+        from chipbench.drivers import contract_faults
+
+        driver = importlib.import_module(f"chipbench.drivers.{self.traffic['driver']}")
+        faults = contract_faults(driver, self.limits)
+        if faults:
+            raise SystemExit(f"cell {self.name}: driver {self.traffic['driver']!r} breaks "
+                             f"the contract: {'; '.join(faults)}")
+        return driver
 
 
 def load_cell(name: str) -> Cell:
@@ -87,14 +114,16 @@ def load_cell(name: str) -> Cell:
     e2e = [m for m in spec["end_to_end"] if mine(m)]
     e2e_names = {m["name"] for m in e2e}
     per_layer = [m for m in spec["per_layer"] if mine(m) and m["moves"] in e2e_names]
+    limits = _read_json(PKG / "limits" / f"{name}.json")
     return Cell(
         name=name,
         chips=int(w["chips"]),
         config=_read_json(ROOT / config["file"]),
         traffic=_read_json(PKG / "traffic" / f"{w['traffic']}.json"),
-        limits=_read_json(PKG / "limits" / f"{name}.json")["limits"],
+        limits=limits["limits"],
         end_to_end=e2e,
         per_layer=per_layer,
+        cpu_scale=limits.get("cpu_scale"),
     )
 
 
@@ -234,12 +263,15 @@ def per_layer_values(cell: Cell, ctx: TraceContext) -> dict:
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
-             require_chip: bool = True, scale: float = 1.0, tuner=None) -> dict:
+             require_chip: bool = True, scale: float = 1.0, tuner=None,
+             cell: Cell | None = None) -> dict:
     """One run of one cell; returns the result object (``checks`` last).
 
     Tests pass ``require_chip=False`` and a small ``scale`` and ``tuner``
-    to drive the same path on the CPU."""
-    cell = load_cell(workload)
+    to drive the same path on the CPU, and may pass a prepared ``cell`` in
+    place of the one the spec names ``workload``."""
+    if cell is None:
+        cell = load_cell(workload)
     import_program()
     device = device_info(cell.chips, require_chip)
     say(f"chipbench: {workload} seed {seed} on {device['count']} x {device['kind']}")

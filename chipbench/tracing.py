@@ -13,7 +13,10 @@ trace this module takes:
   the program (today the gather of x);
 * idle gaps: the stretches of the window in which no op ran, each labelled
   with the innermost program span (``obs.trace``) or benchmark annotation
-  open at its middle.
+  open at its middle;
+* the time of every op and of every XLA module in the window, by name, so
+  that a reader can take a kernel's time inside any program, or a
+  collective's, without a change here.
 
 All times are nanoseconds from the start of the profile.
 """
@@ -116,6 +119,9 @@ class Reduction:
     request_idle_ns: float  # of which no device op ran
     device_ops: list[tuple[str, float]]  # (op, seconds), longest first
     idle_gaps: list[tuple[str, float]]  # (what the host did, seconds)
+    op_ns: dict[str, float]  # every op's time in the window, by short name, over all devices
+    module_ns: dict[str, float]  # every XLA module's time in the window, by name, over all devices
+    devices: int  # device planes reduced
 
 
 def _label(mid: float, spans: list[tuple[float, float, str]]) -> str:
@@ -136,6 +142,7 @@ def reduce(trace: Trace, program_spans=(), top: int = 10) -> Reduction:
     lo, hi = windows[0].start, windows[0].end
     busy_by_dev, kernel_ns, xla_ns, calls = [], 0.0, 0.0, 0
     op_time: dict[str, float] = defaultdict(float)
+    module_time: dict[str, float] = defaultdict(float)
     all_busy = []
     for lines in trace.devices.values():
         ops = [e for e in lines.get(OPS_LINE, []) if e.end > lo and e.start < hi]
@@ -144,6 +151,9 @@ def reduce(trace: Trace, program_spans=(), top: int = 10) -> Reduction:
         all_busy.extend(merged)
         for e in ops:
             op_time[e.short] += min(e.end, hi) - max(e.start, lo)
+        for m in lines.get(MODULES_LINE, []):
+            if m.end > lo and m.start < hi:
+                module_time[m.name] += min(m.end, hi) - max(m.start, lo)
         programs = [m for m in lines.get(MODULES_LINE, [])
                     if SPMV_PROGRAM in m.name and lo <= m.start < hi]
         calls += len(programs)
@@ -186,4 +196,7 @@ def reduce(trace: Trace, program_spans=(), top: int = 10) -> Reduction:
         request_idle_ns=request_idle,
         device_ops=[(name, ns / 1e9) for name, ns in ops_top],
         idle_gaps=idle,
+        op_ns=dict(op_time),
+        module_ns=dict(module_time),
+        devices=n_dev,
     )

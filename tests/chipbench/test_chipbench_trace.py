@@ -11,6 +11,10 @@ chip's trace. Times are in nanoseconds from the profile's start:
   and a 500 ns reduce whose HLO names the kernel but is XLA's own; one
   unrelated op at 80,000..81,000.
 
+A second trace adds another program (``jit_cg_step``) at 96,000..104,000,
+past the window's end, around a Mosaic kernel at 97,000..103,000: the way a
+solve whose recurrence runs on the device holds its SpMV.
+
 Device op events carry the op's HLO text as their name, as a TPU's do.
 """
 
@@ -21,7 +25,7 @@ from chipbench import run, tracing
 START = 1_700_000_000_000_000_000
 
 
-def _xspace() -> str:
+def _xspace(cg_step: bool = False) -> str:
     names = ["jit__jitted_spmv(1)",
              r"%fusion.1 = f32[96]{0} fusion(f32[8]{0} %x, s32[96]{0} %idx), kind=kCustom",
              r"%csr_spmv.1 = f32[3,1,8]{2,1,0} custom-call(s32[3]{0} %m), "
@@ -29,7 +33,10 @@ def _xspace() -> str:
              r"%add.1 = f32[8]{0} add(f32[8]{0} %a, f32[8]{0} %b)",
              tracing.WINDOW, tracing.REQUEST,
              r"%reduce.1 = f32[3,8]{1,0} reduce(f32[3,1,8]{2,1,0} %csr_spmv.1), "
-             r"to_apply=%csr_spmv.reduce_sub_computation"]
+             r"to_apply=%csr_spmv.reduce_sub_computation",
+             "jit_cg_step(2)",
+             r"%cg_spmv.2 = f32[8]{0} custom-call(f32[8]{0} %p), "
+             r'custom_call_target=\"tpu_custom_call\"']
     meta = "\n".join(
         f'  event_metadata {{ key: {i + 1} value {{ id: {i + 1} name: "{n}" }} }}'
         for i, n in enumerate(names)
@@ -38,10 +45,14 @@ def _xspace() -> str:
     def ev(mid, start, dur):
         return f"    events {{ metadata_id: {mid} offset_ps: {start * 1000} duration_ps: {dur * 1000} }}"
 
-    modules = "\n".join([ev(1, 20_000, 10_000), ev(1, 40_000, 10_000)])
-    ops = "\n".join([ev(2, 20_500, 6_000), ev(3, 26_500, 3_000), ev(7, 29_500, 500),
-                     ev(2, 40_500, 6_000), ev(3, 46_500, 3_000), ev(7, 49_500, 500),
-                     ev(4, 80_000, 1_000)])
+    modules = [ev(1, 20_000, 10_000), ev(1, 40_000, 10_000)]
+    ops = [ev(2, 20_500, 6_000), ev(3, 26_500, 3_000), ev(7, 29_500, 500),
+           ev(2, 40_500, 6_000), ev(3, 46_500, 3_000), ev(7, 49_500, 500),
+           ev(4, 80_000, 1_000)]
+    if cg_step:
+        modules.append(ev(8, 96_000, 8_000))
+        ops.append(ev(9, 97_000, 6_000))
+    modules, ops = "\n".join(modules), "\n".join(ops)
     host = "\n".join([ev(5, 0, 100_000), ev(6, 10_000, 50_000), ev(6, 70_000, 25_000)])
     return f"""
 planes {{
@@ -72,15 +83,19 @@ planes {{
 """
 
 
-@pytest.fixture(scope="module")
-def trace(tmp_path_factory):
+def _load(tmp_path_factory, cg_step: bool = False) -> tracing.Trace:
     import jax
 
     d = tmp_path_factory.mktemp("trace")
     out = d / "plugins" / "profile" / "run" / "host.xplane.pb"
     out.parent.mkdir(parents=True)
-    out.write_bytes(jax.profiler.ProfileData.text_proto_to_serialized_xspace(_xspace()))
+    out.write_bytes(jax.profiler.ProfileData.text_proto_to_serialized_xspace(_xspace(cg_step)))
     return tracing.load(d)
+
+
+@pytest.fixture(scope="module")
+def trace(tmp_path_factory):
+    return _load(tmp_path_factory)
 
 
 # one program span, 60,000..68,000 ns on the trace's clock
@@ -90,6 +105,16 @@ PROGRAM_SPANS = [{"name": "session.optimize", "ts": (START + 60_000) / 1e9, "dur
 @pytest.fixture(scope="module")
 def reduction(trace):
     return tracing.reduce(trace, PROGRAM_SPANS)
+
+
+@pytest.fixture(scope="module")
+def cg_trace(tmp_path_factory):
+    return _load(tmp_path_factory, cg_step=True)
+
+
+@pytest.fixture(scope="module")
+def cg_reduction(cg_trace):
+    return tracing.reduce(cg_trace, PROGRAM_SPANS)
 
 
 def test_reduction_counts_programs_kernels_and_busy_time(reduction):
@@ -141,6 +166,31 @@ def test_device_ops_longest_first(reduction):
     assert names[:2] == ["fusion.1", "csr_spmv.1"]
     assert sorted(names[2:]) == ["add.1", "reduce.1"]  # 1,000 ns each
     assert reduction.device_ops[0][1] == pytest.approx(12_000 / 1e9)
+
+
+def test_every_op_and_module_in_the_window_is_timed_by_name(reduction):
+    assert reduction.op_ns == {"fusion.1": 12_000, "csr_spmv.1": 6_000, "reduce.1": 1_000,
+                               "add.1": 1_000}
+    assert reduction.device_ops == [(name, ns / 1e9) for name, ns in sorted(
+        reduction.op_ns.items(), key=lambda kv: -kv[1])]
+    assert reduction.module_ns == {"jit__jitted_spmv(1)": 20_000}
+    assert reduction.devices == 1
+
+
+def test_a_kernel_in_another_program_is_timed_but_not_counted_as_spmv(reduction, cg_trace,
+                                                                     cg_reduction):
+    (op,) = [e for e in cg_trace.devices["/device:TPU:0"][tracing.OPS_LINE]
+             if e.short == "cg_spmv.2"]
+    assert tracing.KERNEL_MARK in op.name
+    r = cg_reduction
+    # clipped to the window, which ends at 100,000
+    assert r.op_ns["cg_spmv.2"] == 3_000
+    assert r.module_ns["jit_cg_step(2)"] == 4_000
+    assert r.busy_ns == 23_000
+    assert (r.kernel_ns, r.xla_ns, r.spmv_calls) == (6_000, 13_000, 2)
+    for metric in ("kernel_ms.solve", "xla_ms.solve", "spmv_roofline.solve"):
+        reader = run.load_reader(metric)
+        assert reader(_ctx(r)) == reader(_ctx(reduction))
 
 
 def test_a_trace_without_the_window_is_refused(trace):

@@ -1,17 +1,30 @@
 """The benchmark's generator copy, byte counts, peaks and spec files."""
 
+import importlib
 import json
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
-from chipbench import matrices, roofline, run
+from chipbench import drivers, matrices, roofline, run
 
 SPEC = json.loads((run.ROOT / "BENCHMARK.json").read_text())
 CONFIGS = {c["name"]: json.loads((run.ROOT / c["file"]).read_text()) for c in SPEC["configs"]}
 
 
-@pytest.mark.parametrize("name", sorted(CONFIGS))
+def _driver_of(workload: dict):
+    traffic = json.loads((run.PKG / "traffic" / f"{workload['traffic']}.json").read_text())
+    return importlib.import_module(f"chipbench.drivers.{traffic['driver']}")
+
+
+# the configurations whose cells take their inputs from chipbench.matrices;
+# a driver with a generator of its own brings that generator's tests
+MATRIX_CONFIGS = sorted({w["config"] for w in SPEC["workloads"]
+                         if getattr(_driver_of(w), "inputs", None) is matrices.inputs})
+
+
+@pytest.mark.parametrize("name", MATRIX_CONFIGS)
 def test_generator_copy_matches_the_program_byte_for_byte(name):
     from repro.sparse.generate import SUITE, generate_by_name
 
@@ -25,7 +38,7 @@ def test_generator_copy_matches_the_program_byte_for_byte(name):
     assert ours.tobytes() == theirs.tobytes()
 
 
-@pytest.mark.parametrize("name", sorted(CONFIGS))
+@pytest.mark.parametrize("name", MATRIX_CONFIGS)
 def test_seed_draws_the_values_and_keeps_the_pattern(name):
     m = CONFIGS[name]["matrix"]
     a = matrices.generate(m, 2**31 + 11, scale=0.02)
@@ -37,7 +50,7 @@ def test_seed_draws_the_values_and_keeps_the_pattern(name):
     assert a[a != 0].min() >= 0.1
 
 
-@pytest.mark.parametrize("name", sorted(CONFIGS))
+@pytest.mark.parametrize("name", MATRIX_CONFIGS)
 @pytest.mark.parametrize("scale", [0.01, 0.05])
 def test_generate_holds_the_published_count(name, scale):
     m = CONFIGS[name]["matrix"]
@@ -98,7 +111,38 @@ def test_every_cell_finds_its_files(cell):
     assert c.per_layer
     for m in c.per_layer:
         assert callable(run.load_reader(m["name"]))
-    assert set(c.limits) == {"vector_rel_err", "eigenvalue_rel_err", "residual_gap",
-                             "iterations_short"}
-    for name in ("build_tuner", "Program", "check", "control"):
-        assert callable(getattr(c.driver, name))
+    driver = importlib.import_module(f"chipbench.drivers.{c.traffic['driver']}")
+    assert drivers.contract_faults(driver, c.limits) == []
+    assert c.driver is driver
+    assert set(c.limits) == set(driver.CHECKS)
+    assert 0 < c.cpu_scale <= 1
+
+
+def _partial_driver(checks):
+    """A driver that keeps only part of the contract."""
+
+    class Program:
+        def request(self, i):
+            return None
+
+    return SimpleNamespace(CHECKS=checks, inputs=lambda config, seed, scale: None,
+                           Program=Program)
+
+
+def test_a_driver_that_breaks_the_contract_is_named():
+    missing = ["no callable build_tuner", "no callable check", "no callable control",
+               "Program has no method release"]
+    assert drivers.contract_faults(_partial_driver(["y_rel_err"]), {"y_rel_err": 0.0}) == [
+        *missing, "CHECKS is not a tuple of names"]
+    assert drivers.contract_faults(_partial_driver(("y_rel_err",)),
+                                   {"y_rel_err": 0.0, "z": 0.0}) == [
+        *missing, "the limits name ['y_rel_err', 'z'], CHECKS ['y_rel_err']"]
+
+
+def test_a_cell_whose_limits_differ_from_its_checks_is_refused():
+    from dataclasses import replace
+
+    c = run.load_cell("rim.solve")
+    broken = replace(c, limits={**c.limits, "answered_short": 0.0})
+    with pytest.raises(SystemExit, match="breaks the contract.*answered_short"):
+        broken.driver
