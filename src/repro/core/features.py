@@ -14,6 +14,8 @@ from dataclasses import dataclass, asdict, fields
 
 import numpy as np
 
+from repro.sparse.host import HostCSR
+
 FEATURE_NAMES = (
     "n",
     "nnz",
@@ -72,19 +74,25 @@ def features_from_row_counts(counts: np.ndarray, n_rows: int) -> SparsityFeature
     )
 
 
-def row_nnz_counts(dense: np.ndarray) -> np.ndarray:
-    """Nonzeros per row of a dense-held matrix (int64, length ``n_rows``).
+def row_nnz_counts(dense) -> np.ndarray:
+    """Nonzeros per row of a dense-held matrix (int64, length ``n_rows``);
+    of a ``HostCSR``, its entries per row, from ``indptr``.
 
     The shared primitive under ``extract_features`` and the row partitioner
     (``repro.partition``): both need the same histogram, and the partitioner
     derives every per-block feature vector from slices of this one array, so
     the Table-7 ``f`` cost is paid once per matrix, not once per block.
     """
+    if isinstance(dense, HostCSR):
+        return dense.row_counts()
     return (np.asarray(dense) != 0).sum(axis=1).astype(np.int64)
 
 
-def extract_features(dense: np.ndarray) -> SparsityFeatures:
-    """Table-2 features of a dense-held matrix (run-time mode step 1)."""
+def extract_features(dense) -> SparsityFeatures:
+    """Table-2 features of a dense-held matrix (run-time mode step 1), or
+    of a ``HostCSR`` from its ``indptr``."""
+    if isinstance(dense, HostCSR):
+        return features_from_csr_indptr(dense.indptr)
     dense = np.asarray(dense)
     return features_from_row_counts(row_nnz_counts(dense), dense.shape[0])
 

@@ -47,8 +47,15 @@ from repro.core.autotuner import (
     should_convert,
 )
 from repro.core.cache import CacheEntry, TuningCache
-from repro.core.features import SparsityFeatures, extract_features
-from repro.kernels.common import DEFAULT_SCHEDULE, InfeasibleConfig, KernelSchedule
+from repro.core.features import SparsityFeatures, extract_features, row_nnz_counts
+from repro.core.tuning_space import TuningConfig
+from repro.kernels.common import (
+    DEFAULT_SCHEDULE,
+    NNZ_TILE_CHOICES,
+    ROWS_PER_BLOCK_CHOICES,
+    InfeasibleConfig,
+    KernelSchedule,
+)
 from repro.kernels.ops import (
     compile_spmspv as _compile_spmspv_kernel,
     compile_spmv,
@@ -58,7 +65,8 @@ from repro.kernels.ops import (
 )
 from repro.obs.trace import counted_span as _counted_span
 from repro.obs.trace import span as _span
-from repro.sparse.registry import default_format, format_names
+from repro.sparse.host import HostCSR
+from repro.sparse.registry import csr_storable, default_format, format_names
 from repro.utils.logging import get_logger
 
 log = get_logger("core.session")
@@ -242,14 +250,22 @@ class AutoSpmvSession:
         # (bucket, objective, fmt) -> regressor latency estimate: one cheap
         # inference per arm per fleet, dropped with the bucket on invalidate
         self._pred_memo: dict[tuple[str, str, str], float] = {}
+        # (fingerprint, planned schedule) -> the storable schedule served in
+        # its place, where the matrix's tiled stream refused the plan
+        self._storable: OrderedDict[tuple, KernelSchedule] = OrderedDict()
 
     # ------------------------------------------------------------- internals
     @staticmethod
-    def _fingerprint(dense: np.ndarray) -> str:
+    def _fingerprint(dense) -> str:
         """``matrix_fingerprint`` under a ``session.fingerprint`` span: the
-        hash reads every byte of the dense input."""
-        dense = np.asarray(dense)
-        with _counted_span("session.fingerprint", bytes=int(dense.nbytes)):
+        hash reads every byte of the input, dense or a ``HostCSR``'s three
+        arrays (``kind``)."""
+        if isinstance(dense, HostCSR):
+            kind, nbytes = "csr", dense.nbytes
+        else:
+            dense = np.asarray(dense)
+            kind, nbytes = "dense", int(dense.nbytes)
+        with _counted_span("session.fingerprint", bytes=nbytes, kind=kind):
             return matrix_fingerprint(dense)
 
     def _analyze(
@@ -346,9 +362,46 @@ class AutoSpmvSession:
             else:
                 self.stats.cache_hits += 1
             sp.set(bucket=bucket, cache_hit=hit)
-            schedule = entry.kernel_schedule()
-            kernel = self._compile(dense, fp, default_format(), schedule)
+            planned = entry.kernel_schedule()
+            schedule = self._storable.get((fp, planned), planned)
+            try:
+                kernel = self._compile(dense, fp, default_format(), schedule)
+            except InfeasibleConfig as exc:
+                if schedule != planned:
+                    raise
+                # the bucket's plan cannot store this matrix's stream:
+                # serve the best storable tiling, and remember it
+                schedule = self._storable_schedule(dense, feats, objective, planned)
+                log.info("compile-time: %s refused (%s); serving %s", planned, exc, schedule)
+                self._storable[(fp, planned)] = schedule
+                while len(self._storable) > self._feat_memo_limit:
+                    self._storable.popitem(last=False)
+                kernel = self._compile(dense, fp, default_format(), schedule)
         return CompileTimeResult(feats, schedule, kernel, dict(entry.predicted))
+
+    def _storable_schedule(
+        self, dense, feats: SparsityFeatures, objective: str, planned: KernelSchedule
+    ) -> KernelSchedule:
+        """The best-rated schedule that differs from ``planned`` only in its
+        CSR tiling and whose tiled stream this matrix can store: every
+        ``(rows_per_block, nnz_tile)`` pair of the tuning space, counted
+        from the matrix's row counts, rated by the predictor's estimate of
+        ``objective`` (fewer stored entries breaks a tie)."""
+        counts = row_nnz_counts(dense)
+        rated = []
+        for rpb in ROWS_PER_BLOCK_CHOICES:
+            for nt in NNZ_TILE_CHOICES:
+                sched = planned.replace(rows_per_block=rpb, nnz_tile=nt)
+                stored = csr_storable(counts, sched)
+                if stored is None:
+                    continue
+                est = self.tuner.predictor.estimate_objective(
+                    feats, TuningConfig(default_format(), sched), objective
+                )
+                rated.append((-est if objective == "efficiency" else est, stored, sched))
+        if not rated:
+            raise InfeasibleConfig("no CSR tiling of the tuning space can store this matrix")
+        return min(rated, key=lambda r: r[:2])[2]
 
     # -------------------------------------------------------------- run time
     def run_time_optimize(
@@ -797,8 +850,6 @@ class AutoSpmvSession:
         if cached is not None:
             return cached
         try:
-            from repro.core.tuning_space import TuningConfig
-
             est = float(
                 self.tuner.predictor.estimate_objective(
                     feats, TuningConfig(fmt, schedule), "latency"

@@ -54,12 +54,22 @@ def __getattr__(name):
 
 
 def prepare(
-    dense: np.ndarray, fmt: str, schedule: KernelSchedule = DEFAULT_SCHEDULE
+    matrix, fmt: str, schedule: KernelSchedule = DEFAULT_SCHEDULE
 ) -> Any:
-    """Convert ``dense`` to ``fmt`` with schedule-aligned storage geometry."""
+    """Convert ``matrix`` (dense, or a ``HostCSR``) to ``fmt`` with
+    schedule-aligned storage geometry."""
+    from repro.sparse.host import HostCSR
     from repro.sparse.registry import get_format
 
-    return get_format(fmt).prepare(np.asarray(dense), schedule)
+    spec = get_format(fmt)
+    if isinstance(matrix, HostCSR):
+        if spec.prepare_csr is None:
+            raise InfeasibleConfig(
+                f"format {fmt!r} converts only from a dense matrix; "
+                "a HostCSR input needs a format with prepare_csr"
+            )
+        return spec.prepare_csr(matrix, schedule)
+    return spec.prepare(np.asarray(matrix), schedule)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
@@ -131,14 +141,24 @@ class PreparedSpmv:
         return None if site is None else site(self.mat, np.float32)
 
 
-def matrix_fingerprint(dense: np.ndarray) -> str:
-    """Content hash of a dense-held matrix — the kernel-memo identity.
+def matrix_fingerprint(matrix) -> str:
+    """Content hash of a matrix — the kernel-memo identity.
 
     Two matrices with equal bytes/shape/dtype share every prepared kernel;
-    the session layer uses this to deduplicate batched tuning requests.
+    the session layer uses this to deduplicate batched tuning requests. A
+    ``HostCSR`` is hashed from its shape, dtypes and three arrays, under a
+    ``csr`` tag, so it never shares an identity with a dense matrix.
     """
-    a = np.ascontiguousarray(np.asarray(dense))
+    from repro.sparse.host import HostCSR
+
     h = hashlib.sha256()
+    if isinstance(matrix, HostCSR):
+        parts = [np.ascontiguousarray(a) for a in (matrix.indptr, matrix.indices, matrix.data)]
+        h.update(str(("csr", matrix.shape, [str(a.dtype) for a in parts])).encode())
+        for a in parts:
+            h.update(memoryview(a).cast("B"))
+        return h.hexdigest()[:32]
+    a = np.ascontiguousarray(np.asarray(matrix))
     h.update(str((a.shape, str(a.dtype))).encode())
     h.update(a.tobytes())
     return h.hexdigest()[:32]

@@ -15,6 +15,13 @@ bare pattern name (``fem``, ``webgraph``, ...); suite names win. CG
 symmetrizes the matrix into an SPD operator (``(A + Aᵀ)/2`` plus a
 diagonal dominance margin) since CG's contract requires one.
 
+``--matrix hpcg`` is HPCG's 27-point operator (``generate.hpcg_stencil``)
+on the grid ``--grid`` gives, or on HPCG's 104³ shrunk by ``--scale`` in
+rows. It is built as a host CSR and handed to the solver as it is, never
+made dense; it is SPD already, so CG takes it unchanged:
+``python -m repro.launch.solve --solver cg --matrix hpcg --grid 104
+--max-iters 50 --tol 0``.
+
 Convergence metadata is always written as JSON (default
 ``artifacts/solve/SOLVE_<solver>_<matrix>.json``) so CI and fleets can
 assert on the emitted artifact rather than parse logs. With
@@ -35,22 +42,41 @@ import numpy as np
 
 from repro.core.session import AutoSpmvSession, build_tuner
 from repro.sparse.generate import (
+    HPCG_GRID,
     MATRIX_NAMES,
     PATTERN_NAMES,
     SUITE,
     generate_by_name,
+    hpcg_stencil,
     random_matrix,
 )
+from repro.sparse.host import HostCSR
 from repro.utils.compile_cache import configure_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.solve")
 
 SOLVER_NAMES = ("pagerank", "cg", "power")
+STENCIL = "hpcg"
 
 
-def resolve_matrix(name: str, scale: float, seed: int) -> np.ndarray:
-    """Suite name or pattern name -> dense matrix (suite names win)."""
+def stencil_grid(grid: list[int] | None, scale: float) -> tuple[int, int, int]:
+    """``--grid`` as (nx, ny, nz): one side for a cube, or three; without
+    it HPCG's grid with ``scale`` of its rows, each side at least 2."""
+    if grid:
+        if len(grid) not in (1, 3) or min(grid) < 2:
+            raise SystemExit(f"--grid takes one side or three, each at least 2; got {grid}")
+        return tuple(grid * 3) if len(grid) == 1 else tuple(grid)
+    return tuple(max(2, round(g * scale ** (1 / 3))) for g in HPCG_GRID)
+
+
+def resolve_matrix(
+    name: str, scale: float, seed: int, grid: list[int] | None = None
+) -> np.ndarray | HostCSR:
+    """Suite name or pattern name -> dense matrix (suite names win);
+    ``hpcg`` -> HPCG's stencil as a host CSR."""
+    if name == STENCIL:
+        return hpcg_stencil(*stencil_grid(grid, scale))
     if name in SUITE:
         return generate_by_name(name, scale=scale)
     if name in PATTERN_NAMES:
@@ -58,8 +84,8 @@ def resolve_matrix(name: str, scale: float, seed: int) -> np.ndarray:
         return random_matrix(n, avg_nnz=8.0, pattern=name, seed=seed)
     raise SystemExit(
         f"unknown matrix {name!r}: expected a suite name "
-        f"({', '.join(MATRIX_NAMES[:4])}, ...) or a pattern "
-        f"({', '.join(PATTERN_NAMES)})"
+        f"({', '.join(MATRIX_NAMES[:4])}, ...), a pattern "
+        f"({', '.join(PATTERN_NAMES)}) or {STENCIL!r}"
     )
 
 
@@ -90,9 +116,12 @@ def run_solve(args):
             policy.n_phases,
         )
 
-    dense = resolve_matrix(args.matrix, args.scale, args.seed)
+    dense = resolve_matrix(args.matrix, args.scale, args.seed, args.grid)
+    sparse_input = isinstance(dense, HostCSR)
+    if sparse_input and args.solver != "cg":
+        raise SystemExit(f"--matrix {STENCIL} is solved by --solver cg")
     n = dense.shape[0]
-    nnz = int((dense != 0).sum())
+    nnz = dense.nnz if sparse_input else int((dense != 0).sum())
     log.info("matrix %s: n=%d nnz=%d", args.matrix, n, nnz)
 
     from repro.solvers import cg, pagerank, power_iteration
@@ -118,7 +147,7 @@ def run_solve(args):
             b = rng.standard_normal(n).astype(np.float32)
             result = cg(
                 session,
-                spd_operator(dense),
+                dense if sparse_input else spd_operator(dense),
                 b,
                 tol=args.tol,
                 max_iters=args.max_iters,
@@ -197,6 +226,9 @@ def main(argv=None):
                     help="suite matrix name or generator pattern")
     ap.add_argument("--scale", type=float, default=0.0008,
                     help="suite scale factor (matches the bench smoke tier)")
+    ap.add_argument("--grid", type=int, nargs="+", default=None,
+                    help=f"grid of --matrix {STENCIL}: one side, or nx ny nz "
+                         "(default: HPCG's 104^3 shrunk by --scale)")
     ap.add_argument("--max-iters", type=int, default=100)
     ap.add_argument("--tol", type=float, default=1e-8,
                     help="convergence tolerance (solver-specific residual)")

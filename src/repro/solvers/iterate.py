@@ -10,17 +10,25 @@ bandit. A solve is one span tree under a ``solver.solve`` root::
 
     solver.solve                 (solver, max_iters)
      ├─ solver.setup             (the per-solve set-up, once)
-     │   ├─ solver.count_nnz     (the ``dense != 0`` count)
+     │   ├─ solver.count_nnz     (the ``dense != 0`` count; ``indptr[-1]`` of
+     │   │                        a ``HostCSR``)
      │   └─ session.serve        (serve_optimize)
-     │       ├─ session.fingerprint
+     │       ├─ session.fingerprint  (bytes, kind: dense or csr)
      │       └─ session.optimize
+     │           └─ kernel.compile   (a kernel-memo miss)
+     │               └─ sparse.convert  (a HostCSR's tiled stream; stored, nnz)
      └─ solver.iterate × N       (iteration; bumps solver_iterations_total)
-         └─ kernel.execute       (one per matvec: the time matvec_seconds holds;
-                                  fmt, and gather: where the kernel gathers x)
+         ├─ kernel.execute       (one per matvec: the time matvec_seconds holds;
+         │                        fmt, and gather: where the kernel gathers x)
+         └─ solver.vector        (CG: the step's host vector work, float64)
 
-``solver.solve``, ``solver.setup``, ``solver.count_nnz``, ``solver.iterate``
-and the session's ``session.fingerprint`` carry the thread's resource
-deltas (``counted_span``).
+``solver.solve``, ``solver.setup``, ``solver.count_nnz``, ``solver.iterate``,
+``solver.vector`` and the session's ``session.fingerprint`` carry the
+thread's resource deltas (``counted_span``).
+
+The matrix is dense, or a ``repro.sparse.host.HostCSR`` for one too large
+to hold dense: the solver then never makes it dense, and the session plans
+and converts it from its arrays.
 
 Solvers (``pagerank`` / ``cg`` / ``power``) express one iteration as a
 ``step(matvec, state) -> (state, residual)`` callable and hand the loop to
@@ -44,6 +52,7 @@ from repro.obs.metrics import get_metrics
 from repro.obs.trace import counted_span as _counted_span
 from repro.obs.trace import span as _span
 from repro.solvers.adaptive import SPMSPV, AdaptiveSpmvPolicy
+from repro.sparse.host import HostCSR
 from repro.utils.logging import get_logger
 
 log = get_logger("solvers.iterate")
@@ -105,7 +114,8 @@ class IterativeSolver:
         The ``AutoSpmvSession`` that owns planning, caching, and telemetry.
     dense:
         The matrix actually multiplied each iteration (solvers pass the
-        normalized / symmetrized operator, not the raw generator output).
+        normalized / symmetrized operator, not the raw generator output):
+        a dense array, or a ``HostCSR``.
     policy:
         Optional ``AdaptiveSpmvPolicy``; without one every matvec is SpMV.
     force_fp32:
@@ -118,7 +128,7 @@ class IterativeSolver:
     def __init__(
         self,
         session,
-        dense: np.ndarray,
+        dense: np.ndarray | HostCSR,
         *,
         name: str = "solver",
         objective: str = "latency",
@@ -128,7 +138,10 @@ class IterativeSolver:
         force_fp32: bool = True,
     ):
         self.session = session
-        self.dense = np.asarray(dense, dtype=np.float32)
+        if isinstance(dense, HostCSR):
+            self.dense = dense.astype(np.float32)
+        else:
+            self.dense = np.asarray(dense, dtype=np.float32)
         self.name = name
         self.objective = objective
         self.tol = float(tol)
@@ -151,7 +164,10 @@ class IterativeSolver:
             return self.plan
         with _counted_span("solver.setup", solver=self.name):
             with _counted_span("solver.count_nnz"):
-                self.nnz = int((self.dense != 0).sum())
+                if isinstance(self.dense, HostCSR):
+                    self.nnz = self.dense.nnz
+                else:
+                    self.nnz = int((self.dense != 0).sum())
             plan = self.session.serve_optimize(self.dense, self.objective)
             kernel = plan.kernel
             if self.force_fp32 and plan.schedule.accum_dtype != "float32":

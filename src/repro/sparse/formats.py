@@ -210,21 +210,39 @@ def csr_tiled(
     """CSR whose stream is aligned to ``(rows_per_block, nnz_tile)`` tiles
     (see ``CSR.tiling``) — the layout the Pallas CSR kernel walks."""
     dense = np.asarray(dense)
-    n_rows, n_cols = dense.shape
-    rpb, nt = rows_per_block, nnz_tile
     rows, cols = np.nonzero(dense)
+    return _tiled(rows, cols, dense[rows, cols], dense.shape, rows_per_block, nnz_tile, dtype)
+
+
+def csr_tiled_from_host(mat, rows_per_block: int, nnz_tile: int, dtype=np.float32) -> CSR:
+    """``csr_tiled`` of a ``HostCSR``, built from its arrays, never dense."""
+    return _tiled(mat.row_ids(), mat.indices, mat.data, mat.shape, rows_per_block, nnz_tile, dtype)
+
+
+def tiled_stored(row_counts: np.ndarray, rows_per_block: int, nnz_tile: int) -> int:
+    """Entries ``csr_tiled`` stores for these nonzeros per row: each block of
+    ``rows_per_block`` rows padded to whole ``nnz_tile`` tiles, one at least."""
+    counts = np.asarray(row_counts, dtype=np.int64)
+    per_block = np.add.reduceat(counts, np.arange(0, max(counts.size, 1), rows_per_block))
+    return int((np.maximum(-(-per_block // nnz_tile), 1) * nnz_tile).sum())
+
+
+def _tiled(rows, cols, vals, shape, rpb: int, nt: int, dtype) -> CSR:
+    """The tiled stream of the entries ``(rows, cols, vals)``, rows in order."""
+    n_rows, n_cols = shape
     n_blocks = max(-(-n_rows // rpb), 1)
-    blk = rows // rpb
-    counts = np.bincount(blk, minlength=n_blocks)
+    starts = np.searchsorted(rows, np.arange(n_blocks + 1) * rpb)  # each block's first entry
+    counts = np.diff(starts)
     stored = np.maximum(-(-counts // nt), 1) * nt  # >= 1 tile per block
     offsets = np.concatenate([[0], np.cumsum(stored)])
-    starts = np.concatenate([[0], np.cumsum(counts)])
-    pos = offsets[blk] + np.arange(rows.size) - starts[blk]
+    # entry k, in block b, lands at offsets[b] + (k - starts[b])
+    pos = np.repeat(offsets[:-1] - starts[:-1], counts)
+    pos += np.arange(rows.size)
     last_row = np.minimum((np.arange(n_blocks) + 1) * rpb, max(n_rows, 1)) - 1
     data = np.zeros(int(offsets[-1]), dtype=dtype)
     indices = np.zeros(int(offsets[-1]), dtype=np.int32)
-    row_ids = np.repeat(last_row, stored).astype(np.int32)
-    data[pos] = dense[rows, cols]
+    row_ids = np.repeat(last_row.astype(np.int32), stored)
+    data[pos] = vals
     indices[pos] = cols
     row_ids[pos] = rows
     indptr = np.zeros(n_rows + 1, dtype=np.int32)

@@ -279,3 +279,39 @@ def random_matrix(
     """Free-form generator for tests and the dataset harness."""
     rng = np.random.default_rng(seed)
     return _PATTERNS[pattern](n, min(avg_nnz, n / 2), rng)
+
+
+# HPCG's local grid as its shipped hpcg.dat sets it (Dongarra, Heroux and
+# Luszczek, UT-EECS-15-736; reference implementation 3.1)
+HPCG_GRID = (104, 104, 104)
+
+
+def hpcg_stencil(nx: int, ny: int, nz: int, dtype=np.float32):
+    """HPCG's 27-point operator on an ``nx x ny x nz`` grid, as a host CSR.
+
+    Row ``ix + nx * (iy + ny * iz)`` holds 26 on the diagonal and -1 for
+    every one of its 26 neighbours that lies inside the grid, columns in
+    ascending order, as HPCG's ``GenerateProblem`` writes them: a symmetric
+    positive-definite matrix of ``(3 nx - 2)(3 ny - 2)(3 nz - 2)`` entries.
+    Built from the grid directly, never as a dense array.
+    """
+    from repro.sparse.host import HostCSR
+
+    n = nx * ny * nz
+    row = np.arange(n, dtype=np.int64)
+    ix, iy, iz = row % nx, (row // nx) % ny, row // (nx * ny)
+
+    def inside(i, d, size):
+        return (i + d >= 0) & (i + d < size)
+
+    # offsets in HPCG's loop order (z outer, x inner): ascending columns
+    offsets = [(dz, dy, dx) for dz in (-1, 0, 1) for dy in (-1, 0, 1) for dx in (-1, 0, 1)]
+    keep = np.stack([inside(iz, dz, nz) & inside(iy, dy, ny) & inside(ix, dx, nx)
+                     for dz, dy, dx in offsets], axis=1)  # (n, 27)
+    step = np.array([dz * nx * ny + dy * nx + dx for dz, dy, dx in offsets])
+    cols = (row[:, None] + step[None, :])[keep].astype(np.int32)
+    diagonal = np.array([s == 0 for s in step])
+    data = np.where(np.broadcast_to(diagonal, keep.shape)[keep], 26.0, -1.0).astype(dtype)
+    indptr = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(keep.sum(axis=1), out=indptr[1:])
+    return HostCSR(indptr, cols, data, (n, n))

@@ -21,6 +21,7 @@ Adding a format is one call::
         from_dense=myfmt_from_dense,
         to_dense=myfmt_to_dense,
         prepare=my_prepare,         # (dense, schedule) -> MyFmt, aligned
+        prepare_csr=my_prepare_csr, # optional: (HostCSR, schedule) -> MyFmt
         spmv=my_spmv,               # (mat, x, schedule) -> y, jit-traceable
         reference=my_reference,     # pure-jnp oracle, (mat, x) -> y
         footprint=my_footprint,     # (MatrixStats, schedule) -> KernelFootprint
@@ -38,6 +39,8 @@ Contract notes for plugin authors (enforced by the shared suite in
 * ``prepare`` aligns storage geometry to the ``KernelSchedule`` and raises
   ``InfeasibleConfig`` when storage would blow up (``check_storage_bytes``);
   all padding and pointer arithmetic happens there, on the host, once;
+  ``prepare_csr`` does the same from a ``repro.sparse.host.HostCSR``
+  without making it dense; a format without one refuses that input;
 * ``spmv`` is traced under ``jax.jit`` (``kernels.ops.spmv_pallas``): it
   may only read static metadata and shapes of the container, never its
   values, so a call moves no matrix storage through the host;
@@ -65,6 +68,8 @@ from repro.kernels.common import (
     ceil_to,
     pad_axis,
 )
+from repro.obs.trace import span as _span
+from repro.sparse.host import HostCSR
 
 __all__ = [
     "FormatSpec",
@@ -175,6 +180,9 @@ class FormatSpec:
     reference: Callable  # (mat, x) -> y — pure-jnp oracle
     footprint: Callable  # (MatrixStats, KernelSchedule) -> KernelFootprint
     priority: int = 100
+    # (HostCSR, KernelSchedule) -> container, tile-aligned, never dense;
+    # None where the format converts only from a dense matrix
+    prepare_csr: Callable | None = None
     # (mat, x dtype) -> where spmv gathers x: "vmem" inside the kernel,
     # "xla" before the launch; None where the format does not say
     x_gather: Callable | None = None
@@ -292,11 +300,13 @@ from repro.sparse.formats import (  # noqa: E402
     bell_to_dense,
     csr_from_dense,
     csr_tiled,
+    csr_tiled_from_host,
     csr_to_dense,
     ell_from_dense,
     ell_to_dense,
     sell_from_dense,
     sell_to_dense,
+    tiled_stored,
 )
 from repro.sparse.spmv import (  # noqa: E402  (pure-jnp oracles)
     spmv_bell as _ref_bell,
@@ -311,14 +321,33 @@ _VAL_B, _IDX_B = 4.0, 4.0  # fp32 values, int32 indices
 # --- CSR -------------------------------------------------------------------
 
 
+_CSR_ENTRY_B = 3 * 4  # value, column id and row id of a stored entry
+
+
+def csr_storable(row_counts: np.ndarray, schedule: KernelSchedule) -> int | None:
+    """Entries of the tiled CSR stream of a matrix with these nonzeros per
+    row at ``schedule``'s tiling, or None where ``check_storage_bytes``
+    would refuse it."""
+    stored = tiled_stored(row_counts, schedule.rows_per_block, schedule.nnz_tile)
+    return stored if stored * _CSR_ENTRY_B <= MAX_STORAGE_BYTES else None
+
+
+def _csr_stored(row_counts: np.ndarray, schedule: KernelSchedule) -> int:
+    stored = tiled_stored(row_counts, schedule.rows_per_block, schedule.nnz_tile)
+    check_storage_bytes(stored * _CSR_ENTRY_B, "CSR")
+    return stored
+
+
 def _csr_prepare(dense: np.ndarray, schedule: KernelSchedule) -> CSR:
     dense = np.asarray(dense)
-    rpb, nt = schedule.rows_per_block, schedule.nnz_tile
-    counts = (dense != 0).sum(axis=1)
-    per_block = np.add.reduceat(counts, np.arange(0, max(len(counts), 1), rpb))
-    stored = int((np.maximum(-(-per_block // nt), 1) * nt).sum())
-    check_storage_bytes(stored * 3 * 4, "CSR")
-    return csr_tiled(dense, rpb, nt)
+    _csr_stored((dense != 0).sum(axis=1), schedule)
+    return csr_tiled(dense, schedule.rows_per_block, schedule.nnz_tile)
+
+
+def _csr_prepare_csr(mat: HostCSR, schedule: KernelSchedule) -> CSR:
+    stored = _csr_stored(mat.row_counts(), schedule)
+    with _span("sparse.convert", fmt="csr", stored=stored, nnz=mat.nnz):
+        return csr_tiled_from_host(mat, schedule.rows_per_block, schedule.nnz_tile)
 
 
 def _csr_spmv(mat: CSR, x, schedule: KernelSchedule):
@@ -495,6 +524,7 @@ register_format(FormatSpec(
     spmv=_csr_spmv,
     reference=_ref_csr,
     footprint=_csr_footprint,
+    prepare_csr=_csr_prepare_csr,
     x_gather=_csr_x_gather,
     priority=0,
     description="Compressed Sparse Row (flat segmented-sum kernel)",

@@ -2,6 +2,7 @@
 entry points a fleet run uses, at reduced scale."""
 
 import numpy as np
+import pytest
 
 from repro.launch.serve import main as serve_main
 from repro.launch.train import main as train_main
@@ -121,3 +122,50 @@ def test_solve_cli_profile_dir_captures_the_solves_spans(tmp_path):
     assert names.count("solver.iterate") == names.count("kernel.execute") == 3
     assert {"solver.setup", "solver.count_nnz", "session.serve",
             "session.fingerprint"} <= set(names)
+
+
+def test_solve_cli_cg_on_the_hpcg_stencil_takes_no_dense_path(tmp_path, monkeypatch):
+    """``launch.solve --solver cg --matrix hpcg``: the stencil goes to CG as
+    a host CSR, which refuses to be made dense, and is never symmetrized."""
+    import json
+
+    from repro.launch import solve as solve_cli
+    from repro.launch.solve import main as solve_main
+    from repro.sparse.generate import hpcg_stencil
+
+    def densify(dense):
+        raise AssertionError("the stencil is SPD already: spd_operator must not run")
+
+    monkeypatch.setattr(solve_cli, "spd_operator", densify)
+    out = tmp_path / "solve.json"
+    res = solve_main([
+        "--solver", "cg",
+        "--matrix", "hpcg",
+        "--grid", "5", "4", "3",
+        "--max-iters", "40",
+        "--tol", "0",
+        "--train-matrices", "2",
+        "--json-out", str(out),
+    ])
+    payload = json.loads(out.read_text())
+    assert payload["n"] == 60 and payload["nnz"] == 13 * 10 * 7
+    assert payload["iterations"] == 40 and payload["fmt"] == "csr"
+    # toward the float64 solution of the same system
+    mat = hpcg_stencil(5, 4, 3)
+    dense = np.zeros(mat.shape)
+    np.add.at(dense, (mat.row_ids(), mat.indices), mat.data)
+    b = np.random.default_rng(0).standard_normal(60).astype(np.float32)
+    want = np.linalg.solve(dense, b.astype(np.float64))
+    assert np.abs(res.value - want).max() / np.abs(want).max() < 1e-5
+    assert payload["residual"] < 1e-6
+
+
+def test_solve_cli_grid_and_scale_size_the_stencil():
+    from repro.launch.solve import stencil_grid
+
+    assert stencil_grid([7], 1.0) == (7, 7, 7)
+    assert stencil_grid([5, 4, 3], 1.0) == (5, 4, 3)
+    assert stencil_grid(None, 1.0) == (104, 104, 104)
+    assert stencil_grid(None, 0.001) == (10, 10, 10)
+    with pytest.raises(SystemExit, match="--grid"):
+        stencil_grid([4, 4], 1.0)

@@ -172,6 +172,22 @@ def test_csr_gather_of_x_compiles_at_the_cells_widths(one_chip, n, nt, gather):
     assert (GATHER_SCOPE in text) == (gather == "xla")
 
 
+@pytest.mark.parametrize("rpb,nt,stored", [(512, 1024, 31_070_208), (128, 128, 29_998_336)])
+def test_csr_compiles_at_the_hpcg_cells_shape(one_chip, rpb, nt, stored):
+    """HPCG's 104^3 stencil (1,124,864 rows) at two storable tilings and
+    their stream lengths: x is past the VMEM bound, so XLA gathers it."""
+    n = 104**3
+    sched = KernelSchedule(rows_per_block=rpb, nnz_tile=nt, unroll=8)
+    text = _compile(
+        lambda d, c, r, x: csr_spmv_pallas(d, c, r, x, n, (rpb, nt), sched, interpret=False),
+        _sds(one_chip, (stored,)),
+        _sds(one_chip, (stored,), jnp.int32),
+        _sds(one_chip, (stored,), jnp.int32),
+        _sds(one_chip, (n,)),
+    ).as_text()
+    assert GATHER_SCOPE in text
+
+
 @pytest.fixture()
 def bcsr_spmv_pallas():
     """The BCSR kernel, with the plugin registered for this test only: the
