@@ -8,11 +8,15 @@ tile per block), so each tile belongs to exactly one row block. The grid
 walks the tiles in row order:
 
 * X lives in VMEM for the whole call, zero-padded to ``(R, 128)`` (R =
-  ceil(n_cols / 128)); each tile's column ids arrive as a ``(nnz_tile/128,
-  128)`` plane and the kernel gathers X from VMEM in two levels: for each of
-  the R rows of X, a lane gather by ``col & 127``, kept where ``col >> 7``
-  names that row (``_vmem_gather``). Where X is not float32, or R passes
-  what that walk can afford (``x_gather``), XLA gathers X before the launch
+  ceil(n_cols / 128) in whole steps of the walk); each tile's column ids
+  arrive as a ``(nnz_tile/128, 128)`` plane and the kernel gathers X from
+  VMEM in two levels: for each row of X in the tile's window, a lane gather
+  by ``col & 127``, kept where ``col >> 7`` names that row
+  (``_vmem_gather``). The window is the stream's own (``x_window``, computed
+  once in conversion): each tile walks the same whole number of steps from
+  its own scalar-prefetched first row, so a banded matrix walks the few rows
+  its tiles reach and not all of X. Where X is not float32, or the walk
+  passes what it can afford (``x_gather``), XLA gathers X before the launch
   into a plane shaped like the values instead;
 * a tile -> row-block map (each tile's first row id over ``rows_per_block``,
   scalar-prefetched) drives the output index map, so consecutive tiles of
@@ -35,6 +39,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -43,6 +48,7 @@ from repro.kernels.common import (
     GATHER_SCOPE,
     LANE,
     SUBLANE,
+    VMEM_BYTES,
     KernelSchedule,
     ceil_to,
     compiler_params,
@@ -51,19 +57,31 @@ from repro.kernels.common import (
     row_sums,
 )
 
-# Rows of X the in-kernel gather may walk per full (8, 128) vreg of column
-# ids. The walk costs ~3.1 ns per row of X for every tile of up to 1,024
-# entries, on top of ~0.41 us a tile, so its cost per stream entry grows
-# with R, while XLA's gather costs a fixed ~7.6 ns per entry at 1,024-wide
-# tiles and ~10 ns at 128-wide ones (TPU v5e; ``benchmarks/csr_gather.py``).
-# The two meet near R = 2,400 at 1,024 and R = 290 at 128; the bound, in
-# whole steps of the walk, stays under both.
+# Rows of X the in-kernel gather may walk per tile, for a tile that fills a
+# full (8, 128) vreg of column ids. The walk costs ~3.1 ns per row of X for
+# every tile of up to 1,024 entries, on top of ~0.41 us a tile, so its cost
+# per stream entry grows with R, the rows walked, while XLA's gather costs a
+# fixed ~7.6 ns per entry at 1,024-wide tiles and ~10 ns at 128-wide ones
+# (TPU v5e; ``benchmarks/csr_gather.py``, whose window sweep reads the same
+# ~3.2 ns a row from a windowed start). The two meet near R = 2,400 at 1,024
+# and R = 290 at 128; the bound, in whole steps of the walk, stays under both.
 VMEM_GATHER_MAX_ROWS = 2304
 # Rows of X per step of the walk, unrolled in the step: Mosaic runs a loop's
 # steps one after another, so a one-row step waits out each lane permute.
 # On a TPU v5e a row costs ~87 ns at 1 row a step, 4.4 ns at 32, 3.1 at 64
 # and 2.5 at 128; 64 keeps the unrolled step, and its compile, moderate.
 WALK_ROWS = 64
+# Bytes of a float32 X the kernel may hold in VMEM. The pipeline keeps two
+# buffers of X's block, so X takes twice this: half of ``VMEM_BYTES``, which
+# leaves the other half to the double-buffered tiles and the one-hot
+# reduction's (rows_per_block, nnz_tile) temporaries, a few MiB at 512 x
+# 1,024. 16 MiB is 32,768 rows of 128: n_cols up to ~4.2M.
+X_VMEM_BYTES = VMEM_BYTES // 4
+# Bytes of SMEM the scalar-prefetched int32 maps may take. A TPU v5e core
+# has 1 MiB of SMEM, and Mosaic refuses a kernel whose prefetched operands
+# pass it; a window's starts double the one map every call prefetches, so
+# a window is kept for streams of up to 65,536 tiles, where both take half.
+PREFETCH_SMEM_BYTES = 512 * 1024
 _LANE_BITS = LANE.bit_length() - 1
 _LANE_GATHER = lax.GatherDimensionNumbers(
     offset_dims=(),
@@ -79,28 +97,61 @@ def x_rows(n_cols: int) -> int:
     return ceil_to(-(-n_cols // LANE), WALK_ROWS)
 
 
-def x_gather(n_cols: int, x_dtype, nnz_tile: int) -> str:
+def x_window(
+    indices: np.ndarray, nnz_tile: int, n_cols: int
+) -> tuple[np.ndarray | None, int]:
+    """Each tile's window of X, from a tiled stream's column ids (host side).
+
+    Returns the first row of 128 each tile's walk starts from, aligned down
+    to a sublane so the walk's dynamic slice stays aligned, as ``(n_tiles,)``
+    int32; and the rows every tile walks: the widest tile's span in whole
+    steps of the walk. A start is pulled back where its walk would run past
+    ``x_rows(n_cols)``. Where the walk covers all of X, or the starts and
+    the tile -> row-block map would not both fit ``PREFETCH_SMEM_BYTES``,
+    there is no window: the starts are None and every tile walks all of X.
+    Padding slots must hold a column of their own tile's span, or they
+    widen its window."""
+    tiles = np.asarray(indices).reshape(-1, nnz_tile)
+    first = tiles.min(axis=1) >> _LANE_BITS
+    start = first - first % SUBLANE
+    span = (tiles.max(axis=1) >> _LANE_BITS) - start + 1
+    rows = x_rows(n_cols)
+    walk = ceil_to(int(span.max(initial=1)), WALK_ROWS)
+    if walk >= rows or 2 * tiles.shape[0] * 4 > PREFETCH_SMEM_BYTES:
+        return None, rows
+    return np.minimum(start, rows - walk).astype(np.int32), walk
+
+
+def x_gather(n_cols: int, x_dtype, nnz_tile: int, walk: int | None = None) -> str:
     """Where the CSR kernel gathers an ``x`` of ``n_cols`` entries:
     ``"vmem"`` (inside the kernel) or ``"xla"`` (before the launch).
 
-    A tile narrower than 1,024 entries fills only ``nnz_tile / 128`` of a
-    vreg's 8 sublanes but pays the whole vreg per row of X, so its bound
-    shrinks in proportion. A bf16 X would be packed 16 x 128 per vreg; only
-    float32 is gathered in the kernel, never rounded to fit."""
+    The in-kernel gather costs each tile a step per ``WALK_ROWS`` rows it
+    walks: ``walk`` rows of its window (``x_window``), or all of X without
+    one. A tile narrower than 1,024 entries fills only ``nnz_tile / 128`` of
+    a vreg's 8 sublanes but pays the whole vreg per row walked, so its bound
+    shrinks in proportion. X itself must fit ``X_VMEM_BYTES``. A bf16 X
+    would be packed 16 x 128 per vreg; only float32 is gathered in the
+    kernel, never rounded to fit."""
+    walked = x_rows(n_cols) if walk is None else walk
     filled = min(nnz_tile // LANE, SUBLANE)
-    fits = x_rows(n_cols) * SUBLANE <= VMEM_GATHER_MAX_ROWS * filled
+    fits = (
+        walked * SUBLANE <= VMEM_GATHER_MAX_ROWS * filled
+        and x_rows(n_cols) * LANE * 4 <= X_VMEM_BYTES
+    )
     return "vmem" if jnp.dtype(x_dtype) == jnp.float32 and fits else "xla"
 
 
-def _vmem_gather(cols: jax.Array, x_ref) -> jax.Array:
+def _vmem_gather(cols: jax.Array, x_ref, start, walk: int) -> jax.Array:
     """``x[cols]`` for a ``(k, 128)`` plane of column ids, from X held as
     ``(R, 128)`` in VMEM; returns the ``(1, k * 128)`` row of the tile.
 
-    Mosaic gathers lanes within one vreg only, so each row of X in turn is
-    broadcast over the plane, lane-gathered by ``col & 127`` and kept where
-    ``col >> 7`` names it. A pure selection: every entry is one value of X.
-    A one-row plane is widened to a full vreg, which Mosaic needs to gather,
-    and cut back."""
+    Mosaic gathers lanes within one vreg only, so each of the ``walk`` rows
+    of X from row ``start`` (row 0 where it is None) in turn is broadcast
+    over the plane, lane-gathered by ``col & 127`` and kept where ``col >>
+    7`` names it. A pure selection: every entry is one value of X, from the
+    tile's window. A one-row plane is widened to a full vreg, which Mosaic
+    needs to gather, and cut back."""
     k = cols.shape[0]
     shape = (SUBLANE, LANE) if k == 1 else cols.shape
     hi = jnp.broadcast_to(cols >> _LANE_BITS, shape)
@@ -108,6 +159,8 @@ def _vmem_gather(cols: jax.Array, x_ref) -> jax.Array:
 
     def step(i, xg):
         base = pl.multiple_of(i * WALK_ROWS, WALK_ROWS)
+        if start is not None:
+            base = pl.multiple_of(start + base, SUBLANE)
         rows = x_ref[pl.ds(base, WALK_ROWS), :]
         hi_in_step = hi - base
         for r in range(WALK_ROWS):
@@ -119,24 +172,33 @@ def _vmem_gather(cols: jax.Array, x_ref) -> jax.Array:
             xg = jnp.where(hi_in_step == r, got, xg)
         return xg
 
-    steps = x_ref.shape[0] // WALK_ROWS
-    xg = lax.fori_loop(0, steps, step, jnp.zeros(shape, x_ref.dtype))
+    xg = lax.fori_loop(0, walk // WALK_ROWS, step, jnp.zeros(shape, x_ref.dtype))
     return xg[:k].reshape(1, k * LANE)
 
 
 def _csr_kernel(
-    bmap_ref, d_ref, v_ref, r_ref, *refs, rpb: int, unroll: int, accum_dtype
+    bmap_ref, *refs, rpb: int, unroll: int, accum_dtype, walk: int | None
 ):
-    """``v_ref`` holds the tile's X values, gathered by XLA, or, where an
-    X ref follows ``r_ref``, its column ids, gathered here from that X."""
-    *x_ref, y_ref = refs
+    """Where ``walk`` is None, ``v_ref`` holds the tile's X values, gathered
+    by XLA. Otherwise ``v_ref`` holds the tile's column ids and an X ref
+    follows ``r_ref``: the kernel gathers from ``walk`` rows of that X, from
+    the tile's start where a window-start ref follows ``bmap_ref``, else
+    from row 0."""
+    if walk is None:
+        d_ref, v_ref, r_ref, y_ref = refs
+    else:
+        *start_ref, d_ref, v_ref, r_ref, x_ref, y_ref = refs
     t = pl.program_id(0)
 
     @pl.when(first_of_run(bmap_ref, t))
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    xg = _vmem_gather(v_ref[0], x_ref[0]) if x_ref else v_ref[0]  # (1, nt)
+    if walk is None:
+        xg = v_ref[0]
+    else:
+        start = start_ref[0][t] if start_ref else None
+        xg = _vmem_gather(v_ref[0], x_ref, start, walk)  # (1, nt)
     p = d_ref[0].astype(accum_dtype) * xg.astype(accum_dtype)
     local = r_ref[0] - bmap_ref[t] * rpb  # (1, nt) row within the block
     nt = p.shape[1]
@@ -155,6 +217,8 @@ def csr_spmv_pallas(
     tiling: tuple[int, int],
     schedule: KernelSchedule,
     *,
+    x_start: jax.Array | None = None,
+    x_walk: int | None = None,
     gather: str | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -162,29 +226,38 @@ def csr_spmv_pallas(
 
     ``data/indices/row_ids: (nnz_pad,)`` laid out by ``prepare`` for
     ``tiling = (rows_per_block, nnz_tile)``: every tile lies inside one row
-    block and every row block has at least one tile. The schedule supplies
-    the numerics (``accum_dtype``, ``unroll``). ``gather`` overrides
-    ``x_gather``'s choice of where X is gathered (tests and kernel timings
-    only). Returns ``y: (n_row_blocks * rows_per_block,)``.
+    block and every row block has at least one tile. ``x_start`` and
+    ``x_walk`` are the stream's window of X (``x_window``): tile ``t`` reads
+    only rows ``x_start[t]`` to ``x_start[t] + x_walk`` of X; without a
+    start every tile walks all of X. The schedule supplies the numerics
+    (``accum_dtype``, ``unroll``). ``gather`` overrides ``x_gather``'s
+    choice of where X is gathered (tests and kernel timings only). Returns
+    ``y: (n_row_blocks * rows_per_block,)``.
     """
     rpb, nt = tiling
     (nnz_pad,) = data.shape
     if nnz_pad % nt or nt % schedule.unroll:
         raise ValueError(f"CSR stream {nnz_pad} not aligned to nnz_tile {nt}")
+    if x_start is not None and not x_walk:
+        raise ValueError("a window of X takes its walk, x_walk, with x_start")
     n_tiles = nnz_pad // nt
     n_blocks = -(-n_rows // rpb)
     tiles = lambda a: a.reshape(n_tiles, 1, nt)  # noqa: E731
-    block_of_tile = row_ids[::nt] // rpb
-    tile_spec = pl.BlockSpec((1, 1, nt), lambda t, bmap: (t, 0, 0))
-    if (gather or x_gather(x.shape[0], x.dtype, nt)) == "vmem":
-        rows = x_rows(x.shape[0])
+    maps = (row_ids[::nt] // rpb,)  # tile -> row block
+    tile_spec = pl.BlockSpec((1, 1, nt), lambda t, *_: (t, 0, 0))
+    rows = x_rows(x.shape[0])
+    walk = rows if x_start is None else x_walk
+    if (gather or x_gather(x.shape[0], x.dtype, nt, walk)) == "vmem":
+        if x_start is not None:
+            maps += (x_start,)
         x_held = jnp.pad(x, (0, rows * LANE - x.shape[0])).reshape(rows, LANE)
         planes = (indices.reshape(n_tiles, nt // LANE, LANE), x_held)
         plane_specs = [
-            pl.BlockSpec((1, nt // LANE, LANE), lambda t, bmap: (t, 0, 0)),
-            pl.BlockSpec((rows, LANE), lambda t, bmap: (0, 0)),  # fetched once
+            pl.BlockSpec((1, nt // LANE, LANE), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec((rows, LANE), lambda t, *_: (0, 0)),  # fetched once
         ]
     else:
+        walk = None
         with jax.named_scope(GATHER_SCOPE):
             planes = (jnp.take(x, tiles(indices), axis=0),)  # XLA gather
         plane_specs = [tile_spec]
@@ -193,12 +266,13 @@ def csr_spmv_pallas(
         rpb=rpb,
         unroll=schedule.unroll,
         accum_dtype=schedule.jnp_accum_dtype,
+        walk=walk,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(maps),
         grid=(n_tiles,),
         in_specs=[tile_spec, plane_specs[0], tile_spec, *plane_specs[1:]],
-        out_specs=pl.BlockSpec((1, 1, rpb), lambda t, bmap: (bmap[t], 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, rpb), lambda t, bmap, *_: (bmap[t], 0, 0)),
     )
     y = pl.pallas_call(
         kernel,
@@ -207,5 +281,5 @@ def csr_spmv_pallas(
         compiler_params=compiler_params("arbitrary"),  # carried accumulation
         interpret=resolve_interpret(interpret),
         name="csr_spmv",
-    )(block_of_tile, tiles(data), planes[0], tiles(row_ids), *planes[1:])
+    )(*maps, tiles(data), planes[0], tiles(row_ids), *planes[1:])
     return y.reshape(n_blocks * rpb)

@@ -140,6 +140,23 @@ class PreparedSpmv:
         site = spec_for(self.mat).x_gather
         return None if site is None else site(self.mat, np.float32)
 
+    @property
+    def x_rows_walked(self) -> int | None:
+        """Rows of 128 of x each tile walks where the kernel gathers x
+        itself from a window (CSR's ``x_walk``); None elsewhere."""
+        if self.gather != "vmem":
+            return None
+        return getattr(self.mat, "x_walk", None) or None
+
+
+def gather_attrs(kernel) -> dict:
+    """A ``kernel.execute`` span's attributes of where ``kernel`` gathers x:
+    ``gather`` ("vmem", "xla" or None) and ``x_rows_walked``."""
+    return {
+        "gather": getattr(kernel, "gather", None),
+        "x_rows_walked": getattr(kernel, "x_rows_walked", None),
+    }
+
 
 def matrix_fingerprint(matrix) -> str:
     """Content hash of a matrix — the kernel-memo identity.

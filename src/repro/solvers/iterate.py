@@ -19,7 +19,8 @@ bandit. A solve is one span tree under a ``solver.solve`` root::
      │               └─ sparse.convert  (a HostCSR's tiled stream; stored, nnz)
      └─ solver.iterate × N       (iteration; bumps solver_iterations_total)
          ├─ kernel.execute       (one per matvec: the time matvec_seconds holds;
-         │                        fmt, and gather: where the kernel gathers x)
+         │                        fmt; gather: where the kernel gathers x, and
+         │                        x_rows_walked: the rows of x a tile walks)
          └─ solver.vector        (CG: the step's host vector work, float64)
 
 ``solver.solve``, ``solver.setup``, ``solver.count_nnz``, ``solver.iterate``,
@@ -47,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.common import KernelSchedule
-from repro.kernels.ops import compile_spmv
+from repro.kernels.ops import compile_spmv, gather_attrs
 from repro.obs.metrics import get_metrics
 from repro.obs.trace import counted_span as _counted_span
 from repro.obs.trace import span as _span
@@ -219,8 +220,7 @@ class IterativeSolver:
                 dt = perf_counter() - t0
             self.modeled_work += kernel.modeled_work(active)
         else:
-            gather = getattr(self._spmv_kernel, "gather", None)
-            with _span("kernel.execute", fmt=self.plan.fmt, gather=gather):
+            with _span("kernel.execute", fmt=self.plan.fmt, **gather_attrs(self._spmv_kernel)):
                 t0 = perf_counter()
                 y = jax.block_until_ready(self._spmv_kernel(jnp.asarray(x)))
                 dt = perf_counter() - t0

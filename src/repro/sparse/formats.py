@@ -33,6 +33,7 @@ import numpy as np
 # LANE/SUBLANE live in kernels/common.py (the single source of truth for TPU
 # tiling constants); re-exported here for backward compatibility.
 from repro.kernels.common import LANE, SUBLANE
+from repro.kernels.csr import x_window
 
 # Deprecated: the four *seed* formats. New code should use
 # ``repro.sparse.registry.format_names()``, which also covers plugins.
@@ -55,8 +56,12 @@ class CSR:
     ``tiling`` is the ``(rows_per_block, nnz_tile)`` geometry the stored
     stream is aligned to (``csr_tiled``): each block of ``rows_per_block``
     rows owns a whole number of ``nnz_tile`` tiles, at least one, its tail
-    padded with explicit zeros (column 0) in the block's last row. ``(0,
-    0)`` marks canonical, unpadded storage (``csr_from_dense``).
+    padded with explicit zeros in the block's last row, each at the column
+    of the block's last entry. ``(0, 0)`` marks canonical, unpadded storage
+    (``csr_from_dense``). A tiled stream also carries its window of x
+    (``kernels.csr.x_window``): ``x_start``, each tile's first row of 128 of
+    x, and ``x_walk``, the rows of x every tile walks from it; canonical
+    storage has none.
     """
 
     data: jax.Array  # (nnz,) stored values
@@ -67,6 +72,8 @@ class CSR:
     tiling: tuple[int, int] = dataclasses.field(
         default=(0, 0), metadata=dict(static=True)
     )
+    x_start: jax.Array | None = None  # (n_tiles,) int32
+    x_walk: int = dataclasses.field(default=0, metadata=dict(static=True))
 
     @property
     def nnz(self) -> int:
@@ -239,14 +246,18 @@ def _tiled(rows, cols, vals, shape, rpb: int, nt: int, dtype) -> CSR:
     pos = np.repeat(offsets[:-1] - starts[:-1], counts)
     pos += np.arange(rows.size)
     last_row = np.minimum((np.arange(n_blocks) + 1) * rpb, max(n_rows, 1)) - 1
+    # a padding slot takes the column of the last entry at or before its
+    # block's end, so it never widens its tile's window of x
+    last_col = cols[np.maximum(starts[1:] - 1, 0)] if rows.size else np.zeros(n_blocks)
     data = np.zeros(int(offsets[-1]), dtype=dtype)
-    indices = np.zeros(int(offsets[-1]), dtype=np.int32)
+    indices = np.repeat(last_col.astype(np.int32), stored)
     row_ids = np.repeat(last_row.astype(np.int32), stored)
     data[pos] = vals
     indices[pos] = cols
     row_ids[pos] = rows
     indptr = np.zeros(n_rows + 1, dtype=np.int32)
     np.cumsum(np.bincount(row_ids, minlength=n_rows)[:n_rows], out=indptr[1:])
+    x_start, x_walk = x_window(indices, nt, n_cols)
     return CSR(
         data=jax.numpy.asarray(data),
         indices=jax.numpy.asarray(indices),
@@ -254,6 +265,8 @@ def _tiled(rows, cols, vals, shape, rpb: int, nt: int, dtype) -> CSR:
         row_ids=jax.numpy.asarray(row_ids),
         shape=(n_rows, n_cols),
         tiling=(rpb, nt),
+        x_start=None if x_start is None else jax.numpy.asarray(x_start),
+        x_walk=x_walk,
     )
 
 
@@ -354,7 +367,7 @@ def _empty_dense(mat) -> np.ndarray:
 
 def csr_to_dense(mat: CSR) -> np.ndarray:
     out = _empty_dense(mat)
-    # add, not assign: tiled storage repeats (row, 0) for its explicit zeros
+    # add, not assign: tiled storage repeats (row, col) for its explicit zeros
     np.add.at(
         out, (np.asarray(mat.row_ids), np.asarray(mat.indices)), np.asarray(mat.data)
     )

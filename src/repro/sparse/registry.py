@@ -358,13 +358,14 @@ def _csr_spmv(mat: CSR, x, schedule: KernelSchedule):
     n_rows, _ = mat.shape
     # the stream's own tiling fixes the geometry; the schedule the numerics
     y = csr_spmv_pallas(
-        mat.data, mat.indices, mat.row_ids, x, n_rows, mat.tiling, schedule
+        mat.data, mat.indices, mat.row_ids, x, n_rows, mat.tiling, schedule,
+        x_start=mat.x_start, x_walk=mat.x_walk or None,
     )
     return y[:n_rows]
 
 
 def _csr_x_gather(mat: CSR, x_dtype) -> str:
-    return x_gather(mat.shape[1], x_dtype, mat.tiling[1])
+    return x_gather(mat.shape[1], x_dtype, mat.tiling[1], mat.x_walk or None)
 
 
 def _csr_footprint(stats: MatrixStats, schedule: KernelSchedule) -> KernelFootprint:

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.session import AutoSpmvSession
+from repro.kernels.ops import gather_attrs
 from repro.models import decode_step, prefill
 from repro.models.model import init_cache
 from repro.models.sparse_linear import SLO_PRIORITY, slo_objective
@@ -410,8 +411,7 @@ class SpmvServer:
         for req in group:
             with _span("server.request", rid=req.rid, objective=objective, mode="observed"):
                 plan = self.session.serve_optimize(req.dense, objective)
-                gather = getattr(plan.kernel, "gather", None)
-                with _span("kernel.execute", fmt=plan.fmt, gather=gather):
+                with _span("kernel.execute", fmt=plan.fmt, **gather_attrs(plan.kernel)):
                     t0 = time.perf_counter()
                     y = np.asarray(plan.kernel(jnp.asarray(req.x)))
                     dt = time.perf_counter() - t0
@@ -504,8 +504,8 @@ class SpmvServer:
                     "server.request", rid=req.rid, objective=objective, mode="batch"
                 ):
                     req.schedule = res.schedule
-                    gather = getattr(res.kernel, "gather", None)
-                    with _span("kernel.execute", fmt=default_format(), gather=gather):
+                    attrs = gather_attrs(res.kernel)
+                    with _span("kernel.execute", fmt=default_format(), **attrs):
                         t_exec = time.perf_counter()
                         req.y = np.asarray(res.kernel(jnp.asarray(req.x)))
                         exec_s = time.perf_counter() - t_exec

@@ -20,6 +20,7 @@ from repro.kernels.ops import (
     kernel_memo_stats,
     matrix_fingerprint,
     prepare,
+    spmv_pallas,
 )
 from repro.obs.trace import get_tracer
 from repro.solvers import IterativeSolver, cg, power_iteration
@@ -144,6 +145,66 @@ def test_tiled_stream_from_host_equals_the_dense_one(rpb, nt):
     for field in ("data", "indices", "indptr", "row_ids"):
         assert np.array_equal(np.asarray(getattr(a, field)), np.asarray(getattr(b, field)))
     assert tiled_stored(_host(dense).row_counts(), rpb, nt) == a.data.shape[0]
+
+
+def _wide_banded(seed: int) -> np.ndarray:
+    """40 rows over 30,000 columns, each row's entries in its own band of
+    600 columns, with rows 16-23 empty: a stencil-like x of 235 rows of 128
+    that no tile spans."""
+    rng = np.random.default_rng(seed)
+    dense = np.zeros((40, 30_000), np.float32)
+    for r in (*range(16), *range(24, 40)):
+        cols = r * 740 + rng.choice(600, size=30, replace=False)
+        dense[r, cols] = rng.normal(size=30)
+    return dense
+
+
+def _tile_windows(dense: np.ndarray, rpb: int, nt: int):
+    """Brute force: each tile's (first, last) row of 128 of x over its real
+    entries, None for a tile of padding only, laid out block by block."""
+    out = []
+    for b in range(-(-dense.shape[0] // rpb)):
+        rows, cols = np.nonzero(dense[b * rpb : (b + 1) * rpb])
+        chunks = [cols[i : i + nt] >> 7 for i in range(0, cols.size, nt)] or [None]
+        out += [None if c is None else (c.min(), c.max()) for c in chunks]
+    return out
+
+
+@pytest.mark.parametrize("source", ["dense", "host"])
+@pytest.mark.parametrize("rpb, nt", [(8, 128), (8, 256), (16, 1024)])
+def test_the_tiled_stream_carries_each_tiles_window_of_x(source, rpb, nt):
+    """The window ``_tiled`` computes is the brute-force per-tile min over
+    the real entries, aligned down to 8 and pulled back to end inside x;
+    the padding columns widen no window and change no value, row or y."""
+    from repro.kernels.csr import WALK_ROWS, x_rows
+    from repro.sparse.formats import csr_to_dense
+
+    dense = _wide_banded(rpb + nt)
+    mat = csr_tiled(dense, rpb, nt) if source == "dense" else csr_tiled_from_host(
+        _host(dense), rpb, nt)
+    windows = _tile_windows(dense, rpb, nt)
+    real = [w for w in windows if w is not None]
+    widest = max(last - (first - first % 8) + 1 for first, last in real)
+    rows = x_rows(dense.shape[1])
+    assert mat.x_walk == -(-widest // WALK_ROWS) * WALK_ROWS < rows
+    starts = np.asarray(mat.x_start)
+    assert starts.shape == (len(windows),)
+    for start, w in zip(starts, windows):
+        if w is not None:
+            assert start == min(w[0] - w[0] % 8, rows - mat.x_walk)
+            assert start <= w[0] and w[1] < start + mat.x_walk
+    # only padding columns moved: every real entry, row and y are as before
+    stream, cols = np.asarray(mat.data), np.asarray(mat.indices)
+    real_rows, real_cols = np.nonzero(dense)
+    assert stream.size == tiled_stored((dense != 0).sum(axis=1), rpb, nt)
+    assert np.array_equal(stream[stream != 0], dense[real_rows, real_cols])
+    assert np.array_equal(cols[stream != 0], real_cols)
+    assert np.array_equal(np.asarray(mat.row_ids)[stream != 0], real_rows)
+    assert np.array_equal(csr_to_dense(mat), dense)
+    sched = KernelSchedule(rows_per_block=rpb, nnz_tile=nt)
+    x = np.random.default_rng(nt).normal(size=dense.shape[1]).astype(np.float32)
+    y = np.asarray(spmv_pallas(mat, x, sched))
+    np.testing.assert_allclose(y, dense.astype(np.float64) @ x, rtol=1e-6, atol=1e-6)
 
 
 def test_a_stored_zero_of_the_host_csr_is_kept():

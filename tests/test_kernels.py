@@ -255,3 +255,129 @@ def test_csr_keeps_the_xla_gather_where_the_kernel_cannot_gather(case):
     y = np.asarray(spmv_pallas(mat, x, sched), np.float32)
     ref = dense @ np.asarray(x, np.float32)
     np.testing.assert_allclose(y, ref, rtol=2e-2, atol=2e-2)
+
+
+# ------------------------------------------------- CSR's window of x (x_window)
+def _stencil_slab(rows: slice):
+    """Rows of HPCG's 27-point operator on a 16 x 16 x 48 grid: 12,288
+    columns (96 rows of 128, walked as 128), of which a tile reaches only
+    +-273 around its own rows."""
+    from repro.sparse.generate import hpcg_stencil
+    from repro.sparse.host import HostCSR
+
+    full = hpcg_stencil(16, 16, 48)
+    lo, hi = full.indptr[rows.start], full.indptr[rows.stop]
+    return HostCSR(full.indptr[rows.start : rows.stop + 1] - lo, full.indices[lo:hi],
+                   full.data[lo:hi], (rows.stop - rows.start, full.shape[1]))
+
+
+@pytest.mark.parametrize("rpb,nt", [(8, 128), (64, 1024), (512, 1024)])
+def test_csr_gathers_a_banded_x_from_each_tiles_window(rpb, nt):
+    """A tile of the stencil walks one step of x from its own start: y is
+    the full walk's, bit for bit, and within 1e-6 of float64."""
+    import jax
+
+    from repro.kernels.csr import WALK_ROWS, csr_spmv_pallas, x_rows
+    from repro.kernels.ops import PreparedSpmv
+
+    host = _stencil_slab(slice(8000, 8800))  # columns 7,727-9,072: rows 60-70
+    n_rows, n_cols = host.shape
+    sched = KernelSchedule(rows_per_block=rpb, nnz_tile=nt)
+    mat = prepare(host, "csr", sched)
+    assert mat.x_walk == WALK_ROWS < x_rows(n_cols)
+    starts = np.asarray(mat.x_start)
+    assert starts.min() > 0 and starts.max() >= WALK_ROWS and (starts % 8 == 0).all()
+    assert PreparedSpmv(mat, sched).gather == "vmem"
+    assert PreparedSpmv(mat, sched).x_rows_walked == WALK_ROWS
+    x = np.random.default_rng(rpb).normal(size=n_cols).astype(np.float32)
+    y = np.asarray(spmv_pallas(mat, x, sched))
+    full_walk = jax.jit(
+        lambda x: csr_spmv_pallas(
+            mat.data, mat.indices, mat.row_ids, x, n_rows, mat.tiling, sched, gather="vmem"
+        )
+    )
+    np.testing.assert_array_equal(y, np.asarray(full_walk(x))[:n_rows])
+    ref = np.zeros(n_rows)
+    np.add.at(ref, host.row_ids(), host.data.astype(np.float64) * x[host.indices])
+    np.testing.assert_allclose(y, ref, rtol=0, atol=1e-6 * np.abs(ref).max())
+
+
+def _tail_case(n_cols=20_000):
+    """Two 8-row blocks: the first near column 0, the second holding one
+    entry, in x's last column."""
+    dense = np.zeros((16, n_cols), np.float32)
+    dense[np.arange(8), np.arange(8) * 40] = np.arange(1, 9)
+    dense[11, n_cols - 1] = 2.5
+    return dense
+
+
+def test_csr_padding_keeps_a_tiles_window_at_its_only_entry():
+    """The second block's tile is one entry and 127 slots of padding; the
+    padding sits at that entry's column, so the window starts near the end
+    of x, not at row 0."""
+    from repro.kernels.csr import WALK_ROWS, x_rows
+
+    n_cols = 20_000
+    sched = KernelSchedule(rows_per_block=8, nnz_tile=128)
+    mat = prepare(_tail_case(n_cols), "csr", sched)
+    tiles = np.asarray(mat.indices).reshape(-1, 128)
+    assert (tiles[1] == n_cols - 1).all()
+    last_row = (n_cols - 1) // 128  # 156
+    assert mat.x_walk == WALK_ROWS
+    assert np.asarray(mat.x_start).tolist() == [0, x_rows(n_cols) - WALK_ROWS]
+    assert np.asarray(mat.x_start)[1] > last_row - WALK_ROWS
+
+
+def test_csr_window_may_end_at_xs_last_row():
+    """A start is pulled back so that the walk ends at x's last padded row,
+    and the entry in x's last column is still read."""
+    from repro.kernels.csr import x_rows
+
+    n_cols = 20_000
+    dense = _tail_case(n_cols)
+    sched = KernelSchedule(rows_per_block=8, nnz_tile=128)
+    mat = prepare(dense, "csr", sched)
+    assert int(np.asarray(mat.x_start)[-1]) + mat.x_walk == x_rows(n_cols)
+    x = np.random.default_rng(3).normal(size=n_cols).astype(np.float32)
+    y = np.asarray(spmv_pallas(mat, x, sched))
+    np.testing.assert_allclose(y, dense.astype(np.float64) @ x, rtol=1e-6, atol=1e-6)
+    assert y[11] == np.float32(2.5) * x[-1]
+
+
+@pytest.mark.parametrize("n", [300, 9_000])
+def test_csr_random_matrix_walks_all_of_x(n):
+    """Uniform columns span all of x in every tile: no window, and the walk
+    takes x_rows / WALK_ROWS steps, as before windows existed."""
+    from repro.kernels.csr import WALK_ROWS, x_rows
+    from repro.kernels.ops import PreparedSpmv
+
+    dense = np.zeros((16, n), np.float32)
+    rng = np.random.default_rng(n)
+    cols = rng.integers(0, n, size=(16, 60))
+    dense[np.arange(16)[:, None], cols] = rng.normal(size=cols.shape)
+    dense[0, 0] = dense[1, n - 1] = 1.0  # the first block spans x's ends
+    sched = KernelSchedule(rows_per_block=8, nnz_tile=128)
+    mat = prepare(dense, "csr", sched)
+    assert mat.x_start is None and mat.x_walk == x_rows(n)
+    assert mat.x_walk // WALK_ROWS == x_rows(n) / WALK_ROWS
+    assert PreparedSpmv(mat, sched).x_rows_walked == x_rows(n)
+
+
+def test_csr_windowed_stream_keeps_the_xla_gather_for_bf16_x():
+    import jax.numpy as jnp
+
+    from repro.kernels.common import GATHER_SCOPE
+    from repro.kernels.csr import x_gather
+    from repro.kernels.ops import _jitted_spmv
+    from repro.sparse.registry import spec_for
+
+    dense = _tail_case()
+    sched = KernelSchedule(rows_per_block=8, nnz_tile=128)
+    mat = prepare(dense, "csr", sched)
+    assert mat.x_start is not None
+    assert x_gather(dense.shape[1], jnp.bfloat16, 128, mat.x_walk) == "xla"
+    x = jnp.asarray(np.random.default_rng(5).normal(size=dense.shape[1]), jnp.bfloat16)
+    text = _jitted_spmv.lower(spec_for(mat).spmv, mat, x, sched).as_text(debug_info=True)
+    assert f"{GATHER_SCOPE}/" in text
+    y = np.asarray(spmv_pallas(mat, x, sched), np.float32)
+    np.testing.assert_allclose(y, dense @ np.asarray(x, np.float32), rtol=2e-2, atol=2e-2)

@@ -221,14 +221,18 @@ class _CsrPredictor(_FakePredictor):
 
 
 def test_power_solve_on_a_csr_plan_gathers_x_in_the_kernel(web):
-    """Every ``kernel.execute`` of a CSR solve says where x was gathered."""
+    """Every ``kernel.execute`` of a CSR solve says where x was gathered and
+    how many rows of it a tile walked: all of a small x, in whole steps."""
+    from repro.kernels.csr import x_rows
+
     session = AutoSpmvSession(AutoSpMV(_CsrPredictor(), _FakeOverhead()))
     tracer = get_tracer()
     tracer.clear()
     res = power_iteration(session, web, tol=0.0, max_iters=3)
     assert res.iterations == 3
     execute = [s for s in tracer.spans() if s["name"] == "kernel.execute"]
-    assert [s["attrs"] for s in execute] == [{"fmt": "csr", "gather": "vmem"}] * 3
+    attrs = {"fmt": "csr", "gather": "vmem", "x_rows_walked": x_rows(web.shape[1])}
+    assert [s["attrs"] for s in execute] == [attrs] * 3
 
 
 def test_thread_usage_rides_on_the_five_counted_spans_only(session, web):

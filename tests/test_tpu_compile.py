@@ -27,7 +27,7 @@ from repro.kernels.common import (
     ceil_to,
     default_interpret,
 )
-from repro.kernels.csr import csr_spmv_pallas
+from repro.kernels.csr import PREFETCH_SMEM_BYTES, WALK_ROWS, csr_spmv_pallas
 from repro.kernels.ell import ell_spmm_pallas, ell_spmv_pallas
 from repro.kernels.fused import fused_spmv_pallas
 from repro.kernels.sell import sell_spmv_pallas
@@ -172,20 +172,31 @@ def test_csr_gather_of_x_compiles_at_the_cells_widths(one_chip, n, nt, gather):
     assert (GATHER_SCOPE in text) == (gather == "xla")
 
 
+@pytest.mark.parametrize("window", [True, False], ids=["window", "no-window"])
 @pytest.mark.parametrize("rpb,nt,stored", [(512, 1024, 31_070_208), (128, 128, 29_998_336)])
-def test_csr_compiles_at_the_hpcg_cells_shape(one_chip, rpb, nt, stored):
+def test_csr_compiles_at_the_hpcg_cells_shape(one_chip, rpb, nt, stored, window):
     """HPCG's 104^3 stencil (1,124,864 rows) at two storable tilings and
-    their stream lengths: x is past the VMEM bound, so XLA gathers it."""
+    their stream lengths. x is past the bound of a walk over all of it, so
+    without a window XLA gathers it. The stream's window (a tile spans at
+    most 173 rows of 128: 3 steps) brings it into the kernel where its
+    starts fit SMEM beside the tile map: 30,342 tiles of 1,024, not 234,362
+    of 128, which ``x_window`` gives no window."""
     n = 104**3
+    n_tiles = stored // nt
+    windowed = window and 2 * n_tiles * 4 <= PREFETCH_SMEM_BYTES
     sched = KernelSchedule(rows_per_block=rpb, nnz_tile=nt, unroll=8)
     text = _compile(
-        lambda d, c, r, x: csr_spmv_pallas(d, c, r, x, n, (rpb, nt), sched, interpret=False),
+        lambda d, c, r, x, s: csr_spmv_pallas(
+            d, c, r, x, n, (rpb, nt), sched, interpret=False,
+            **(dict(x_start=s, x_walk=3 * WALK_ROWS) if windowed else {}),
+        ),
         _sds(one_chip, (stored,)),
         _sds(one_chip, (stored,), jnp.int32),
         _sds(one_chip, (stored,), jnp.int32),
         _sds(one_chip, (n,)),
+        _sds(one_chip, (n_tiles,), jnp.int32),
     ).as_text()
-    assert GATHER_SCOPE in text
+    assert (GATHER_SCOPE in text) == (not windowed)
 
 
 @pytest.fixture()
