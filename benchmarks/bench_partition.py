@@ -9,15 +9,10 @@ margin, the measured (interpret-mode wall time) margin, per-block routing,
 and a multi-device ``shard_map`` correctness pass on however many devices
 the host exposes.
 
-Two PR6 studies ride along:
-
-- fused single-launch executor: the same composite plan lowered into ONE
-  Pallas launch (merge-path work descriptor) must measure no slower than
-  the sequential per-block dispatch AND the best monolithic kernel — the
-  per-launch fixed cost it removes is real, not modeled.
-- calibration: per-block (predicted, measured) pairs from ``timed_call``
-  feed ``CalibratedCostModel.fit``; its mean relative error against the
-  same measurements must be at most half the uncalibrated model's.
+A calibration study rides along: per-block (predicted, measured) pairs
+from ``timed_call`` feed ``CalibratedCostModel.fit``; its mean relative
+error against the same measurements must be at most half the uncalibrated
+model's.
 """
 
 from __future__ import annotations
@@ -30,12 +25,7 @@ from benchmarks.common import print_table, save_result
 from repro.core.objectives import CalibratedCostModel
 from repro.core.session import build_tuner
 from repro.kernels.ops import compile_spmv
-from repro.partition import (
-    compile_fused_partitioned,
-    compile_partitioned,
-    partition_rows,
-    shard_partitioned,
-)
+from repro.partition import compile_partitioned, partition_rows, shard_partitioned
 from repro.telemetry import TelemetryRecorder
 from repro.sparse.generate import MATRIX_NAMES, random_matrix
 from repro.utils.logging import get_logger
@@ -115,26 +105,6 @@ def run(scale: str = "ci") -> dict:
         rel_err_monolithic=err_mono,
     )
 
-    # --- fused single-launch executor vs sequential dispatch --------------
-    fused_kernel = compile_fused_partitioned(het, plan)
-    t_fused, y_fused = _measure(fused_kernel, x, reps)
-    err_fused = float(np.abs(y_fused - ref).max() / norm)
-    assert err_fused < 2e-2, f"fused output diverged: {err_fused}"
-    out["hetero"].update(
-        measured_fused_s=t_fused,
-        rel_err_fused=err_fused,
-        fused_n_tiles=fused_kernel.n_tiles,
-        fused_tile=fused_kernel.kernel.tile,
-    )
-    assert t_fused <= t_part, (
-        f"fused single launch ({t_fused*1e3:.2f} ms) slower than sequential "
-        f"per-block dispatch ({t_part*1e3:.2f} ms)"
-    )
-    assert t_fused <= t_mono, (
-        f"fused single launch ({t_fused*1e3:.2f} ms) slower than the best "
-        f"monolithic kernel ({t_mono*1e3:.2f} ms)"
-    )
-
     # --- calibration: measured block times halve the model's error --------
     recorder = TelemetryRecorder()
     for _ in range(max(reps, 3)):
@@ -209,10 +179,8 @@ def run(scale: str = "ci") -> dict:
         ],
     )
     log.info(
-        "hetero: measured %.2f ms fused vs %.2f ms sequential partitioned vs "
-        "%.2f ms monolithic (interpret mode); sharded over %d device(s), rel "
-        "err %.2e",
-        t_fused * 1e3,
+        "hetero: measured %.2f ms partitioned vs %.2f ms monolithic "
+        "(interpret mode); sharded over %d device(s), rel err %.2e",
         t_part * 1e3,
         t_mono * 1e3,
         n_dev,

@@ -13,9 +13,9 @@ The acceptance study for ``repro.solvers``:
   SpMSpV path, and modeled work is deterministic where wall time is not.
 
 Reported metrics include end-to-end solve latency, per-iteration p50, and
-the adaptive/always per-iteration latency ratio — the second gated metric
-in ``benchmarks/compare.py`` (both sides measured in the same process, so
-the ratio cancels runner speed exactly like the fused/sequential gate).
+the adaptive/always per-iteration latency ratio — a gated metric in
+``benchmarks/compare.py`` (both sides measured in the same process, so the
+ratio cancels runner speed).
 """
 
 from __future__ import annotations

@@ -6,8 +6,6 @@ gate compares a *within-run latency ratio*: both sides of each ratio come
 from the same process on the same machine, so runner speed cancels. Gated
 ratios (lower = better):
 
-* ``fused_vs_sequential`` — the partition bench's single-launch fused
-  executor over its sequential per-block dispatch;
 * ``solver_adaptive_vs_always`` — the solvers bench's per-iteration p50
   with the adaptive SpMV↔SpMSpV policy over the always-SpMV run;
 * ``lm_sparse_per_token`` — sparse-served decode over dense decode;
@@ -20,10 +18,10 @@ the final summary counts the failures by name.
 
 A gate fails when its current ratio is more than ``--threshold`` (default
 25%) worse than the baseline ratio AND the ratio has left the gate's
-absolute comfort zone (``max_ok_ratio`` — e.g. the fused executor is no
-longer 10× faster, or the adaptive solver is more than 25% slower per
-iteration than always-SpMV). The absolute guard keeps ratio jitter that is
-still well inside the win from failing CI.
+absolute comfort zone (``max_ok_ratio`` — e.g. the adaptive solver is
+more than 25% slower per iteration than always-SpMV). The absolute guard
+keeps ratio jitter that is still well inside the comfort zone from failing
+CI.
 
 A baseline that lacks a metric a gate references is itself a failure with
 an explicit message naming the bench and metric — a silently skipped gate
@@ -50,11 +48,6 @@ log = get_logger("bench.compare")
 
 DEFAULT_BASELINE = Path(__file__).parent / "baseline" / "BENCH_smoke.json"
 
-# kept as module constants: external tooling greps these key names
-FUSED_KEY = "hetero/measured_fused_s"
-SEQUENTIAL_KEY = "hetero/measured_partitioned_s"
-
-
 @dataclass(frozen=True)
 class RatioGate:
     """One gated within-run latency ratio (numerator / denominator)."""
@@ -70,14 +63,6 @@ class RatioGate:
 
 
 GATES = (
-    RatioGate(
-        name="fused_vs_sequential",
-        bench="partition",
-        num_key=FUSED_KEY,
-        den_key=SEQUENTIAL_KEY,
-        # historic min_margin=10x: jitter inside a 10x win never fails
-        max_ok_ratio=0.1,
-    ),
     RatioGate(
         name="solver_adaptive_vs_always",
         bench="solvers",
@@ -140,11 +125,6 @@ def gate_ratio(report: dict, gate: RatioGate) -> tuple[float | None, str | None]
             f"non-positive ({num:g}/{den:g})"
         )
     return num / den, None
-
-
-def fused_ratio(report: dict) -> float | None:
-    """fused / sequential latency of the partition bench (lower = better)."""
-    return gate_ratio(report, GATES[0])[0]
 
 
 @dataclass(frozen=True)

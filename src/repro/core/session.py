@@ -577,7 +577,6 @@ class AutoSpmvSession:
         objective: str = "latency",
         *,
         max_blocks: int = 8,
-        fused: bool = False,
         fingerprint: str | None = None,
     ) -> PartitionedResult:
         """Partitioned run-time mode through the plan cache.
@@ -590,20 +589,12 @@ class AutoSpmvSession:
 
         Planning uses the session's ``cost_model`` when one is set (a
         ``CalibratedCostModel`` after ``calibrate``), so block-count search
-        charges the measured per-launch fixed cost. With ``fused=True`` the
-        composite lowers to ONE Pallas launch (``compile_fused_partitioned``,
-        one memo entry keyed on the whole plan) instead of per-block kernels
-        — the fast serving path; per-block timing needs ``fused=False``."""
-        from repro.partition.executor import (
-            compile_fused_partitioned,
-            compile_partitioned,
-        )
+        charges the measured per-launch fixed cost."""
+        from repro.partition.executor import compile_partitioned
         from repro.partition.partitioner import SUPPORTED_BLOCK_COUNTS
 
         self.stats.requests += 1
-        with _span(
-            "session.optimize", mode="partitioned", objective=objective, fused=fused
-        ) as sp:
+        with _span("session.optimize", mode="partitioned", objective=objective) as sp:
             fp, feats, bucket = self._analyze(dense, fingerprint)
             mode = _part_mode_key(max_blocks)
             with _span("cache.lookup", bucket=bucket, mode=mode):
@@ -648,14 +639,7 @@ class AutoSpmvSession:
             else:
                 self.stats.cache_hits += 1
             before = kernel_memo_stats()["compiles"]
-            if fused:
-                kernel = compile_fused_partitioned(
-                    dense, plan, memo_key=fp
-                )
-            else:
-                kernel = compile_partitioned(
-                    dense, plan, memo_key=fp
-                )
+            kernel = compile_partitioned(dense, plan, memo_key=fp)
             self.stats.kernel_compiles += kernel_memo_stats()["compiles"] - before
         return PartitionedResult(
             fingerprint=fp,

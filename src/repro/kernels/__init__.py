@@ -11,9 +11,9 @@ from repro.kernels.common import (
     UNROLL_CHOICES,
     ACCUM_DTYPE_CHOICES,
     X_RESIDENCY_CHOICES,
+    InfeasibleConfig,
 )
 from repro.kernels.ops import (
-    InfeasibleConfig,
     PreparedSpmspv,
     PreparedSpmv,
     clear_kernel_memo,

@@ -132,27 +132,6 @@ class KernelSchedule:
 
 DEFAULT_SCHEDULE = KernelSchedule()
 
-# Fused partitioned executor (kernels/fused.py): target grid length for the
-# single-launch composite stream. The fused tile is derived from the total
-# work so the one launch never degenerates into hundreds of tiny grid steps
-# (the per-step overhead would hand the win straight back to the per-block
-# launches it replaces).
-MAX_FUSED_STEPS = 8
-
-
-def fused_nnz_tile(total_elems: int, *, max_steps: int = MAX_FUSED_STEPS) -> int:
-    """Lane-aligned flat tile for the fused composite nonzero stream.
-
-    Sized so the whole stream fits in at most ``max_steps`` sequential grid
-    steps, capped so one tile's three operand planes (values + columns +
-    row ids, 4 B each) stay well inside the VMEM budget — a stream too large
-    for the cap simply takes more grid steps.
-    """
-    tile = ceil_to(max(1, -(-int(total_elems) // max_steps)), LANE)
-    cap = max(LANE, (VMEM_BYTES // 8 // 12) // LANE * LANE)
-    return min(tile, cap)
-
-
 def ceil_to(x: int, q: int) -> int:
     return ((x + q - 1) // q) * q
 

@@ -28,11 +28,7 @@ from typing import Any, Hashable
 import jax
 import numpy as np
 
-from repro.kernels.common import (
-    DEFAULT_SCHEDULE,
-    InfeasibleConfig,  # noqa: F401  (canonical home moved to kernels.common)
-    KernelSchedule,
-)
+from repro.kernels.common import DEFAULT_SCHEDULE, InfeasibleConfig, KernelSchedule
 from repro.obs.metrics import get_metrics
 from repro.obs.trace import span as _span
 
@@ -41,16 +37,6 @@ from repro.obs.trace import span as _span
 _M_HITS = get_metrics().counter("spmv_kernel_memo_hits_total")
 _M_COMPILES = get_metrics().counter("spmv_kernel_memo_compiles_total")
 _M_EVICTIONS = get_metrics().counter("spmv_kernel_memo_evictions_total")
-
-
-def __getattr__(name):
-    if name == "MAX_STORAGE_BYTES":
-        # deprecated alias: the live bound moved to the format registry;
-        # resolve it there so the two names can never drift apart
-        from repro.sparse.registry import MAX_STORAGE_BYTES
-
-        return MAX_STORAGE_BYTES
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def prepare(
@@ -185,9 +171,8 @@ def matrix_fingerprint(matrix) -> str:
 # schedule). Opt-in via ``compile_spmv(..., memo_key=...)`` so
 # one-off callers don't pin large format storage. Bounded: each entry holds
 # the full converted matrix storage, so an unbounded memo on a serving path
-# streaming distinct matrices would grow RSS until OOM. Fused composite
-# kernels share the memo with a "fused:<fmt>+<fmt>..." format tag and the
-# composite-plan signature in the schedule slot (one entry per plan).
+# streaming distinct matrices would grow RSS until OOM. Every entry goes in
+# through ``_memo_insert`` and comes back out through ``_memo_hit``.
 _KERNEL_MEMO: "OrderedDict[tuple, object]" = OrderedDict()
 _MEMO_STATS = {"hits": 0, "compiles": 0, "evictions": 0}
 _MEMO_LIMIT = 256
@@ -212,10 +197,7 @@ def set_kernel_memo_limit(limit: int) -> None:
     if limit < 1:
         raise ValueError("kernel memo limit must be >= 1")
     _MEMO_LIMIT = limit
-    while len(_KERNEL_MEMO) > _MEMO_LIMIT:
-        _KERNEL_MEMO.popitem(last=False)
-        _MEMO_STATS["evictions"] += 1
-        _M_EVICTIONS.inc()
+    _memo_evict_to_limit()
 
 
 def kernel_memoized(
@@ -232,13 +214,39 @@ def clear_kernel_memo() -> None:
     _KERNEL_MEMO.clear()
 
 
-def _fused_tag_contains(tag, fmt: str) -> bool:
-    """Whether a fused memo tag ("fused:ell+csr+...") involves ``fmt``."""
-    return (
-        isinstance(tag, str)
-        and tag.startswith(_FUSED_TAG_PREFIX)
-        and fmt in tag[len(_FUSED_TAG_PREFIX) :].split("+")
-    )
+def _memo_evict(key) -> None:
+    del _KERNEL_MEMO[key]
+    _MEMO_STATS["evictions"] += 1
+    _M_EVICTIONS.inc()
+
+
+def _memo_evict_to_limit() -> None:
+    """Drop least-recently-used entries until the memo fits its bound."""
+    while len(_KERNEL_MEMO) > _MEMO_LIMIT:
+        _memo_evict(next(iter(_KERNEL_MEMO)))
+
+
+def _memo_hit(key):
+    """The memoized kernel under ``key`` (counted and marked most recently
+    used), or None on a miss."""
+    hit = _KERNEL_MEMO.get(key)
+    if hit is not None:
+        _MEMO_STATS["hits"] += 1
+        _M_HITS.inc()
+        _KERNEL_MEMO.move_to_end(key)
+    return hit
+
+
+def _memo_insert(key, kernel):
+    """Store a freshly compiled ``kernel`` under ``key`` and evict to the
+    LRU bound. Counters cover memoized traffic only, so
+    hits/(hits+compiles) is a true memo hit rate (plain one-off compiles
+    don't skew it)."""
+    _MEMO_STATS["compiles"] += 1
+    _M_COMPILES.inc()
+    _KERNEL_MEMO[key] = kernel
+    _memo_evict_to_limit()
+    return kernel
 
 
 def evict_kernel_memo_format(fmt: str) -> int:
@@ -247,16 +255,10 @@ def evict_kernel_memo_format(fmt: str) -> int:
     Called by the registry when a format is unregistered or re-registered:
     a memoized ``PreparedSpmv`` must not outlive the ``FormatSpec`` that
     built it (its container would no longer resolve in ``spec_for``, or
-    would silently run the old implementation). Fused composite kernels are
-    evicted when ANY of their block formats matches — their flattened
-    streams were lowered through the retiring ``FormatSpec``."""
-    stale = [
-        k for k in _KERNEL_MEMO if k[1] == fmt or _fused_tag_contains(k[1], fmt)
-    ]
+    would silently run the old implementation)."""
+    stale = [k for k in _KERNEL_MEMO if k[1] == fmt]
     for k in stale:
-        del _KERNEL_MEMO[k]
-        _MEMO_STATS["evictions"] += 1
-        _M_EVICTIONS.inc()
+        _memo_evict(k)
     return len(stale)
 
 
@@ -274,27 +276,12 @@ def compile_spmv(
     same (matrix, format, schedule) return the existing ``PreparedSpmv``
     without re-running conversion — the ``c`` term of the §5.3 overhead
     model is paid once per unique matrix (until LRU eviction)."""
-    if memo_key is not None:
-        key = (memo_key, fmt, schedule)
-        hit = _KERNEL_MEMO.get(key)
-        if hit is not None:
-            _MEMO_STATS["hits"] += 1
-            _M_HITS.inc()
-            _KERNEL_MEMO.move_to_end(key)
-            return hit
+    key = (memo_key, fmt, schedule)
+    if memo_key is not None and (hit := _memo_hit(key)) is not None:
+        return hit
     with _span("kernel.compile", fmt=fmt):
         prepared = PreparedSpmv(prepare(dense, fmt, schedule), schedule)
-    if memo_key is not None:
-        # counters cover memoized traffic only, so hits/(hits+compiles) is a
-        # true memo hit rate (plain one-off compiles don't skew it)
-        _MEMO_STATS["compiles"] += 1
-        _M_COMPILES.inc()
-        _KERNEL_MEMO[key] = prepared
-        while len(_KERNEL_MEMO) > _MEMO_LIMIT:
-            _KERNEL_MEMO.popitem(last=False)
-            _MEMO_STATS["evictions"] += 1
-            _M_EVICTIONS.inc()
-    return prepared
+    return prepared if memo_key is None else _memo_insert(key, prepared)
 
 
 def compile_spmv_block(
@@ -317,55 +304,6 @@ def compile_spmv_block(
     block = np.asarray(dense)[row_start:row_end]
     key = (memo_key, row_start, row_end) if memo_key is not None else None
     return compile_spmv(block, fmt, schedule, memo_key=key)
-
-
-_FUSED_TAG_PREFIX = "fused:"
-
-
-def fused_plan_signature(plan) -> tuple:
-    """Hashable identity of a ``CompositePlan``'s executable content.
-
-    Two plans lower to the same fused stream iff their (row range, format,
-    schedule) tuples agree per block — the memo key component that makes
-    "one kernel memo entry keyed on the composite plan" precise."""
-    return tuple(
-        (bp.block.row_start, bp.block.row_end, bp.fmt, bp.schedule)
-        for bp in plan.blocks
-    )
-
-
-def compile_spmv_fused(
-    dense: np.ndarray, plan, *, memo_key: Hashable | None = None
-):
-    """Lower a ``CompositePlan`` to its single-launch fused kernel.
-
-    The whole composite memoizes as ONE entry: the format slot carries a
-    ``fused:<fmt>+<fmt>...`` tag (so ``evict_kernel_memo_format`` retires it
-    with any constituent format) and the schedule slot carries the plan
-    signature. Returns a ``repro.kernels.fused.FusedSpmv``."""
-    from repro.kernels.fused import lower_fused
-
-    key = None
-    if memo_key is not None:
-        tag = _FUSED_TAG_PREFIX + "+".join(bp.fmt for bp in plan.blocks)
-        key = (memo_key, tag, fused_plan_signature(plan))
-        hit = _KERNEL_MEMO.get(key)
-        if hit is not None:
-            _MEMO_STATS["hits"] += 1
-            _M_HITS.inc()
-            _KERNEL_MEMO.move_to_end(key)
-            return hit
-    with _span("kernel.compile", fused=True, formats="+".join(bp.fmt for bp in plan.blocks)):
-        kernel = lower_fused(dense, plan)
-    if key is not None:
-        _MEMO_STATS["compiles"] += 1
-        _M_COMPILES.inc()
-        _KERNEL_MEMO[key] = kernel
-        while len(_KERNEL_MEMO) > _MEMO_LIMIT:
-            _KERNEL_MEMO.popitem(last=False)
-            _MEMO_STATS["evictions"] += 1
-            _M_EVICTIONS.inc()
-    return kernel
 
 
 _SPMSPV_TAG = "spmspv"
@@ -416,24 +354,11 @@ def compile_spmspv(
     from repro.kernels.spmspv import col_nnz as _col_nnz
     from repro.kernels.spmspv import csc_from_dense
 
-    if memo_key is not None:
-        key = (memo_key, _SPMSPV_TAG, schedule)
-        hit = _KERNEL_MEMO.get(key)
-        if hit is not None:
-            _MEMO_STATS["hits"] += 1
-            _M_HITS.inc()
-            _KERNEL_MEMO.move_to_end(key)
-            return hit
+    key = (memo_key, _SPMSPV_TAG, schedule)
+    if memo_key is not None and (hit := _memo_hit(key)) is not None:
+        return hit
     with _span("kernel.compile", fmt=_SPMSPV_TAG):
         prepared = PreparedSpmspv(
             csc_from_dense(dense, schedule), schedule, _col_nnz(dense)
         )
-    if memo_key is not None:
-        _MEMO_STATS["compiles"] += 1
-        _M_COMPILES.inc()
-        _KERNEL_MEMO[key] = prepared
-        while len(_KERNEL_MEMO) > _MEMO_LIMIT:
-            _KERNEL_MEMO.popitem(last=False)
-            _MEMO_STATS["evictions"] += 1
-            _M_EVICTIONS.inc()
-    return prepared
+    return prepared if memo_key is None else _memo_insert(key, prepared)

@@ -229,7 +229,6 @@ def serve_spmv(args) -> list[SpmvRequest]:
         feedback=feedback,
         partition=args.partition,
         max_blocks=args.max_blocks,
-        fused=args.fused,
         calibrate_every=args.calibrate_every,
         slo=slo_tracker,
         anomaly=args.anomaly,
@@ -240,9 +239,8 @@ def serve_spmv(args) -> list[SpmvRequest]:
     if args.partition:
         log.info(
             "partitioned serving: composite plans up to %d nnz-balanced row "
-            "blocks per matrix (monolithic fallback when partitioning loses)%s",
+            "blocks per matrix (monolithic fallback when partitioning loses)",
             args.max_blocks,
-            ", fused single-launch executor" if args.fused else "",
         )
 
     # synthetic traffic: suite matrices with repeats (fleet-like resubmission)
@@ -353,11 +351,6 @@ def main(argv=None):
     ap.add_argument("--max-blocks", type=int, default=8,
                     help="block-count budget for --partition (searched over "
                          "{1, 2, 4, 8} up to this bound; 1 = monolithic)")
-    ap.add_argument("--fused", action="store_true",
-                    help="with --partition: run the composite plan as ONE "
-                         "Pallas launch (merge-path work descriptor) instead "
-                         "of per-block kernels; disables per-block bandit "
-                         "timing")
     ap.add_argument("--calibrate-every", type=int, default=0,
                     help="refit the CalibratedCostModel from telemetry every "
                          "N served requests (0=off; needs --telemetry); the "

@@ -6,8 +6,7 @@ nnz-balanced blocks (each with its own Table-2 feature vector), ``plan``
 routes every block through the format registry + predictors + cost model
 and searches block counts {1, 2, 4, 8} with a monolithic fallback, and
 ``executor`` runs the winning composite plan — heterogeneous per-block
-Pallas kernels on one device, every block fused into ONE Pallas launch
-(``compile_fused_partitioned``), or one block per device over a mesh
+Pallas kernels on one device, or one block per device over a mesh
 ``data`` axis via ``shard_map`` (X gathered, Y shards local).
 
 Session/cache/serving integration lives in ``repro.core.session``
@@ -17,10 +16,8 @@ and ``repro.train.serve`` / ``repro.launch.serve`` (``--partition``).
 
 from repro.partition.executor import (
     BlockKernel,
-    FusedPartitionedSpmv,
     PartitionedSpmv,
     ShardedPartitionedSpmv,
-    compile_fused_partitioned,
     compile_partitioned,
     shard_partitioned,
 )
@@ -42,13 +39,11 @@ __all__ = [
     "BlockKernel",
     "BlockPlan",
     "CompositePlan",
-    "FusedPartitionedSpmv",
     "PartitionedSpmv",
     "RowBlock",
     "RowPartition",
     "SUPPORTED_BLOCK_COUNTS",
     "ShardedPartitionedSpmv",
-    "compile_fused_partitioned",
     "compile_partitioned",
     "partition_rows",
     "plan_for_partition",

@@ -148,83 +148,6 @@ def compile_partitioned(
     return PartitionedSpmv(blocks, plan.partition.n_rows)
 
 
-class FusedPartitionedSpmv:
-    """Heterogeneous composite SpMV in ONE Pallas launch.
-
-    The sequential ``PartitionedSpmv`` pays one kernel launch per block plus
-    a host-side concatenate; this wrapper holds the composite lowered to a
-    single fused stream (``repro.kernels.fused``): program ids map to
-    (block, tile) work items through the prefix-sum work descriptor, and
-    every program scatter-writes its y shard in place into the one
-    VMEM-resident output buffer. Exposes the same identity surface as the
-    sequential executor (``formats`` / ``n_blocks``) so serving code can
-    treat either interchangeably; per-block timing is structurally
-    impossible here (one launch), so telemetry-driven paths keep the
-    sequential executor.
-    """
-
-    def __init__(self, kernel, plan: CompositePlan):
-        self.kernel = kernel  # repro.kernels.fused.FusedSpmv
-        self.n_rows = plan.partition.n_rows
-        self._formats = tuple(bp.fmt for bp in plan.blocks)
-        self._block_ranges = tuple(
-            (bp.block.row_start, bp.block.row_end) for bp in plan.blocks
-        )
-
-    @property
-    def n_blocks(self) -> int:
-        return len(self._formats)
-
-    @property
-    def formats(self) -> tuple[str, ...]:
-        return self._formats
-
-    @property
-    def n_tiles(self) -> int:
-        return self.kernel.n_tiles
-
-    def descriptor(self) -> dict:
-        """Work-descriptor layout (docs/diagnostics): tile size, the program
-        id -> flat tile map, and each work item's owning block."""
-        return {
-            "tile": self.kernel.tile,
-            "tile_map": np.asarray(self.kernel.tile_map).tolist(),
-            "block_of_tile": list(self.kernel.block_of_tile),
-            "block_ranges": list(self._block_ranges),
-        }
-
-    def __call__(self, x: jax.Array) -> jax.Array:
-        with _span(
-            "kernel.execute",
-            mode="fused",
-            n_blocks=self.n_blocks,
-            formats="+".join(self.formats),
-        ):
-            return self.kernel(x)
-
-
-def compile_fused_partitioned(
-    dense: np.ndarray,
-    plan: CompositePlan,
-    *,
-    memo_key: Hashable | None = None,
-) -> FusedPartitionedSpmv:
-    """Lower ``plan`` to its single-launch executor (one memo entry)."""
-    from repro.kernels.ops import compile_spmv_fused
-
-    kernel = compile_spmv_fused(np.asarray(dense), plan, memo_key=memo_key)
-    fused = FusedPartitionedSpmv(kernel, plan)
-    log.info(
-        "compiled fused partitioned kernel: %d block(s) -> %d work item(s) "
-        "of %d elems, formats=%s",
-        fused.n_blocks,
-        fused.n_tiles,
-        kernel.tile,
-        "+".join(fused.formats),
-    )
-    return fused
-
-
 class ShardedPartitionedSpmv:
     """SPMD multi-device composite SpMV (one row block per mesh device).
 

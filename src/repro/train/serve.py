@@ -320,7 +320,6 @@ class SpmvServer:
         feedback=None,  # optional repro.telemetry.FeedbackLoop
         partition: bool = False,
         max_blocks: int = 8,
-        fused: bool = False,
         calibrate_every: int = 0,
         slo=None,  # optional repro.obs.slo.SloTracker
         anomaly: bool = False,  # attach a CostModelWatchdog (needs telemetry)
@@ -337,10 +336,6 @@ class SpmvServer:
         self.feedback = feedback
         self.partition = partition
         self.max_blocks = max_blocks
-        # single-launch composite executor on the non-adaptive partitioned
-        # path (the adaptive path needs per-block timing, which one launch
-        # cannot provide)
-        self.fused = fused
         # recalibrate the session's cost model every N served requests
         # (0 = never); requires telemetry on the session
         self.calibrate_every = int(calibrate_every)
@@ -458,8 +453,7 @@ class SpmvServer:
                         )
                 else:
                     res = self.session.partitioned_optimize(
-                        req.dense, objective, max_blocks=self.max_blocks,
-                        fused=self.fused,
+                        req.dense, objective, max_blocks=self.max_blocks
                     )
                     t0 = time.perf_counter()
                     y = np.asarray(jax.block_until_ready(res.kernel(x)))

@@ -251,14 +251,6 @@ def test_default_config_tracks_registry_default(with_bcsr):
     assert ts.DEFAULT_CONFIG.fmt == "csr"
 
 
-def test_ops_storage_bound_alias_reads_registry():
-    import repro.kernels.ops as ops
-
-    assert ops.MAX_STORAGE_BYTES == reg.MAX_STORAGE_BYTES
-    with pytest.raises(AttributeError):
-        ops.no_such_attribute
-
-
 # -------------------------------------------------------- fifth format: e2e
 def test_bcsr_row_compression_beats_bell_on_skew(with_bcsr):
     """The CMRS argument: on skewed block occupancy BCSR stores fewer

@@ -534,7 +534,7 @@ def test_server_metrics_endpoint_e2e():
 def test_session_trace_monolithic_and_fused_paths(tmp_path):
     """Acceptance: the trace JSONL shows session.optimize -> kernel.compile
     nesting and a kernel.execute span for BOTH the monolithic and the
-    fused-partitioned serving paths."""
+    partitioned serving paths."""
     tracer = get_tracer()
     clear_kernel_memo()
     session = AutoSpmvSession(_fake_tuner())
@@ -544,8 +544,8 @@ def test_session_trace_monolithic_and_fused_paths(tmp_path):
     res = session.compile_time_optimize(dense, "latency")
     res.kernel(np.ones(dense.shape[1], np.float32))
 
-    # fused-partitioned path (one Pallas launch)
-    part = session.partitioned_optimize(dense, "latency", max_blocks=4, fused=True)
+    # partitioned path (one kernel per row block, run in sequence)
+    part = session.partitioned_optimize(dense, "latency", max_blocks=4)
     part.kernel(np.ones(dense.shape[1], np.float32))
 
     path = tmp_path / "trace.jsonl"
@@ -567,19 +567,20 @@ def test_session_trace_monolithic_and_fused_paths(tmp_path):
         s["name"] for s in mono_children
     }
 
-    fused = named("session.optimize", mode="partitioned", fused=True)
-    assert fused
-    fused_children = {s["name"] for s in span_children(spans, fused[0]["id"])}
-    assert "kernel.compile" in fused_children
-    compile_span = next(
+    partitioned = named("session.optimize", mode="partitioned")
+    assert partitioned
+    assert "fused" not in partitioned[0]["attrs"]
+    compiles = [
         s for s in spans
-        if s["name"] == "kernel.compile" and s["parent"] == fused[0]["id"]
-    )
-    assert compile_span["attrs"]["fused"] is True
+        if s["name"] == "kernel.compile" and s["parent"] == partitioned[0]["id"]
+    ]
+    # one compile per row block, each under its block's own format
+    assert len(compiles) == part.n_blocks
+    assert sorted(s["attrs"]["fmt"] for s in compiles) == sorted(part.formats)
 
-    execs = named("kernel.execute", mode="fused")
+    execs = named("kernel.execute", mode="partitioned")
     assert execs and execs[0]["attrs"]["n_blocks"] == part.n_blocks
-    assert execs[0]["attrs"]["formats"]  # per-block formats, "+"-joined
+    assert execs[0]["attrs"]["formats"] == "+".join(part.formats)
     # executions happen after optimize returned: roots, not optimize children
     for s in execs:
         assert s["parent"] is None or by_id[s["parent"]]["name"] != "session.optimize"

@@ -29,7 +29,6 @@ from repro.kernels.common import (
 )
 from repro.kernels.csr import PREFETCH_SMEM_BYTES, WALK_ROWS, csr_spmv_pallas
 from repro.kernels.ell import ell_spmm_pallas, ell_spmv_pallas
-from repro.kernels.fused import fused_spmv_pallas
 from repro.kernels.sell import sell_spmv_pallas
 from repro.kernels.spmspv import csc_spmspv_pallas
 from repro.sparse.generate import SUITE
@@ -269,15 +268,9 @@ def test_sharded_ell_compiles_on_four_chips(topo):
 
 
 def test_scatter_kernels_refuse_to_compile():
-    """Fused and SpMSpV scatter-add by unsorted row id, which Mosaic cannot
-    lower: asked for a compiled kernel, they raise, never interpret."""
+    """SpMSpV scatter-adds by unsorted row id, which Mosaic cannot lower:
+    asked for a compiled kernel, it raises, never interprets."""
     sched = KernelSchedule()
-    x = jnp.zeros(256, jnp.float32)
-    with pytest.raises(NotImplementedError, match="fused_partitioned_spmv"):
-        fused_spmv_pallas(
-            x, x.astype(jnp.int32), x.astype(jnp.int32), jnp.zeros(2, jnp.int32),
-            x, 256, 128, interpret=False,
-        )
     with pytest.raises(NotImplementedError, match="csc_spmspv"):
         csc_spmspv_pallas(
             jnp.zeros((9, 128)), jnp.zeros((9, 128), jnp.int32),
